@@ -1,7 +1,7 @@
 """JAX-side early-bird benchmark: gradient-sync modes on an 8-device mesh.
 
-Spawns a subprocess with 8 fake host devices (the benchmark process itself
-keeps the single real device) and reports, per sync mode:
+Spawns a CPU-only subprocess with 8 fake host devices (the benchmark
+process itself keeps whatever device it has) and reports, per sync mode:
   * pre-optimization all-reduce count (program structure),
   * per-device all-reduce bytes from the compiled HLO (loop-corrected),
   * predicted DP-sync time on the v5e ICI from those bytes,
@@ -27,8 +27,9 @@ from repro.core.earlybird import SyncConfig, value_and_synced_grad
 from repro.configs import get_smoke_config
 from repro.models import lm
 from repro.launch import hlo_analysis
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 cfg = get_smoke_config("llama3.2-1b").replace(n_layers=8, d_model=128,
                                               d_ff=512, vocab=2048)
 params = lm.init_params(cfg, jax.random.PRNGKey(0))
@@ -70,6 +71,9 @@ print("RESULT " + json.dumps(out))
 
 def rows():
     env = os.environ.copy()
+    # a structure check on 8 host devices: the child never needs (and so
+    # never contends for) an accelerator the parent may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     repo = Path(__file__).resolve().parent.parent
     env["PYTHONPATH"] = f"{repo / 'src'}{os.pathsep}" + \
@@ -78,9 +82,10 @@ def rows():
                        capture_output=True, text=True, timeout=900)
     line = next((l for l in r.stdout.splitlines() if l.startswith("RESULT ")),
                 None)
-    if line is None:
-        return [("jax_earlybird/FAILED", 0.0,
-                 (r.stderr or r.stdout)[-200:].replace("\n", " "))]
+    if r.returncode != 0 or line is None:
+        raise RuntimeError(
+            f"jax_earlybird child failed (exit {r.returncode}):\n"
+            f"{(r.stderr or r.stdout)[-4000:]}")
     data = json.loads(line[len("RESULT "):])
     out = []
     for mode, d in data.items():

@@ -22,6 +22,7 @@ from . import (fig4_latency, fig5_congestion, fig6_vci, fig7_aggregation,
                scen_halo, scen_imbalance, scen_serving, scen_steady,
                scen_stencil, tableA_delayrate)
 from .common import emit
+from repro.runtime.compile_cache import enable_compile_cache
 
 SCENARIOS = (scen_steady, scen_halo, scen_stencil, scen_imbalance,
              scen_serving, scen_faults)
@@ -55,6 +56,7 @@ def _scenario_kw(mod, seed: int) -> dict:
 
 def main() -> None:
     fast = "--fast" in sys.argv
+    enable_compile_cache()
     seed = _seed(sys.argv)
     emit([], header=True)
     for mod in (tableA_delayrate, fig4_latency, fig5_congestion, fig6_vci,

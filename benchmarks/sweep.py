@@ -61,6 +61,7 @@ from repro.experiments import (SPECS, compare_to_baseline,
                                make_baseline, run_spec, run_specs,
                                save_disk_cache)
 from repro.experiments import engine as _engine_mod
+from repro.runtime.compile_cache import enable_compile_cache
 
 BENCH_ENGINES = ("vector", "reference", "jax", "pallas")
 BENCH_VERSION = 1
@@ -304,6 +305,7 @@ def main(argv=None) -> int:
     specs = _select_specs(args)
     if specs is None:
         return 2
+    enable_compile_cache()
 
     if args.list:
         list_specs(specs)

@@ -31,12 +31,13 @@ from jax.sharding import PartitionSpec as P
 from repro.configs import get_smoke_config
 from repro.core.earlybird import SyncConfig, value_and_synced_grad
 from repro.launch import hlo_analysis
+from repro.launch.mesh import make_mesh
 from repro.models import lm
 from repro.compat import shard_map
 
 
 def main():
-    mesh = jax.make_mesh((jax.device_count(),), ("data",))
+    mesh = make_mesh((jax.device_count(),), ("data",))
     cfg = get_smoke_config("llama3.2-1b").replace(
         n_layers=12, d_model=128, d_ff=512, vocab=2048)
     params = lm.init_params(cfg, jax.random.PRNGKey(0))

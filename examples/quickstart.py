@@ -21,7 +21,6 @@ from repro.data import pipeline
 from repro.launch.steps import StepConfig, make_train_step
 from repro.launch.train import build_state
 from repro.runtime import elastic
-from repro.compat import set_mesh
 
 
 def main():
@@ -34,7 +33,7 @@ def main():
                       param_dtype="float32", peak_lr=1e-3,
                       warmup_steps=5, total_steps=60)
     seq_len, batch = 128, 4
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         step_fn, *_ = make_train_step(cfg, mesh, scfg, seq_len=seq_len,
                                       global_batch=batch)
         step = jax.jit(step_fn, donate_argnums=0)

@@ -1,12 +1,7 @@
-"""Version-compatibility shims.
+"""Precision switch of the compiled fabric engines, and the repo's one
+``shard_map`` spelling.
 
-``shard_map`` became ``jax.shard_map`` (with ``check_vma``/``axis_names``)
-in newer JAX; older releases ship it as
-``jax.experimental.shard_map.shard_map`` with ``check_rep`` and an ``auto``
-set (the complement of the manual axes).  Everything in this repo imports
-it from here so both spellings work.
-
-This module also owns the **x64 guard** for the compiled fabric engine
+This module owns the **x64 guard** for the compiled fabric engine
 (:mod:`repro.core.fabric_jax`): under ``JAX_ENABLE_X64`` the jax engine
 computes in float64 and is bit-for-bit identical to the scalar
 ``ReferenceFabric``; under the float32 default it is tolerance-gated
@@ -15,8 +10,6 @@ forces one for a scope (the differential tests exercise both).
 """
 
 from __future__ import annotations
-
-import contextlib
 
 import jax
 
@@ -32,61 +25,15 @@ def x64_enabled() -> bool:
 
 
 def x64_mode(enable: bool):
-    """Context manager forcing x64 on or off for a scope.
-
-    Uses ``jax.experimental.enable_x64/disable_x64`` where available
-    (jit caches are config-keyed, so toggling mid-process is safe);
-    falls back to flipping the config flag directly.
-    """
-    exp = jax.experimental
-    if enable and hasattr(exp, "enable_x64"):
-        return exp.enable_x64()
-    if not enable and hasattr(exp, "disable_x64"):
-        return exp.disable_x64()
-
-    @contextlib.contextmanager
-    def _flip():
-        prev = x64_enabled()
-        jax.config.update("jax_enable_x64", enable)
-        try:
-            yield
-        finally:
-            jax.config.update("jax_enable_x64", prev)
-    return _flip()
+    """Context manager forcing x64 on or off for a scope (jit caches are
+    config-keyed, so toggling mid-process is safe)."""
+    return jax.enable_x64(enable)
 
 
-def axis_size(axis_name):
-    """``jax.lax.axis_size`` where available, else the classic psum-of-1
-    (constant-folded to a static int inside shard_map)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-def set_mesh(mesh):
-    """Ambient-mesh context: ``jax.set_mesh`` / ``use_mesh`` where
-    available; older jax uses the Mesh object itself as the context."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return mesh
-
-
-if hasattr(jax, "shard_map"):
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False,
-                  axis_names=None):
-        kw = {} if axis_names is None else {"axis_names": axis_names}
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma, **kw)
-else:  # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False,
-                  axis_names=None):
-        auto = frozenset()
-        if axis_names is not None:
-            auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=bool(check_vma),
-                          auto=auto)
+def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False,
+              axis_names=None):
+    """``jax.shard_map`` with ``axis_names=None`` meaning every mesh axis
+    is manual, and the varying-manual-axes check off by default."""
+    kw = {} if axis_names is None else {"axis_names": axis_names}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma, **kw)

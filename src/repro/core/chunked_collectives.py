@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 
 from .commplan import channel_slices
-from ..compat import axis_size
 
 
 def _ring_perm(n: int, reverse: bool = False):
@@ -63,7 +62,7 @@ def ring_all_gather(x: jax.Array, axis: str, *, n_channels: int = 1,
     x: the local shard.  Returns (N, *x.shape) stacked in global rank
     order, or concatenated along dim 0 if ``tiled``.
     """
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     perm = _ring_perm(n)
 
@@ -90,7 +89,7 @@ def ring_reduce_scatter(x: jax.Array, axis: str, *, n_channels: int = 1
                         ) -> jax.Array:
     """Reduce-scatter via a ring: x is (N, chunk, ...) of local
     contributions in global order; returns this rank's reduced chunk."""
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     perm = _ring_perm(n)
 
@@ -116,7 +115,7 @@ def ring_reduce_scatter(x: jax.Array, axis: str, *, n_channels: int = 1
 def ring_all_reduce(x: jax.Array, axis: str, *, n_channels: int = 1
                     ) -> jax.Array:
     """All-reduce = reduce-scatter + all-gather over flat chunks."""
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     flat = x.reshape(-1)
     pad = (-flat.shape[0]) % (n * max(1, n_channels))
     if pad:
@@ -138,7 +137,7 @@ def ring_all_reduce_q8(x: jax.Array, axis: str) -> jax.Array:
     aggressive gradient compression in the distributed-optimization bag of
     tricks; see optim.grad_compress for the error-feedback wrapper.
     """
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     perm = _ring_perm(n)
     flat = x.reshape(-1)
@@ -189,7 +188,7 @@ def collective_ag_matmul(x_shard: jax.Array, w: jax.Array, axis: str
     x_shard: (rows_local, K); w: (K, N) (replicated or K-sharded upstream).
     Returns (axis_size * rows_local, N) in global row order.
     """
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     perm = _ring_perm(n)
     rows = x_shard.shape[0]
@@ -216,7 +215,7 @@ def collective_matmul_rs(x: jax.Array, w_shard: jax.Array, axis: str
     x: (M, K_local); w_shard: (K_local, N).  Returns this rank's (M/n, N)
     chunk of the fully-reduced product (row-scattered in rank order).
     """
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     perm = _ring_perm(n)
     m = x.shape[0]

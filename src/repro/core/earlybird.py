@@ -64,15 +64,11 @@ def _constrain(tree, spec_tree):
     """
     if spec_tree is None:
         return tree
-    import jax.sharding as jsh
 
     def pin(x, spec):
         if spec is None:
             return x
-        try:
-            return jax.lax.with_sharding_constraint(x, spec)
-        except Exception:
-            return x
+        return jax.lax.with_sharding_constraint(x, spec)
 
     return jax.tree.map(pin, tree, spec_tree,
                         is_leaf=lambda x: x is None or hasattr(x, "shape"))

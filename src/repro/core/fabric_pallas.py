@@ -1,50 +1,50 @@
-"""The fused Pallas fabric engine: one kernel program per super-batch.
+"""The Pallas fabric engine: queue-scan kernels inside one jitted program.
 
 Fourth engine of the fabric family (``engine="pallas"``).  It advances
 the same three-stage resource model — per-rank VCI banks, per-rank NIC,
-per-directed-link wires — as a **single fused Pallas kernel** instead of
-the jax engine's chain of jitted scans plus host-side finish reduction:
+per-directed-link wires — as the jax engine, with each stage's queue
+recurrence ``t[k] = max(r[k], t[k-1]) + c[k]`` run by a Pallas kernel:
 
   * the whole grid of sweep points is flattened into one cfg-bucketed
     super-batch; per-stage jagged groups are re-bucketed by segment
-    depth — **exact-depth, mask-free buckets** when a stage has at most
+    depth — exact-depth buckets when a stage has at most
     :data:`MAX_EXACT_DEPTHS` distinct depths (the common stencil case:
     every VCI bank of a dimension sees the same message count), padded
-    power-of-two classes with masks otherwise;
+    power-of-two classes otherwise;
   * per-message stage-1 costs (previous-owner injection chain, protocol
     copy costs) are precomputed on the host in float64 with exactly the
-    scalar engine's operation order, so the kernel body is nothing but
-    the queue recurrences ``t[i] = max(r[i], t[i-1]) + c[i]``;
-  * per-stage queue state lives in VMEM scratch refs threaded through
-    the bucket scans, and the :class:`~repro.core.fabric.NetConfig`
-    costs enter as a scalar-prefetch operand, so traces are shared
-    across cost points;
-  * the finish reduction (per-flow max arrival + affine finish offsets
-    + per-rank max) runs **inside the kernel** via gathers into flow-
-    and rank-segment layouts — a 32k-rank point returns 32768 floats
-    instead of 1.6M arrivals.
+    scalar engine's operation order, so the kernels compute nothing but
+    the queue recurrences;
+  * each bucket is a ``(K, G)`` matrix — row k holds the k-th message of
+    every segment, segments on the lanes — stored as ``(K, G/128, 128)``
+    so one row fills whole vector registers.  G is padded to the lane
+    tiling and K to the depth tiling; padded slots gather a release time
+    of ``-inf`` and a cost of 0, so ``max(-inf, t) + 0 == t`` leaves the
+    queue state untouched without any mask.  The kernel grid tiles G
+    (independent segments, "parallel") and K (the recurrence, carried
+    across steps in the resident ``last`` block, "arbitrary"), with
+    blocks sized to fit v5e's default scoped VMEM;
+  * the gathers between stage layouts and the finish reduction (per-flow
+    max arrival + affine finish offsets + per-rank max) run in XLA
+    around the kernels, in the same jitted program — a 32k-rank point
+    returns 32768 floats instead of 1.6M arrivals.
 
-Under the interpreter (``REPRO_PALLAS_INTERPRET=1``, this container's
-default) the kernel runs as one fused grid program: the interpreter
-threads every ref through every grid step, so a multi-program grid pays
-a per-step toll the fused form avoids.  ``REPRO_PALLAS_GRID=bucket``
-selects the one-program-per-bucket grid instead — the layout a compiled
-TPU deployment wants, where per-bucket programs pipeline block loads —
-and is differential-tested but slower under interpretation.
-
-Precision contract: identical to the jax engine — bit-for-bit equal to
-``ReferenceFabric`` under ``JAX_ENABLE_X64`` (host costs are float64
-with the reference operation order; adding ``0.0`` is bitwise identity;
-``max`` reductions are order-independent), tolerance-close under
-float32.  Pinned by ``tests/test_engine_pallas.py``.
+Precision contract: the kernels compute in float32 on the chip (Mosaic
+has no float64), tolerance-close to ``ReferenceFabric``.  Under
+``JAX_ENABLE_X64`` on the CPU backend, where the kernels run in the
+Pallas interpreter, the engine is bit-for-bit equal to
+``ReferenceFabric`` (host costs are float64 with the reference
+operation order; adding ``0.0`` is bitwise identity; ``max`` reductions
+are order-independent).  x64 on any other backend raises.  Pinned by
+``tests/test_engine_pallas.py``; ``tests/test_tpu_compile.py`` compiles
+the kernels for a described v5e.
 """
 
 from __future__ import annotations
 
 import functools
-import os
 from dataclasses import dataclass
-from typing import Dict, Hashable, List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -62,22 +62,52 @@ if HAVE_JAX:
     from jax.experimental.pallas import tpu as pltpu
 
 # A stage whose groups span at most this many distinct depths is
-# bucketed by *exact* depth — no padding, no masks, no wasted lanes.
+# bucketed by *exact* depth — no padding rows, no wasted sublanes.
 MAX_EXACT_DEPTHS = 8
+LANES = 128
+# One grid step's double-buffered r, c and ys blocks (float32) stay
+# within half of v5e's 16 MiB default scoped VMEM.
+VMEM_BLOCK_BUDGET = 8 << 20
+# Deepest K tile; deeper buckets carry the recurrence across grid steps.
+MAX_TILE_DEPTH = 256
 
 
-def _bucket_grid_mode() -> bool:
-    """One grid program per bucket (the compiled-TPU layout) instead of
-    the fused single program the interpreter prefers."""
-    return os.environ.get("REPRO_PALLAS_GRID", "fused") == "bucket"
+def _kernel_dtype():
+    """float32 on the chip; float64 only under x64 on the CPU backend,
+    where the interpreter keeps the bit-for-bit contract."""
+    if not x64_enabled():
+        return jnp.float32
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(
+            f"engine='pallas' cannot run with JAX_ENABLE_X64 on the "
+            f"{backend!r} backend: Mosaic kernels have no float64, so the "
+            "engine's bit-for-bit x64 contract holds only in interpret mode"
+            " on the CPU.  Disable x64 (float32 is tolerance-close) or use"
+            " engine='jax'.")
+    return jnp.float64
+
+
+def _tiles(K: int, G: int) -> Tuple[int, int, int, int]:
+    """``(TK, K_pad, TS, S_pad)`` of one ``(K, G)`` bucket: depth tile
+    and padded depth, sublane-row tile and padded rows of 128 lanes."""
+    nk = -(-K // MAX_TILE_DEPTH)
+    TK = -(-K // nk)
+    S = -(-G // LANES)
+    ts_max = max(8, VMEM_BLOCK_BUDGET // (6 * TK * LANES * 4) // 8 * 8)
+    if S <= ts_max:  # one block spans every row: no alignment needed
+        return TK, TK * nk, S, S
+    ns = -(-S // ts_max)
+    TS = -(-(-(-S // ns)) // 8) * 8  # ceil(S / ns) rounded up to 8
+    return TK, TK * nk, TS, TS * ns
 
 
 @dataclass
 class FinishSpec:
-    """In-kernel finish reduction of one grid item.
+    """Finish reduction of one grid item, computed on the device.
 
     Valid only for *affine* finishes (``finish_batch(flows, None, x) ==
-    x + foff`` elementwise — the caller probes this): the kernel then
+    x + foff`` elementwise — the caller probes this): the program then
     computes per-flow max arrival + ``foff`` and the per-rank max of
     those, returning per-rank completion times directly.
     """
@@ -89,23 +119,26 @@ class FinishSpec:
 
 @dataclass
 class _Bucket:
-    """One depth-class of a stage: ``idx[k, g]`` is the global message
-    id of the k-th member of the bucket's g-th segment; ``mask`` marks
-    real slots (None when the bucket is exact-depth); ``sel`` names the
-    segments as indices into the stage's concatenated group list."""
+    """One depth-class of a stage: ``idx[k, g]`` is the id of the k-th
+    member of the bucket's g-th segment, or the stage's sentinel on
+    padded slots; ``sel`` names the segments as indices into the
+    stage's concatenated group list; ``meta`` is ``(K_pad, S_pad, TK,
+    TS, G, go)`` — padded shape, tiles, real segment count and the
+    bucket's first segment in bucket-major group order."""
     idx: np.ndarray
-    mask: Optional[np.ndarray]
     sel: np.ndarray
+    meta: tuple
 
 
 def _stage_buckets(order: np.ndarray, counts: np.ndarray,
-                   offsets: np.ndarray, n: int
+                   offsets: np.ndarray, sentinel: int
                    ) -> Tuple[List[_Bucket], np.ndarray, int]:
     """Re-bucket one stage's jagged segments by depth class.
 
-    Returns ``(buckets, pos, size)``: ``pos[i]`` is message i's slot in
+    Returns ``(buckets, pos, size)``: ``pos[i]`` is member i's slot in
     the stage's flat scan-output vector (concatenation of the buckets'
-    raveled ``(K, G)`` matrices, ``size`` total slots).
+    raveled padded ``(K_pad, S_pad * 128)`` matrices, ``size`` total
+    slots).  ``sentinel`` (the member count) fills padded slots.
     """
     exact = len(np.unique(counts)) <= MAX_EXACT_DEPTHS
     if exact:
@@ -113,12 +146,14 @@ def _stage_buckets(order: np.ndarray, counts: np.ndarray,
     else:  # counts >= 1 always; log2 of an exact power of two is exact
         kcls = (1 << np.ceil(np.log2(np.maximum(counts, 1)))
                 .astype(np.int64))
-    pos = np.empty(n, dtype=np.int64)
+    pos = np.empty(sentinel, dtype=np.int64)
     buckets: List[_Bucket] = []
-    base = 0
+    base = go = 0
     for K in np.unique(kcls).tolist():
         sel = np.nonzero(kcls == K)[0]
         G = len(sel)
+        TK, Kp, TS, S = _tiles(K, G)
+        W = S * LANES
         cnt = counts[sel]
         offs = offsets[sel]
         total = int(cnt.sum())
@@ -127,16 +162,13 @@ def _stage_buckets(order: np.ndarray, counts: np.ndarray,
         within = np.arange(total, dtype=np.int64) - np.repeat(starts, cnt)
         col = np.repeat(np.arange(G, dtype=np.int64), cnt)
         members = order[np.repeat(offs, cnt) + within]
-        idx = np.zeros((K, G), dtype=np.int32)
+        idx = np.full((Kp, W), sentinel, dtype=np.int32)
         idx[within, col] = members
-        if int(cnt.min()) == K:
-            mask = None
-        else:
-            mask = np.zeros((K, G), dtype=bool)
-            mask[within, col] = True
-        pos[members] = base + within * G + col
-        buckets.append(_Bucket(idx=idx, mask=mask, sel=sel))
-        base += K * G
+        pos[members] = base + within * W + col
+        buckets.append(_Bucket(idx=idx, sel=sel,
+                               meta=(Kp, S, TK, TS, G, go)))
+        base += Kp * W
+        go += G
     return buckets, pos, base
 
 
@@ -180,266 +212,163 @@ def _cost_columns(t_ready, nbytes, thread, put, am_copy, cfg: NetConfig,
     return c1, c3, rdv
 
 
-def _pack_stage_ops(b1, b2, b3, pos1, pos2):
-    """Static kernel operands + per-bucket metadata for the three stage
-    blocks, in the kernel's pop order (the single source of truth the
-    kernel's operand cursor mirrors): per stage-1 bucket ``idx[,mask]``,
-    per stage-2 bucket ``pos1[idx][,mask]``, per stage-3 bucket ``idx,
-    pos2[idx][,mask]``.  Also returns each stage's bucket-major group
-    permutation (for warm-state init/readback vectors)."""
-    statics: List[np.ndarray] = []
-    metas = []
-    grp_orders = []
-    for s, bks in enumerate((b1, b2, b3)):
-        m = []
-        fo = go = 0
-        for bk in bks:
-            K, G = bk.idx.shape
-            m.append((K, G, bk.mask is not None, fo, go))
-            if s == 0:
-                statics.append(bk.idx)
-            elif s == 1:
-                statics.append(pos1[bk.idx].astype(np.int32))
-            else:
-                statics.append(bk.idx)
-                statics.append(pos2[bk.idx].astype(np.int32))
-            if bk.mask is not None:
-                statics.append(bk.mask)
-            fo += K * G
-            go += G
-        metas.append(tuple(m))
-        grp_orders.append(np.concatenate([bk.sel for bk in bks]))
-    return metas, statics, grp_orders
+def _stage_ops(lays, n: int):
+    """The three scan stages' buckets and static operands, in the order
+    :func:`_build_call` consumes them: per stage-1 bucket ``idx``, per
+    stage-2 bucket ``pos1[idx]``, per stage-3 bucket ``idx, pos2[idx]``
+    (each ``pos`` extended so a sentinel maps to the previous stage's
+    sentinel slot).  Returns ``(core, statics, pos3, s3, grp_orders)``:
+    the structure dict :func:`_runtime_meta` completes, the statics,
+    message slots in the stage-3 output, its size, and each stage's
+    bucket-major group permutation (for warm-state vectors)."""
+    (b1, pos1, s1), (b2, pos2, s2), (b3, pos3, s3) = (
+        _stage_buckets(lay[0], lay[2], lay[3], n) for lay in lays)
+    pos1x = np.append(pos1, s1)
+    pos2x = np.append(pos2, s2)
+    statics: List[np.ndarray] = [bk.idx for bk in b1]
+    statics += [pos1x[bk.idx].astype(np.int32) for bk in b2]
+    for bk in b3:
+        statics += [bk.idx, pos2x[bk.idx].astype(np.int32)]
+    core = dict(n=n, st1=tuple(bk.meta for bk in b1),
+                st2=tuple(bk.meta for bk in b2),
+                st3=tuple(bk.meta for bk in b3), sizes=(s1, s2, s3),
+                finf=(), n_flows=0, finr=())
+    grp_orders = tuple(np.concatenate([bk.sel for bk in bks])
+                       for bks in (b1, b2, b3))
+    return core, statics, pos3, s3, grp_orders
 
 
 @dataclass(frozen=True)
 class _Meta:
-    """Hashable shape/structure key of one kernel build (the
-    ``lru_cache`` key of :func:`_build_call`): per-bucket ``(K, G,
-    masked, flat_offset, group_offset)`` tuples plus the runtime
-    switches that select a different trace."""
+    """Hashable shape/structure key of one program build (the
+    ``lru_cache`` key of :func:`_build_call`): per-bucket ``_Bucket.meta``
+    tuples plus the runtime switches that select a different trace."""
     mode: str           # "finish" | "arrivals"
     f64: bool
     interpret: bool
-    bucket_grid: bool
     n: int
     st1: tuple
     st2: tuple
     st3: tuple
-    sizes: tuple        # flat scan-vector slots per stage
-    n_groups: tuple     # segment count per stage
-    finf: tuple         # finish flow buckets: (K, G, masked, go)
+    sizes: tuple        # flat scan-output slots per stage
+    finf: tuple         # finish flow buckets
     n_flows: int
-    finr: tuple         # finish rank buckets: (K, G, masked, go)
-    n_rank_out: int
+    finr: tuple         # finish rank buckets
 
 
-def _n_inputs(meta: _Meta) -> int:
-    n = 7 + (1 if meta.mode == "finish" else 0)
-    n += sum(1 + mk for (_, _, mk, _, _) in meta.st1)
-    n += sum(1 + mk for (_, _, mk, _, _) in meta.st2)
-    n += sum(2 + mk for (_, _, mk, _, _) in meta.st3)
-    if meta.mode == "finish":
-        n += sum(1 + mk for (_, _, mk, _) in meta.finf) + 1  # + fperm
-        n += sum(1 + mk for (_, _, mk, _) in meta.finr)
-    else:
-        n += 1  # pos3 (per-message arrival gather)
-    return n
+@functools.lru_cache(maxsize=256)
+def _scan_call(K: int, S: int, TK: int, TS: int, dtype_name: str,
+               interpret: bool):
+    """The queue-scan kernel of one ``(K, S, 128)`` bucket:
+    ``(r, c, cur0) -> (ys, last)`` with ``ys[k] = max(r[k], ys[k-1]) +
+    c[k]`` down the depth axis (``ys[-1] = cur0``) and ``last`` the
+    carry after the final row."""
+    dtype = jnp.dtype(dtype_name)
 
+    def kernel(r_ref, c_ref, cur0_ref, ys_ref, last_ref):
+        @pl.when(pl.program_id(1) == 0)
+        def _():
+            last_ref[...] = cur0_ref[...]
 
-def _scan_vals(r, c, m, cur0, cscalar=None):
-    """One bucket's queue recurrence ``t[k] = max(r[k], t[k-1]) + c[k]``
-    down the depth axis, vectorized across the bucket's segments.
-    Returns ``(last_carry, ys)`` — the per-segment busy-until state and
-    the full (K, G) release matrix.  Masked (padded) lanes never touch
-    the carry; their ys slots are garbage nothing gathers from."""
-    if r.shape[0] == 1:  # depth-1 segments: no scan machinery at all
-        ck = cscalar if cscalar is not None else c[0]
-        t = jnp.maximum(r[0], cur0) + ck
-        last = t if m is None else jnp.where(m[0], t, cur0)
-        return last, t[None]
-    if cscalar is None:
-        if m is None:
-            def step(cur, xs):
-                rk, ck = xs
-                t = jnp.maximum(rk, cur) + ck
-                return t, t
-            xs = (r, c)
-        else:
-            def step(cur, xs):
-                rk, ck, mk = xs
-                t = jnp.maximum(rk, cur) + ck
-                return jnp.where(mk, t, cur), t
-            xs = (r, c, m)
-    else:
-        if m is None:
-            def step(cur, rk):
-                t = jnp.maximum(rk, cur) + cscalar
-                return t, t
-            xs = r
-        else:
-            def step(cur, xs):
-                rk, mk = xs
-                t = jnp.maximum(rk, cur) + cscalar
-                return jnp.where(mk, t, cur), t
-            xs = (r, m)
-    return lax.scan(step, cur0, xs)
+        def row(k, cur):
+            t = jnp.maximum(r_ref[k], cur) + c_ref[k]
+            ys_ref[k] = t
+            return t
+
+        last_ref[...] = lax.fori_loop(0, TK, row, last_ref[...])
+
+    blk = pl.BlockSpec((TK, TS, LANES), lambda j, k: (k, j, 0))
+    rows = pl.BlockSpec((TS, LANES), lambda j, k: (j, 0))
+    return pl.pallas_call(
+        kernel, grid=(S // TS, K // TK),
+        in_specs=[blk, blk, rows], out_specs=[blk, rows],
+        out_shape=[jax.ShapeDtypeStruct((K, S, LANES), dtype),
+                   jax.ShapeDtypeStruct((S, LANES), dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret, name="fabric_queue_scan")
 
 
 @functools.lru_cache(maxsize=64)
 def _build_call(meta: _Meta):
-    """Build (once per structure) the jitted ``pallas_call`` advancing a
-    whole super-batch.  Operand order mirrors :func:`_pack_stage_ops`
-    exactly; the NetConfig cost vector rides the scalar-prefetch slot so
-    different cost points share the trace."""
+    """Build (once per structure) the jitted program advancing a whole
+    super-batch: per stage, XLA gathers each bucket's release times and
+    costs, the scan kernel advances it, and the flattened outputs feed
+    the next stage's gathers.  Operand order mirrors :func:`_stage_ops`
+    (then the finish statics of :func:`_assemble`); the NetConfig cost
+    vector is a dynamic operand so cost points share the trace."""
     _require_jax()
     dtype = jnp.float64 if meta.f64 else jnp.float32
     finish = meta.mode == "finish"
-    n_in = _n_inputs(meta)
-    n_out = 1 if finish else 4
     s1, s2, s3 = meta.sizes
-    G1, G2, G3 = meta.n_groups
-    n_prog = len(meta.st1) + len(meta.st2) + len(meta.st3)
-    n_prog += (len(meta.finf) + 1 + len(meta.finr)) if finish else 1
 
-    def kernel(consts_ref, *refs):
-        ins = refs[:n_in]
-        outs = refs[n_in:n_in + n_out]
-        scratch = refs[n_in + n_out:]
-        ys1_ref, ys2_ref, ys3_ref = scratch[0], scratch[1], scratch[2]
-        if finish:
-            fmb_ref, fin_ref = scratch[3], scratch[4]
-            rank_out = outs[0]
-        else:
-            arr_out, cur1_out, cur2_out, cur3_out = outs
-        tr_ref, c1_ref, c3_ref, rdv_ref = ins[0:4]
-        init_refs = ins[4:7]
-        cursor = [8 if finish else 7]
-        if finish:
-            foff_ref = ins[7]
+    def scan_stage(buckets, init, operands):
+        ys, lasts = [], []
+        for (K, S, TK, TS, G, go), (r, c) in zip(buckets, operands):
+            cur0 = jnp.pad(init[go:go + G], (0, S * LANES - G))
+            y, last = _scan_call(K, S, TK, TS, jnp.dtype(dtype).name,
+                                 meta.interpret)(
+                r.reshape(K, S, LANES), c.reshape(K, S, LANES),
+                cur0.reshape(S, LANES))
+            ys.append(y.reshape(-1))
+            lasts.append(last.reshape(-1)[:G])
+        return jnp.concatenate(ys), jnp.concatenate(lasts)
 
-        def pop():
-            ref = ins[cursor[0]]
-            cursor[0] += 1
-            return ref
+    def bucket_max(v, G):
+        return v.max(axis=0)[:G]
 
-        aw, anic, ar = consts_ref[2], consts_ref[6], consts_ref[9]
-        programs = []
-        for (K, G, masked, fo, go) in meta.st1:
-            idx_ref = pop()
-            m_ref = pop() if masked else None
+    def run(consts, tr, c1, c3, rdv, init1, init2, init3, *rest):
+        it = iter(rest)
+        foff = next(it) if finish else None
+        aw, anic, ar = consts[2], consts[6], consts[9]
+        ninf = jnp.full((1,), -jnp.inf, dtype)
+        zero = jnp.zeros((1,), dtype)
+        # message columns extended by the sentinel slot n
+        tr_x = jnp.concatenate([tr, ninf])
+        c1_x = jnp.concatenate([c1, zero])
+        c3_x = jnp.concatenate([c3, zero])
+        rdv_x = jnp.concatenate([rdv, zero])
 
-            def t1(idx_ref=idx_ref, m_ref=m_ref, K=K, G=G, fo=fo, go=go):
-                idx = idx_ref[...]
-                m = None if m_ref is None else m_ref[...]
-                cur0 = init_refs[0][...][go:go + G]
-                last, ys = _scan_vals(tr_ref[...][idx], c1_ref[...][idx],
-                                      m, cur0)
-                ys1_ref[fo:fo + K * G] = ys.reshape(-1)
-                if not finish:
-                    cur1_out[go:go + G] = last
-            programs.append(t1)
-        for (K, G, masked, fo, go) in meta.st2:
-            p_ref = pop()
-            m_ref = pop() if masked else None
+        ops = []
+        for _ in meta.st1:
+            idx = next(it)
+            ops.append((tr_x[idx], c1_x[idx]))
+        ys1, cur1 = scan_stage(meta.st1, init1, ops)
+        ys1 = jnp.concatenate([ys1, ninf])
 
-            def t2(p_ref=p_ref, m_ref=m_ref, K=K, G=G, fo=fo, go=go):
-                m = None if m_ref is None else m_ref[...]
-                cur0 = init_refs[1][...][go:go + G]
-                last, ys = _scan_vals(ys1_ref[...][p_ref[...]], None, m,
-                                      cur0, cscalar=anic)
-                ys2_ref[fo:fo + K * G] = ys.reshape(-1)
-                if not finish:
-                    cur2_out[go:go + G] = last
-            programs.append(t2)
-        for (K, G, masked, fo, go) in meta.st3:
-            idx_ref = pop()
-            p_ref = pop()
-            m_ref = pop() if masked else None
+        ops = []
+        for _ in meta.st2:
+            p = next(it)
+            ops.append((ys1[p], jnp.where(p == s1, 0.0, anic)))
+        ys2, cur2 = scan_stage(meta.st2, init2, ops)
+        ys2 = jnp.concatenate([ys2, ninf])
 
-            def t3(idx_ref=idx_ref, p_ref=p_ref, m_ref=m_ref, K=K, G=G,
-                   fo=fo, go=go):
-                idx = idx_ref[...]
-                # rendezvous RTS/CTS delays the wire-queue entry; the
-                # carried busy-until state excludes the +aw+ar delivery
-                # tail, which only the arrival values pick up
-                r = ys2_ref[...][p_ref[...]] + rdv_ref[...][idx]
-                m = None if m_ref is None else m_ref[...]
-                cur0 = init_refs[2][...][go:go + G]
-                last, ys = _scan_vals(r, c3_ref[...][idx], m, cur0)
-                ys3_ref[fo:fo + K * G] = (ys + aw + ar).reshape(-1)
-                if not finish:
-                    cur3_out[go:go + G] = last
-            programs.append(t3)
-        if finish:
-            for (K, G, masked, go) in meta.finf:
-                p_ref = pop()
-                m_ref = pop() if masked else None
+        ops = []
+        for _ in meta.st3:
+            idx, p = next(it), next(it)
+            # rendezvous RTS/CTS delays the wire-queue entry; the
+            # carried busy-until state excludes the +aw+ar delivery
+            # tail, which only the arrival values pick up
+            ops.append((ys2[p] + rdv_x[idx], c3_x[idx]))
+        ys3, cur3 = scan_stage(meta.st3, init3, ops)
+        arr = ys3 + aw + ar
 
-                def tf(p_ref=p_ref, m_ref=m_ref, G=G, go=go):
-                    v = ys3_ref[...][p_ref[...]]
-                    if m_ref is not None:  # arrivals > 0: 0-fill is safe
-                        v = jnp.where(m_ref[...], v, jnp.zeros_like(v))
-                    fmb_ref[go:go + G] = v.max(axis=0)
-                programs.append(tf)
-            fperm_ref = pop()
+        if not finish:
+            return arr[next(it)], cur1, cur2, cur3
+        # arrivals > 0, so the sentinel's 0-fill never wins a max
+        arr_x = jnp.concatenate([arr, zero])
+        fmb = jnp.concatenate([bucket_max(arr_x[next(it)], G)
+                               for (_, _, _, _, G, _) in meta.finf])
+        fin = jnp.concatenate([fmb[next(it)] + foff, zero])
+        return jnp.concatenate([bucket_max(fin[next(it)], G)
+                                for (_, _, _, _, G, _) in meta.finr])
 
-            def tc(fperm_ref=fperm_ref):
-                fin_ref[...] = fmb_ref[...][fperm_ref[...]] + foff_ref[...]
-            programs.append(tc)
-            for (K, G, masked, go) in meta.finr:
-                f_ref = pop()
-                m_ref = pop() if masked else None
-
-                def tr_(f_ref=f_ref, m_ref=m_ref, G=G, go=go):
-                    v = fin_ref[...][f_ref[...]]
-                    if m_ref is not None:
-                        v = jnp.where(m_ref[...], v, jnp.zeros_like(v))
-                    rank_out[go:go + G] = v.max(axis=0)
-                programs.append(tr_)
-        else:
-            pos3_ref = pop()
-
-            def te(pos3_ref=pos3_ref):
-                arr_out[...] = ys3_ref[...][pos3_ref[...]]
-            programs.append(te)
-        if meta.bucket_grid:
-            pid = pl.program_id(0)
-            for i, prog in enumerate(programs):
-                pl.when(pid == i)(prog)
-        else:
-            for prog in programs:
-                prog()
-
-    if finish:
-        out_shape = jax.ShapeDtypeStruct((meta.n_rank_out,), dtype)
-        out_specs = pl.BlockSpec(memory_space=pltpu.ANY)
-    else:
-        out_shape = (jax.ShapeDtypeStruct((meta.n,), dtype),
-                     jax.ShapeDtypeStruct((G1,), dtype),
-                     jax.ShapeDtypeStruct((G2,), dtype),
-                     jax.ShapeDtypeStruct((G3,), dtype))
-        out_specs = (pl.BlockSpec(memory_space=pltpu.ANY),) * 4
-    scratch_shapes = [pltpu.VMEM((s1,), dtype), pltpu.VMEM((s2,), dtype),
-                      pltpu.VMEM((s3,), dtype)]
-    if finish:
-        scratch_shapes += [pltpu.VMEM((meta.n_flows,), dtype),
-                           pltpu.VMEM((meta.n_flows,), dtype)]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n_prog if meta.bucket_grid else 1,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * n_in,
-        out_specs=out_specs,
-        scratch_shapes=scratch_shapes)
-    return jax.jit(pl.pallas_call(kernel, grid_spec=grid_spec,
-                                  out_shape=out_shape,
-                                  interpret=meta.interpret))
+    return jax.jit(run)
 
 
 def _runtime_meta(core: dict, mode: str) -> _Meta:
-    return _Meta(mode=mode, f64=x64_enabled(),
-                 interpret=_rt.interpret_mode(),
-                 bucket_grid=_bucket_grid_mode(), **core)
+    return _Meta(mode=mode, f64=_kernel_dtype() == jnp.float64,
+                 interpret=_rt.interpret_mode(), **core)
 
 
 # ---------------------------------------------------------------------------
@@ -448,21 +377,19 @@ def _runtime_meta(core: dict, mode: str) -> _Meta:
 
 def _assemble(items: List[GridItem],
               finishes: Optional[List[FinishSpec]]):
-    """Flatten one cfg-uniform bucket of grid items into the kernel's
+    """Flatten one cfg-uniform bucket of grid items into the program's
     operands.  Per-item stage layouts (memoized, shared with the jax
     engine) compose by message-base offset — no global argsort; only the
     finish reduction's flow/rank groupings sort globally.  Returns
     ``(core, dyn, statics, aux)``: the structure dict :func:`_runtime_meta`
-    completes, float64 dynamic operands, integer/bool static operands,
-    and the host-side unpack info."""
+    completes, float64 dynamic operands, integer static operands, and
+    the host-side unpack info."""
     N = sum(len(it) for it in items)
     tr = np.empty(N)
     c1 = np.empty(N)
     c3 = np.empty(N)
     rdv = np.empty(N)
-    st_orders: Tuple[list, ...] = ([], [], [])
-    st_counts: Tuple[list, ...] = ([], [], [])
-    st_offs: Tuple[list, ...] = ([], [], [])
+    st_lays = tuple(([], [], [], []) for _ in range(3))
     fid_l, foff_l, fdst_l, item_ranks = [], [], [], []
     item_lens = []
     base = fbase = rbase = 0
@@ -476,10 +403,11 @@ def _assemble(items: List[GridItem],
             it.t_ready, it.nbytes, it.thread, it.put, it.am_copy,
             it.cfg, lays[0], None)
         for s in range(3):
-            o, _, cnt, f = lays[s]
-            st_orders[s].append(o + base)
-            st_counts[s].append(cnt)
-            st_offs[s].append(f + base)
+            o, u, cnt, f = lays[s]
+            st_lays[s][0].append(o + base)
+            st_lays[s][1].append(u)
+            st_lays[s][2].append(cnt)
+            st_lays[s][3].append(f + base)
         if finishes is not None:
             fin = finishes[k]
             fid_l.append(fin.fid + fbase)
@@ -490,22 +418,12 @@ def _assemble(items: List[GridItem],
             rbase += fin.n_ranks
         item_lens.append(n)
         base += n
-    stages = []
-    n_groups = []
-    for s in range(3):
-        counts = np.concatenate(st_counts[s])
-        stages.append(_stage_buckets(np.concatenate(st_orders[s]), counts,
-                                     np.concatenate(st_offs[s]), N))
-        n_groups.append(len(counts))
-    (b1, pos1, s1), (b2, pos2, s2), (b3, pos3, s3) = stages
-    (st1m, st2m, st3m), statics, grp_orders = _pack_stage_ops(
-        b1, b2, b3, pos1, pos2)
+    lays = [tuple(np.concatenate(part) for part in lay) for lay in st_lays]
+    core, statics, pos3, s3, _ = _stage_ops(lays, N)
+    n_groups = [len(lay[2]) for lay in lays]
     dyn = [tr, c1, c3, rdv, np.zeros(n_groups[0]),
            np.zeros(n_groups[1]), np.zeros(n_groups[2])]
-    aux: dict = {"item_lens": item_lens, "grp_orders": tuple(grp_orders)}
-    core = dict(n=N, st1=st1m, st2=st2m, st3=st3m, sizes=(s1, s2, s3),
-                n_groups=tuple(n_groups), finf=(), n_flows=0, finr=(),
-                n_rank_out=0)
+    aux: dict = {"item_lens": item_lens}
     if finishes is None:
         statics.append(pos3.astype(np.int32))
         return core, dyn, statics, aux
@@ -517,42 +435,27 @@ def _assemble(items: List[GridItem],
     if len(uf) != F:
         raise ValueError("every flow needs at least one wire message")
     fbuckets, _, _ = _stage_buckets(of, cf, ff, N)
-    finfm = []
+    pos3x = np.append(pos3, s3)
     fperm = np.empty(F, dtype=np.int32)
-    go = 0
     for bk in fbuckets:
-        K, G = bk.idx.shape
-        finfm.append((K, G, bk.mask is not None, go))
-        statics.append(pos3[bk.idx].astype(np.int32))
-        if bk.mask is not None:
-            statics.append(bk.mask)
+        statics.append(pos3x[bk.idx].astype(np.int32))
+        G, go = bk.meta[4], bk.meta[5]
         fperm[uf[bk.sel]] = go + np.arange(G, dtype=np.int32)
-        go += G
     statics.append(fperm)
     orr, ur, cr, fr = _fb._group_layout(fdst)
     rbuckets, _, _ = _stage_buckets(orr, cr, fr, F)
-    finrm = []
-    rank_out_ids = []
-    go = 0
-    for bk in rbuckets:
-        K, G = bk.idx.shape
-        finrm.append((K, G, bk.mask is not None, go))
-        statics.append(bk.idx)  # values are flow ids: gathers from fin
-        if bk.mask is not None:
-            statics.append(bk.mask)
-        rank_out_ids.append(ur[bk.sel])
-        go += G
+    statics += [bk.idx for bk in rbuckets]  # flow ids: gathers from fin
     dyn.append(foff)
-    aux.update(rank_out_ids=np.concatenate(rank_out_ids),
+    aux.update(rank_out_ids=np.concatenate([ur[bk.sel] for bk in rbuckets]),
                item_ranks=item_ranks, n_ranks_total=rbase)
-    core.update(finf=tuple(finfm), n_flows=F, finr=tuple(finrm),
-                n_rank_out=go)
+    core.update(finf=tuple(bk.meta for bk in fbuckets), n_flows=F,
+                finr=tuple(bk.meta for bk in rbuckets))
     return core, dyn, statics, aux
 
 
 # Whole-super-batch operands (device-committed), keyed by the member
 # items' layout keys + precision: benchmark repeats re-dispatch the
-# kernel without re-assembling or re-copying anything.
+# program without re-assembling or re-copying anything.
 _OPS_MEMO = _fb.CappedMemo(8)
 # Single-batch arrivals-mode structure (stage buckets + static operands)
 # for the warm-state driver path, keyed by layout key + precision.
@@ -564,18 +467,20 @@ def memo_stats() -> dict:
 
 
 def clear_memos() -> None:
-    """Reset the pallas engine's operand caches and built kernels with
+    """Reset the pallas engine's operand caches and built programs with
     their counters (``sweep --profile`` cold pass)."""
     _OPS_MEMO.clear()
     _ARR_MEMO.clear()
     _build_call.cache_clear()
+    _scan_call.cache_clear()
 
 
 def _dispatch(items: List[GridItem],
               finishes: Optional[List[FinishSpec]]):
-    """Assemble (or reuse) one bucket's operands and dispatch the fused
-    kernel; returns the *unsynced* jax result plus the unpack aux."""
+    """Assemble (or reuse) one bucket's operands and dispatch the
+    program; returns the *unsynced* jax result plus the unpack aux."""
     mode = "finish" if finishes is not None else "arrivals"
+    dtype = _kernel_dtype()
     key = None
     if all(it.key is not None for it in items):
         key = ("pallas-" + mode, x64_enabled(),
@@ -583,7 +488,6 @@ def _dispatch(items: List[GridItem],
     entry = _OPS_MEMO.get(key) if key is not None else None
     if entry is None:
         core, dyn, statics, aux = _assemble(items, finishes)
-        dtype = jnp.float64 if x64_enabled() else jnp.float32
         consts = jnp.asarray(np.array(_consts(items[0].cfg)), dtype)
         ops = ([consts] + [jnp.asarray(a, dtype) for a in dyn]
                + [jnp.asarray(a) for a in statics])
@@ -592,16 +496,16 @@ def _dispatch(items: List[GridItem],
             _OPS_MEMO.put(key, entry)
     core, ops, aux = entry
     meta = _runtime_meta(core, mode)
-    return _build_call(meta)(ops[0], *ops[1:]), aux
+    return _build_call(meta)(*ops), aux
 
 
 def _cfg_buckets(items: List[GridItem]) -> Dict[tuple, List[int]]:
     """Items bucketed by (cfg, n_ranks, n_vcis): each bucket's NetConfig
-    is uniform (one scalar-prefetch vector), and keeping rank-grid
-    shapes uniform keeps each bucket's per-resource chain depths nearly
-    uniform too — the exact-depth (mask-free) scan buckets stay under
-    :data:`MAX_EXACT_DEPTHS`, which measures faster than fusing the
-    whole sweep into one mixed-depth masked dispatch."""
+    is uniform (one cost vector), and keeping rank-grid shapes uniform
+    keeps each bucket's per-resource chain depths nearly uniform too —
+    the exact-depth scan buckets stay under :data:`MAX_EXACT_DEPTHS`
+    instead of fusing the whole sweep into one mixed-depth padded
+    dispatch."""
     buckets: Dict[tuple, List[int]] = {}
     for i, it in enumerate(items):
         buckets.setdefault((it.cfg, it.n_ranks, it.n_vcis), []).append(i)
@@ -609,8 +513,8 @@ def _cfg_buckets(items: List[GridItem]) -> Dict[tuple, List[int]]:
 
 
 def transmit_grid(items: List[GridItem]) -> List[np.ndarray]:
-    """Evaluate many independent cold-start exchanges through the fused
-    kernel; returns each item's per-message arrival times in its input
+    """Evaluate many independent cold-start exchanges through the scan
+    kernels; returns each item's per-message arrival times in its input
     (merge) order.  Drop-in for :func:`repro.core.fabric_jax
     .transmit_grid` — used for points without an affine finish."""
     _require_jax()
@@ -633,7 +537,7 @@ def transmit_grid(items: List[GridItem]) -> List[np.ndarray]:
 def transmit_grid_finish(items: List[GridItem],
                          finishes: List[FinishSpec]) -> List[np.ndarray]:
     """Evaluate many cold-start exchanges *and their finish reductions*
-    in-kernel; returns each item's per-rank completion times (ranks
+    on the device; returns each item's per-rank completion times (ranks
     receiving no flow complete at 0.0, as in the host-side reduction).
     The 32k-rank path: device->host traffic shrinks from one float per
     wire message to one per rank."""
@@ -661,33 +565,26 @@ def transmit_grid_finish(items: List[GridItem],
 def _arr_structure(lays, n: int):
     """Stage buckets + committed static operands of one arrivals-mode
     batch (the warm driver path's per-layout structure cache entry)."""
-    stages = []
-    n_groups = []
-    for s in range(3):
-        order, _, counts, offsets = lays[s]
-        stages.append(_stage_buckets(order, counts, offsets, n))
-        n_groups.append(len(counts))
-    (b1, pos1, s1), (b2, pos2, s2), (b3, pos3, s3) = stages
-    (st1m, st2m, st3m), statics, grp_orders = _pack_stage_ops(
-        b1, b2, b3, pos1, pos2)
+    core, statics, pos3, _, grp_orders = _stage_ops(lays, n)
     statics.append(pos3.astype(np.int32))
-    core = dict(n=n, st1=st1m, st2=st2m, st3=st3m, sizes=(s1, s2, s3),
-                n_groups=tuple(n_groups), finf=(), n_flows=0, finr=(),
-                n_rank_out=0)
-    return core, [jnp.asarray(a) for a in statics], tuple(grp_orders)
+    return core, [jnp.asarray(a) for a in statics], grp_orders
 
 
 class PallasFabric(JaxFabric):
-    """Fused-kernel fabric: one Pallas program per staged batch.
+    """Kernel fabric: one jitted scan-kernel program per staged batch.
 
     Scalar state stays authoritative on the Python side exactly as in
     the jax engine — warm semantics (steady-state iterations, dependent
     RMA traffic between batches) are identical.  A staged batch folds
     the warm VCI owners into the host cost precompute, passes the
-    per-resource busy-until clocks as the kernel's init vectors, and
+    per-resource busy-until clocks as the kernels' init vectors, and
     writes the carried-out clocks back.  Tiny or narrow batches take
     the same bit-identical scalar fallback as the other engines.
     """
+
+    def __init__(self, cfg: NetConfig, n_vcis: int, n_ranks: int = 2):
+        super().__init__(cfg, n_vcis, n_ranks=n_ranks)
+        _kernel_dtype()  # fail at construction, not mid-simulation
 
     def transmit_arrays(self, t_ready, nbytes, vci, thread, put, am_copy,
                         src, dst, *, layout_key=None):
@@ -727,7 +624,7 @@ class PallasFabric(JaxFabric):
                  for c in lays[2][1].tolist()]
         state3 = np.array([self.wire_free.get(sd, 0.0) for sd in links])
 
-        dtype = jnp.float64 if x64_enabled() else jnp.float32
+        dtype = _kernel_dtype()
         dyn = [jnp.asarray(a, dtype) for a in
                (t_ready, c1, c3, rdv, state1[grp_orders[0]],
                 state2[grp_orders[1]], state3[grp_orders[2]])]
@@ -736,8 +633,8 @@ class PallasFabric(JaxFabric):
         arr, cur1, cur2, cur3 = _build_call(meta)(consts, *dyn, *statics)
         arrivals = np.asarray(arr, dtype=np.float64)
 
-        # warm state out: the kernel's cur vectors are in bucket-group
-        # order; unsort them back to each stage's group (resource) order
+        # warm state out: the cur vectors are in bucket-group order;
+        # unsort them back to each stage's group (resource) order
         s1o = np.empty(len(banks))
         s1o[grp_orders[0]] = np.asarray(cur1, dtype=np.float64)
         # a bank's final owner is its last queued message's thread — a

@@ -19,7 +19,6 @@ from typing import Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
-from ..compat import axis_size
 
 NEG_INF = -2.3819763e38
 
@@ -34,7 +33,7 @@ def _flat_index(axes: Tuple[str, ...]) -> jax.Array:
     """Row-major rank of this device within the given mesh axes."""
     idx = jnp.int32(0)
     for a in axes:
-        idx = idx * axis_size(a) + jax.lax.axis_index(a)
+        idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return idx
 
 

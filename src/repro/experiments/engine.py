@@ -695,6 +695,12 @@ def run_records(runner: str, points: Sequence[Mapping[str, Any]],
                 jobs: int = 1,
                 engine: str = DEFAULT_ENGINE) -> Dict[str, Dict[str, float]]:
     """Run deduplicated points through one runner; returns key -> metrics."""
+    if jobs > 1 and engine in ("jax", "pallas"):
+        # the batched path below runs in this process, which then holds
+        # the accelerator, and a forked worker that touches it fails or
+        # hangs: the compiled engines run in one process
+        raise ValueError(f"jobs={jobs} needs a NumPy engine; engine="
+                         f"{engine!r} runs on the device in this process")
     keyed: Dict[str, Dict[str, Any]] = {}
     for p in points:
         keyed.setdefault(record_key(p), dict(p))

@@ -1,8 +1,7 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On this CPU container every wrapper runs the kernel in interpret mode
-(``REPRO_PALLAS_INTERPRET=1`` default here); on a real TPU deployment the
-flag flips off and the same call sites emit Mosaic kernels.  The flag is
+On the CPU backend every wrapper runs the kernel in interpret mode; on a
+TPU the same call sites emit Mosaic kernels.  The flag is
 resolved lazily *per call* through :func:`repro.kernels.runtime
 .interpret_mode` and enters each jit as a static argument, so toggling
 it (tests, the pallas fabric engine) selects a different trace instead

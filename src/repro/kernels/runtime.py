@@ -3,10 +3,10 @@
 One resolver for ``REPRO_PALLAS_INTERPRET``, read *per call* rather than
 once at import: tests (and the pallas fabric engine) can toggle the
 environment variable — or use :func:`force_interpret` — without
-reimporting every module that consults it.  On this CPU container the
-flag defaults to on (kernels run through the Pallas interpreter); on a
-real TPU deployment it flips off and the same call sites emit Mosaic
-kernels.
+reimporting every module that consults it.  Unset, kernels run through
+the Pallas interpreter only when JAX's default backend is the CPU; on a
+TPU the same call sites emit Mosaic kernels.  ``REPRO_PALLAS_INTERPRET``
+set to ``1`` or ``0`` overrides that either way.
 
 Callers must treat the flag as a *static* compilation option: jitted
 wrappers pass it as a static argument (or key their trace caches on it)
@@ -26,7 +26,11 @@ def interpret_mode() -> bool:
     """Resolve the interpret switch now (not at import time)."""
     if _FORCED is not None:
         return _FORCED
-    return os.environ.get("REPRO_PALLAS_INTERPRET", "1") == "1"
+    env = os.environ.get("REPRO_PALLAS_INTERPRET")
+    if env is not None:
+        return env == "1"
+    import jax
+    return jax.default_backend() == "cpu"
 
 
 class force_interpret:

@@ -40,7 +40,6 @@ from repro.configs import ARCH_IDS, get_config
 from repro.configs.shapes import SHAPES, cells
 from repro.launch import hlo_analysis
 from repro.launch.mesh import make_production_mesh
-from repro.compat import set_mesh
 from repro.launch.steps import (StepConfig, make_decode_step,
                                 make_prefill_step, make_train_step)
 
@@ -55,7 +54,7 @@ def lower_cell(arch_id: str, shape_name: str, *, multi_pod: bool,
     shape = SHAPES[shape_name]
     mesh = make_production_mesh(multi_pod=multi_pod)
     cfg = get_config(arch_id)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             step_fn, state_structs, batch_structs, _ = make_train_step(
                 cfg, mesh, scfg, seq_len=shape.seq_len,

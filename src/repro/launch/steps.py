@@ -133,9 +133,11 @@ def _apply_overrides(cfg, scfg):
 
 def make_train_step(cfg, mesh, scfg: StepConfig, *, seq_len: int,
                     global_batch: int):
-    """Returns (step_fn, state_structs, batch_structs, shardings).
+    """Returns (step_fn, state_structs, batch_structs, grad_fn).
 
     step_fn(state, batch) -> (state, loss); state = {'params', 'opt'}.
+    grad_fn(params, batch) -> (loss, grads): the synced gradients the
+    step applies (what the sync modes must agree on).
     """
     cfg = cfg.with_tp(model_size(mesh)).replace(param_dtype=scfg.param_dtype)
     cfg = _apply_overrides(cfg, scfg)
@@ -195,8 +197,7 @@ def make_train_step(cfg, mesh, scfg: StepConfig, *, seq_len: int,
 
     state_structs = {"params": with_sh(params_struct, psh),
                      "opt": with_sh(opt_struct, opt_sh)}
-    shardings = {"params": psh, "opt": opt_sh}
-    return step_fn, state_structs, batch_structs, shardings
+    return step_fn, state_structs, batch_structs, grad_fn
 
 
 # ---------------------------------------------------------------------------
@@ -284,6 +285,7 @@ def make_decode_step(cfg, mesh, scfg: StepConfig, *, seq_len: int,
     ``seq_len`` is the KV-cache length; one new token is decoded.
     """
     cfg = cfg.with_tp(model_size(mesh)).replace(param_dtype=scfg.param_dtype)
+    cfg = _apply_overrides(cfg, scfg)
     dp = dp_axes(mesh)
     batch_shardable = global_batch >= dp_size(mesh) \
         and global_batch % dp_size(mesh) == 0

@@ -18,20 +18,19 @@ import time
 from pathlib import Path
 
 import jax
-import jax.numpy as jnp
-import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.ckpt.checkpoint import AsyncCheckpointer, latest_step, restore
 from repro.configs import ARCH_IDS, get_config, get_smoke_config
 from repro.data import pipeline
-from repro.launch.mesh import dp_axes, model_size
+from repro.launch.mesh import model_size
 from repro.launch.steps import StepConfig, make_train_step
 from repro.models import lm
 from repro.optim.adamw import AdamWConfig, init_opt_state
 from repro.runtime import elastic
-from repro.compat import set_mesh
-from repro.runtime.fault_tolerance import (Heartbeat, StragglerMonitor,
+from repro.runtime.compile_cache import enable_compile_cache
+from repro.runtime.fault_tolerance import (Heartbeat, LoopReport,
+                                           StragglerMonitor,
                                            run_training_loop)
 
 
@@ -44,7 +43,7 @@ def build_state(cfg, mesh, scfg):
     return {"params": params, "opt": opt}
 
 
-def main():
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b", choices=ARCH_IDS)
     ap.add_argument("--smoke", action="store_true",
@@ -70,8 +69,11 @@ def main():
     ap.add_argument("--param-dtype", default="float32")
     ap.add_argument("--peak-lr", type=float, default=3e-4)
     ap.add_argument("--log-every", type=int, default=10)
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def model_config(args):
+    """The run's model config: registered (or smoke) config + overrides."""
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if args.scale != 1.0:
         cfg = cfg.replace(d_model=int(cfg.d_model * args.scale),
@@ -83,19 +85,35 @@ def main():
             if v}
     if over:
         cfg = cfg.replace(**over, head_dim=0)
-    cfg = cfg.replace(param_dtype=args.param_dtype)
+    return cfg.replace(param_dtype=args.param_dtype)
 
+
+def step_config(args, plan) -> StepConfig:
+    return StepConfig(sync_mode=args.sync, aggr_bytes=args.aggr_bytes,
+                      param_dtype=args.param_dtype, peak_lr=args.peak_lr,
+                      warmup_steps=max(args.steps // 10, 1),
+                      total_steps=args.steps, seq_parallel=plan.model > 1)
+
+
+def put_batch(batch, batch_structs):
+    """Place a host batch with the step's batch shardings (split over the
+    data axes), not on the default device alone."""
+    return {k: jax.device_put(v, batch_structs[k].sharding)
+            for k, v in batch.items()}
+
+
+def run(args) -> LoopReport:
+    """Train ``args.steps`` steps; returns the loop's report."""
+    enable_compile_cache()
+    cfg = model_config(args)
     plan = elastic.plan_mesh(len(jax.devices()), args.tp)
     mesh = elastic.build_mesh(plan)
     print(f"mesh: data={plan.data} model={plan.model} "
           f"(devices={plan.n_devices})")
 
-    scfg = StepConfig(sync_mode=args.sync, aggr_bytes=args.aggr_bytes,
-                      param_dtype=args.param_dtype, peak_lr=args.peak_lr,
-                      warmup_steps=max(args.steps // 10, 1),
-                      total_steps=args.steps, seq_parallel=plan.model > 1)
-    with set_mesh(mesh):
-        step_fn, _, _, shardings = make_train_step(
+    scfg = step_config(args, plan)
+    with jax.set_mesh(mesh):
+        step_fn, _, batch_structs, _ = make_train_step(
             cfg, mesh, scfg, seq_len=args.seq_len,
             global_batch=args.global_batch)
         jit_step = jax.jit(step_fn, donate_argnums=0)
@@ -120,7 +138,7 @@ def main():
                 print(f"step {step:5d} loss {loss:.4f}", flush=True)
 
         def get_batch(step):
-            return {k: jnp.asarray(v) for k, v in stream.batch(step).items()}
+            return put_batch(stream.batch(step), batch_structs)
 
         checkpointer = AsyncCheckpointer(ckpt_dir)
         t0 = time.time()
@@ -138,6 +156,11 @@ def main():
               f"final ckpt step {report.final_step}")
         if report.straggler_steps:
             print(f"stragglers at {report.straggler_steps}")
+    return report
+
+
+def main(argv=None):
+    run(parse_args(argv))
     return 0
 
 

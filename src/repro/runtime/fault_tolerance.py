@@ -114,6 +114,7 @@ class Heartbeat:
             self._stamp()
 
     def __enter__(self):
+        self.path.parent.mkdir(parents=True, exist_ok=True)
         # Stamp synchronously before the thread's first interval elapses:
         # a watchdog polling a fresh rank must see liveness immediately,
         # not after ``interval`` seconds of looking stale.
@@ -163,6 +164,7 @@ class LoopReport:
     preempted: bool
     straggler_steps: List[int]
     losses: List[float]
+    step_s: List[float] = field(default_factory=list)  # wall time per step
 
 
 def run_training_loop(*, step_fn: Callable, state, start_step: int,
@@ -183,6 +185,7 @@ def run_training_loop(*, step_fn: Callable, state, start_step: int,
     """
     straggler = straggler or StragglerMonitor()
     losses: List[float] = []
+    step_s: List[float] = []
     preempted = False
     # ``completed`` is the step id the current ``state`` belongs to:
     # advanced the moment step_fn returns the new state, so the final
@@ -198,6 +201,7 @@ def run_training_loop(*, step_fn: Callable, state, start_step: int,
                 loss = float(loss)
                 losses.append(loss)
                 dt = time.perf_counter() - t0
+                step_s.append(dt)
                 if straggler.record(step, dt):
                     print(f"[straggler] step {step}: {dt:.3f}s "
                           f"(median {straggler.median:.3f}s)")
@@ -223,4 +227,4 @@ def run_training_loop(*, step_fn: Callable, state, start_step: int,
     return LoopReport(steps_run=len(losses), final_step=completed,
                       preempted=preempted,
                       straggler_steps=list(straggler.straggler_steps),
-                      losses=losses)
+                      losses=losses, step_s=step_s)

@@ -43,6 +43,26 @@ def ready(n_threads, theta, seed):
     return rng.uniform(0.0, 25e-6, size=(n_threads, theta))
 
 
+def grid_items(points):
+    """Assemble GridItems + FinishSpecs for the low-level grid entry
+    points, the way ``simulate_stencil_grid`` does internally."""
+    from repro.core import fabric_jax as fj
+    items, fins = [], []
+    for p in points:
+        prep = sim._prepare_stencil(**p)
+        order = sim._merge_order(prep.cols["t_ready"], prep.memo_key)
+        c = prep.cols
+        items.append(fj.GridItem(
+            t_ready=c["t_ready"][order], nbytes=c["nbytes"][order],
+            vci=c["vci"][order], thread=c["thread"][order],
+            put=c["put"][order], am_copy=c["am_copy"][order],
+            src=c["src"][order], dst=c["dst"][order],
+            cfg=prep.cfg, n_vcis=prep.n_vcis, n_ranks=prep.n_ranks,
+            key=prep.memo_key))
+        fins.append(sim._pallas_finish_spec(prep, order))
+    return items, fins
+
+
 @contextlib.contextmanager
 def forced_scans():
     """Route every batch through the staged scans / fused kernels,

@@ -10,10 +10,11 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import chunked_collectives as cc
 from repro.compat import shard_map
+from repro.launch.mesh import make_mesh
 
 N = jax.device_count()
 assert N == 8, N
-mesh = jax.make_mesh((N,), ("x",))
+mesh = make_mesh((N,), ("x",))
 key = jax.random.PRNGKey(0)
 
 

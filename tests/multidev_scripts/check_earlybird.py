@@ -20,12 +20,13 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.earlybird import SyncConfig, value_and_synced_grad
 from repro.configs import get_smoke_config
+from repro.launch.mesh import make_mesh
 from repro.models import lm
 from repro.compat import shard_map
 
 jax.config.update("jax_threefry_partitionable", True)
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 cfg = get_smoke_config("llama3.2-1b")
 params = lm.init_params(cfg, jax.random.PRNGKey(0))
 B, S = 8, 32

@@ -10,9 +10,10 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.flash_decode import flash_decode_ref, flash_decode_shard
 from repro.compat import shard_map
+from repro.launch.mesh import make_mesh
 
 N = jax.device_count()
-mesh = jax.make_mesh((N,), ("x",))
+mesh = make_mesh((N,), ("x",))
 B, H, KV, D, S = 2, 4, 2, 16, 64
 key = jax.random.PRNGKey(0)
 kq, kk, kv = jax.random.split(key, 3)

@@ -13,16 +13,16 @@ import jax.numpy as jnp
 
 from repro.configs import get_smoke_config
 from repro.launch import hlo_analysis
-from repro.compat import set_mesh
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import (StepConfig, make_decode_step,
                                 make_prefill_step, make_train_step)
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 scfg = StepConfig(param_dtype="float32")  # CPU compile, no bf16 passes
 
 for arch in ("llama3.2-1b", "granite-moe-3b-a800m", "mamba2-780m"):
     cfg = get_smoke_config(arch)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         # train
         step_fn, state_structs, batch_structs, _ = make_train_step(
             cfg, mesh, scfg, seq_len=64, global_batch=4)

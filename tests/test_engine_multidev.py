@@ -4,16 +4,7 @@ Each test runs in a subprocess with 8 fake host devices so the main pytest
 process keeps exactly one device (dry-run isolation requirement).
 """
 
-import jax
 import pytest
-
-# The engine's grad-sync and launch paths run partial-auto shard_map
-# (manual DP axes, auto TP axes) with sharding constraints inside — on
-# jax < 0.5 (no jax.shard_map) that combination aborts XLA with
-# `Check failed: sharding.IsManualSubgroup()`.
-partial_auto = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="partial-auto shard_map unsupported by this jax/jaxlib")
 
 
 @pytest.mark.slow
@@ -23,7 +14,6 @@ def test_ring_collectives(multidev):
 
 
 @pytest.mark.slow
-@partial_auto
 def test_earlybird_grad_sync(multidev):
     out = multidev("check_earlybird.py")
     assert "ALL-OK" in out
@@ -38,7 +28,6 @@ def test_flash_decode(multidev):
 
 
 @pytest.mark.slow
-@partial_auto
 def test_launch_steps_mini_dryrun(multidev):
     """Train/prefill/decode lower+compile on an 8-device (2x4) mesh across
     dense / MoE / SSM families — the production dry-run path, in pytest."""

@@ -19,9 +19,9 @@ Driver invocation and comparison fields come from the shared table in
 (the shared ``REPRO_PALLAS_INTERPRET`` resolver in
 :mod:`repro.kernels.runtime`), which executes the same program through
 XLA — the differential guarantees carry to compiled TPU runs because
-the operand protocol and program are identical.  The
-``REPRO_PALLAS_GRID=bucket`` layout (one program instance per scan
-bucket) is diffed against the default fused layout.  The 32768-rank
+the operand protocol and program are identical; their v5e compile
+is pinned by ``tests/test_tpu_compile.py``.  Lane and depth tiling of
+the scan kernels is diffed against the single-tile layout.  The 32768-rank
 ``weak_scaling_xxl`` smoke tier must finish within budget and
 reproduce the committed baseline; the full XXL grid is ``slow``-marked.
 """
@@ -37,7 +37,7 @@ jax = pytest.importorskip("jax")
 
 from _engines import (APPROACHES, F32_RTOL, PIPELINED,  # noqa: E402
                       assert_engines_agree, assert_results_close,
-                      forced_scans as forced, ready)
+                      forced_scans as forced, grid_items, ready)
 from repro import compat  # noqa: E402
 from repro.core import fabric_jax as fj  # noqa: E402
 from repro.core import fabric_pallas as fp  # noqa: E402
@@ -51,25 +51,6 @@ except ImportError:  # env without hypothesis: deterministic fallback
     from _hypo import given, settings, st
 
 PV = ("pallas", "vector")
-
-
-def _grid_items(points):
-    """Assemble GridItems + FinishSpecs for the low-level grid entry
-    points, the way ``simulate_stencil_grid`` does internally."""
-    items, fins = [], []
-    for p in points:
-        prep = sim._prepare_stencil(**p)
-        order = sim._merge_order(prep.cols["t_ready"], prep.memo_key)
-        c = prep.cols
-        items.append(fj.GridItem(
-            t_ready=c["t_ready"][order], nbytes=c["nbytes"][order],
-            vci=c["vci"][order], thread=c["thread"][order],
-            put=c["put"][order], am_copy=c["am_copy"][order],
-            src=c["src"][order], dst=c["dst"][order],
-            cfg=prep.cfg, n_vcis=prep.n_vcis, n_ranks=prep.n_ranks,
-            key=prep.memo_key))
-        fins.append(sim._pallas_finish_spec(prep, order))
-    return items, fins
 
 
 class TestX64BitForBit:
@@ -206,27 +187,32 @@ class TestGridPath:
         """The in-kernel arrivals output (the non-affine-finish escape
         hatch) equals the jax engine's grid arrivals bit-for-bit."""
         with compat.x64_mode(True):
-            items, _ = _grid_items(self.POINTS)
+            items, _ = grid_items(self.POINTS)
             got = fp.transmit_grid(items)
             ref = fj.transmit_grid(items)
             for g, r in zip(got, ref):
                 assert np.array_equal(np.asarray(g), np.asarray(r))
 
-    def test_bucket_grid_layout_matches_fused(self, monkeypatch):
-        """REPRO_PALLAS_GRID=bucket (one program instance per scan
-        bucket — the compiled-TPU layout) produces bit-identical rank
-        finish times to the default fused single program."""
+    def test_tiled_grid_matches_single_tile(self, monkeypatch):
+        """Tiny VMEM and depth budgets split every scan bucket into
+        several lane tiles and carry the recurrence across depth tiles;
+        rank finish times and arrivals stay bit-identical to the
+        default tiling."""
+        pts = [dict(self.POINTS[0], approach=ap, dims=(8, 8, 4))
+               for ap in ("pt2pt_single", "part")]
         with compat.x64_mode(True):
-            items, fins = _grid_items(self.POINTS)
-            assert all(f is not None for f in fins)
+            items, fins = grid_items(pts)
             fp.clear_memos()
-            fused = fp.transmit_grid_finish(items, fins)
-            monkeypatch.setenv("REPRO_PALLAS_GRID", "bucket")
+            wide = fp.transmit_grid_finish(items, fins)
+            wide_arr = fp.transmit_grid(items)
+            monkeypatch.setattr(fp, "VMEM_BLOCK_BUDGET", 1)
+            monkeypatch.setattr(fp, "MAX_TILE_DEPTH", 3)
+            assert fp._tiles(7, 3000) == (3, 9, 8, 24)
             fp.clear_memos()
-            bucket = fp.transmit_grid_finish(items, fins)
-            monkeypatch.delenv("REPRO_PALLAS_GRID")
+            tiled = fp.transmit_grid_finish(items, fins)
+            tiled_arr = fp.transmit_grid(items)
             fp.clear_memos()
-            for a, b in zip(fused, bucket):
+            for a, b in zip(wide + wide_arr, tiled + tiled_arr):
                 assert np.array_equal(a, b)
 
     def test_run_records_batched(self):
@@ -242,6 +228,13 @@ class TestGridPath:
             assert metrics["n_messages"] == ref["n_messages"]
             assert metrics["time_us"] == pytest.approx(
                 ref["time_us"], rel=10 * F32_RTOL, abs=1e-9)
+
+    def test_process_pool_refused_for_device_engines(self):
+        """A worker forked after this process touched the device could
+        not reach it: jobs > 1 is for the NumPy engines only."""
+        from repro.experiments.engine import run_records
+        with pytest.raises(ValueError, match="jobs=2"):
+            run_records("stencil", self.POINTS, jobs=2, engine="pallas")
 
     def test_batched_path_declines_other_runners(self):
         from repro.experiments.engine import run_records_batched
@@ -260,6 +253,31 @@ class TestInterpretResolver:
                 assert rt.interpret_mode() is False
             assert rt.interpret_mode() is True
         assert rt.interpret_mode() is base
+
+    def test_default_follows_backend(self, monkeypatch):
+        """Unset, the interpreter runs only on the CPU backend; the
+        environment variable overrides either way."""
+        monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
+        assert rt.interpret_mode() is True  # this suite runs on the CPU
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert rt.interpret_mode() is False
+        monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+        assert rt.interpret_mode() is True
+        monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+        monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+        assert rt.interpret_mode() is False
+
+    def test_x64_off_the_cpu_raises(self, monkeypatch):
+        """Mosaic has no float64: under x64 on an accelerator backend the
+        engine refuses at construction instead of running interpreted or
+        silently in float32."""
+        from repro.core import fabric as fb
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with compat.x64_mode(True):
+            with pytest.raises(RuntimeError, match="float64"):
+                fp.PallasFabric(fb.DEFAULT_NET, 2, n_ranks=4)
+        with compat.x64_mode(False):
+            fp.PallasFabric(fb.DEFAULT_NET, 2, n_ranks=4)
 
     def test_kernel_matches_across_modes(self, forced_scans):
         """Interpret on/off must not change results (on CPU both
@@ -311,3 +329,30 @@ class TestWeakScalingXXL:
         for key in rp:
             for metric, val in rp[key].items():
                 assert val == rj[key][metric], (key, metric)
+
+
+class TestWarmWaves:
+    """The warm-state path: admission waves through one live fabric,
+    each starting while the previous still holds VCIs, NICs and wires —
+    the kernels' init vectors carry that state in and out."""
+
+    def test_waves_match_vector_bitwise(self):
+        from chip_smoke import wave_traffic
+        from repro.core import fabric as fb
+        cols = wave_traffic(seed=3, n_ranks=512, per_rank=16)
+        with compat.x64_mode(True):
+            pal = fp.PallasFabric(fb.DEFAULT_NET, 4, n_ranks=512)
+            vec = fb.Fabric(fb.DEFAULT_NET, 4, n_ranks=512)
+            calls = fp._build_call.cache_info()
+            shift = 0.0
+            for _ in range(3):
+                wave = dict(cols, t_ready=cols["t_ready"] + shift)
+                ap, av = pal.advance(**wave), vec.advance(**wave)
+                assert np.array_equal(ap, av)
+                shift = 0.5 * float(av.max())
+            after = fp._build_call.cache_info()
+            assert after.hits + after.misses == calls.hits + calls.misses + 3
+        assert pal.nic_free == vec.nic_free
+        assert pal.vci_free == vec.vci_free
+        assert pal.vci_last_thread == vec.vci_last_thread
+        assert pal.wire_free == vec.wire_free

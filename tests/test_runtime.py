@@ -148,6 +148,13 @@ class TestHeartbeat:
             assert doc["step"] == 0
             assert doc["pid"] == os.getpid()
 
+    def test_creates_missing_parent_directory(self, tmp_path):
+        """A fresh checkpoint directory: the heartbeat makes its parent
+        instead of failing on the first stamp."""
+        path = tmp_path / "ckpt" / "arch" / "heartbeat.json"
+        with Heartbeat(path, interval=60.0):
+            assert json.loads(path.read_text())["step"] == 0
+
     def test_background_stamp_carries_updated_step(self, tmp_path):
         path = tmp_path / "hb.json"
         with Heartbeat(path, interval=0.02) as hb:

@@ -27,7 +27,11 @@ recurrence ``t[k] = max(r[k], t[k-1]) + c[k]`` run by a Pallas kernel:
   * the gathers between stage layouts and the finish reduction (per-flow
     max arrival + affine finish offsets + per-rank max) run in XLA
     around the kernels, in the same jitted program — a 32k-rank point
-    returns 32768 floats instead of 1.6M arrivals.
+    returns 32768 floats instead of 1.6M arrivals;
+  * each host stage of a grid call — assembly (``repro.fabric.operands``
+    and its children), the copy to the device (``h2d``), ``launch`` and
+    the wait for the result (``readback``) — is a profiler span
+    (:mod:`repro.runtime.spans`).
 
 Precision contract: the kernels compute in float32 on the chip (Mosaic
 has no float64), tolerance-close to ``ReferenceFabric``.  Under
@@ -53,6 +57,7 @@ from .fabric import NetConfig
 from .fabric_jax import (HAVE_JAX, GridItem, JaxFabric, _consts,
                          _raw_layouts, _require_jax, x64_enabled)
 from ..kernels import runtime as _rt
+from ..runtime.spans import span
 
 if HAVE_JAX:
     import jax
@@ -383,7 +388,9 @@ def _assemble(items: List[GridItem],
     finish reduction's flow/rank groupings sort globally.  Returns
     ``(core, dyn, statics, aux)``: the structure dict :func:`_runtime_meta`
     completes, float64 dynamic operands, integer static operands, and
-    the host-side unpack info."""
+    the host-side unpack info.  Its stages are the spans
+    ``repro.fabric.layouts``, ``cost_columns``, ``stage_ops`` and
+    ``finish_layout``."""
     N = sum(len(it) for it in items)
     tr = np.empty(N)
     c1 = np.empty(N)
@@ -396,12 +403,14 @@ def _assemble(items: List[GridItem],
     for k, it in enumerate(items):
         n = len(it)
         sl = slice(base, base + n)
-        lays = _raw_layouts(it.src, it.dst, it.vci % it.n_vcis,
-                            it.n_vcis, it.n_ranks, it.key)
+        with span("fabric.layouts"):
+            lays = _raw_layouts(it.src, it.dst, it.vci % it.n_vcis,
+                                it.n_vcis, it.n_ranks, it.key)
         tr[sl] = it.t_ready
-        c1[sl], c3[sl], rdv[sl] = _cost_columns(
-            it.t_ready, it.nbytes, it.thread, it.put, it.am_copy,
-            it.cfg, lays[0], None)
+        with span("fabric.cost_columns"):
+            c1[sl], c3[sl], rdv[sl] = _cost_columns(
+                it.t_ready, it.nbytes, it.thread, it.put, it.am_copy,
+                it.cfg, lays[0], None)
         for s in range(3):
             o, u, cnt, f = lays[s]
             st_lays[s][0].append(o + base)
@@ -418,8 +427,10 @@ def _assemble(items: List[GridItem],
             rbase += fin.n_ranks
         item_lens.append(n)
         base += n
-    lays = [tuple(np.concatenate(part) for part in lay) for lay in st_lays]
-    core, statics, pos3, s3, _ = _stage_ops(lays, N)
+    with span("fabric.stage_ops"):
+        lays = [tuple(np.concatenate(part) for part in lay)
+                for lay in st_lays]
+        core, statics, pos3, s3, _ = _stage_ops(lays, N)
     n_groups = [len(lay[2]) for lay in lays]
     dyn = [tr, c1, c3, rdv, np.zeros(n_groups[0]),
            np.zeros(n_groups[1]), np.zeros(n_groups[2])]
@@ -427,29 +438,31 @@ def _assemble(items: List[GridItem],
     if finishes is None:
         statics.append(pos3.astype(np.int32))
         return core, dyn, statics, aux
-    fid = np.concatenate(fid_l)
-    foff = np.concatenate(foff_l)
-    fdst = np.concatenate(fdst_l)
-    F = len(foff)
-    of, uf, cf, ff = _fb._group_layout(fid)
-    if len(uf) != F:
-        raise ValueError("every flow needs at least one wire message")
-    fbuckets, _, _ = _stage_buckets(of, cf, ff, N)
-    pos3x = np.append(pos3, s3)
-    fperm = np.empty(F, dtype=np.int32)
-    for bk in fbuckets:
-        statics.append(pos3x[bk.idx].astype(np.int32))
-        G, go = bk.meta[4], bk.meta[5]
-        fperm[uf[bk.sel]] = go + np.arange(G, dtype=np.int32)
-    statics.append(fperm)
-    orr, ur, cr, fr = _fb._group_layout(fdst)
-    rbuckets, _, _ = _stage_buckets(orr, cr, fr, F)
-    statics += [bk.idx for bk in rbuckets]  # flow ids: gathers from fin
-    dyn.append(foff)
-    aux.update(rank_out_ids=np.concatenate([ur[bk.sel] for bk in rbuckets]),
-               item_ranks=item_ranks, n_ranks_total=rbase)
-    core.update(finf=tuple(bk.meta for bk in fbuckets), n_flows=F,
-                finr=tuple(bk.meta for bk in rbuckets))
+    with span("fabric.finish_layout"):
+        fid = np.concatenate(fid_l)
+        foff = np.concatenate(foff_l)
+        fdst = np.concatenate(fdst_l)
+        F = len(foff)
+        of, uf, cf, ff = _fb._group_layout(fid)
+        if len(uf) != F:
+            raise ValueError("every flow needs at least one wire message")
+        fbuckets, _, _ = _stage_buckets(of, cf, ff, N)
+        pos3x = np.append(pos3, s3)
+        fperm = np.empty(F, dtype=np.int32)
+        for bk in fbuckets:
+            statics.append(pos3x[bk.idx].astype(np.int32))
+            G, go = bk.meta[4], bk.meta[5]
+            fperm[uf[bk.sel]] = go + np.arange(G, dtype=np.int32)
+        statics.append(fperm)
+        orr, ur, cr, fr = _fb._group_layout(fdst)
+        rbuckets, _, _ = _stage_buckets(orr, cr, fr, F)
+        statics += [bk.idx for bk in rbuckets]  # flow ids: gathers from fin
+        dyn.append(foff)
+        aux.update(
+            rank_out_ids=np.concatenate([ur[bk.sel] for bk in rbuckets]),
+            item_ranks=item_ranks, n_ranks_total=rbase)
+        core.update(finf=tuple(bk.meta for bk in fbuckets), n_flows=F,
+                    finr=tuple(bk.meta for bk in rbuckets))
     return core, dyn, statics, aux
 
 
@@ -478,7 +491,10 @@ def clear_memos() -> None:
 def _dispatch(items: List[GridItem],
               finishes: Optional[List[FinishSpec]]):
     """Assemble (or reuse) one bucket's operands and dispatch the
-    program; returns the *unsynced* jax result plus the unpack aux."""
+    program; returns the *unsynced* jax result plus the unpack aux.
+    Its stages are the spans ``repro.fabric.operands`` (the assembly),
+    ``h2d`` (the operands' copy to the device, ``bytes`` on it) and
+    ``launch``."""
     mode = "finish" if finishes is not None else "arrivals"
     dtype = _kernel_dtype()
     key = None
@@ -487,16 +503,21 @@ def _dispatch(items: List[GridItem],
                tuple(it.key for it in items))
     entry = _OPS_MEMO.get(key) if key is not None else None
     if entry is None:
-        core, dyn, statics, aux = _assemble(items, finishes)
-        consts = jnp.asarray(np.array(_consts(items[0].cfg)), dtype)
-        ops = ([consts] + [jnp.asarray(a, dtype) for a in dyn]
-               + [jnp.asarray(a) for a in statics])
+        with span("fabric.operands"):
+            core, dyn, statics, aux = _assemble(items, finishes)
+        with span("fabric.h2d") as sp:
+            consts = jnp.asarray(np.array(_consts(items[0].cfg)), dtype)
+            ops = ([consts] + [jnp.asarray(a, dtype) for a in dyn]
+                   + [jnp.asarray(a) for a in statics])
+            sp.set_metadata(bytes=sum(a.nbytes for a in ops))
         entry = (core, ops, aux)
         if key is not None:
             _OPS_MEMO.put(key, entry)
     core, ops, aux = entry
-    meta = _runtime_meta(core, mode)
-    return _build_call(meta)(*ops), aux
+    with span("fabric.launch"):
+        meta = _runtime_meta(core, mode)
+        res = _build_call(meta)(*ops)
+    return res, aux
 
 
 def _cfg_buckets(items: List[GridItem]) -> Dict[tuple, List[int]]:
@@ -526,7 +547,8 @@ def transmit_grid(items: List[GridItem]) -> List[np.ndarray]:
         res, aux = _dispatch([items[i] for i in members], None)
         pending.append((members, res, aux))
     for members, res, aux in pending:
-        arr = np.asarray(res[0], dtype=np.float64)
+        with span("fabric.readback"):
+            arr = np.asarray(res[0], dtype=np.float64)
         o = 0
         for ln, i in zip(aux["item_lens"], members):
             out[i] = arr[o:o + ln]
@@ -551,8 +573,10 @@ def transmit_grid_finish(items: List[GridItem],
                              [finishes[i] for i in members])
         pending.append((members, res, aux))
     for members, res, aux in pending:
+        with span("fabric.readback"):
+            rank_tts = np.asarray(res, dtype=np.float64)
         full = np.zeros(aux["n_ranks_total"])
-        full[aux["rank_out_ids"]] = np.asarray(res, dtype=np.float64)
+        full[aux["rank_out_ids"]] = rank_tts
         for (rb, R), i in zip(aux["item_ranks"], members):
             out[i] = full[rb:rb + R]
     return out  # type: ignore[return-value]

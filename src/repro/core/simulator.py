@@ -85,6 +85,7 @@ from .faults import DropDraws, FaultSpec, make_faulty_fabric
 from .partition import PartitionedRequest
 from .recovery import RecoveryPolicy, make_policy
 from .topology import CartTopology, HaloSpec
+from ..runtime.spans import span
 
 # The fabric engines selectable via the drivers' ``engine`` argument.
 ENGINES = ("vector", "reference", "jax", "pallas")
@@ -1239,40 +1240,60 @@ def simulate_stencil_grid(points: Sequence[Mapping], engine: str = "jax"
     back to :func:`simulate_stencil`).  Both engines are bit-for-bit
     identical to the per-point engines under ``JAX_ENABLE_X64``;
     tolerance-close under float32.
+
+    Host stages are profiler spans (:mod:`repro.runtime.spans`):
+    ``repro.fabric.grid`` around the call, and inside it ``prepare``,
+    ``merge``, ``permute`` and ``result`` (and, on the pallas engine,
+    ``finish_spec`` and the engine's own stages).
     """
     if engine not in ("jax", "pallas"):
         raise ValueError(
             f"unknown grid engine {engine!r}; one of ('jax', 'pallas')")
-    from . import fabric_jax  # lazy: only the compiled engines need jax
-    if engine == "pallas":
-        from . import fabric_pallas
-    prepared: List[Optional[tuple]] = []
-    for p in points:
+    with span("fabric.grid"):
+        return _stencil_grid(points, engine)
+
+
+def _grid_entry(p: Mapping, fabric_jax) -> Optional[tuple]:
+    """One point assembled, merge-sorted and permuted (memoized by its
+    parameters when they hash): ``(prep, order, item, aux)``, or None
+    when the batched path cannot take it."""
+    with span("fabric.prepare"):
         try:  # hashable param sets reuse the assembled + sorted point
             pkey = ("stencil-point", tuple(sorted(p.items())))
             hash(pkey)
         except TypeError:  # e.g. ndarray-valued ready tables
             pkey = None
         entry = _GRID_MEMO.get(pkey)
-        if entry is None:
-            prep = _prepare_stencil(**p)
-            if prep is None:
-                prepared.append(None)
-                continue
-            order = _merge_order(prep.cols["t_ready"], prep.memo_key)
-            c = prep.cols
-            item = fabric_jax.GridItem(
-                t_ready=c["t_ready"][order], nbytes=c["nbytes"][order],
-                vci=c["vci"][order], thread=c["thread"][order],
-                put=c["put"][order], am_copy=c["am_copy"][order],
-                src=c["src"][order], dst=c["dst"][order],
-                cfg=prep.cfg, n_vcis=prep.n_vcis, n_ranks=prep.n_ranks,
-                key=prep.memo_key)
-            # the trailing dict accumulates engine-lazy per-point state
-            # (pallas finish spec, sent-per-rank counts)
-            entry = (prep, order, item, {})
-            _GRID_MEMO.put(pkey, entry)
-        prepared.append(entry)
+        if entry is not None:
+            return entry
+        prep = _prepare_stencil(**p)
+    if prep is None:
+        return None
+    with span("fabric.merge"):
+        order = _merge_order(prep.cols["t_ready"], prep.memo_key)
+    with span("fabric.permute"):
+        c = prep.cols
+        item = fabric_jax.GridItem(
+            t_ready=c["t_ready"][order], nbytes=c["nbytes"][order],
+            vci=c["vci"][order], thread=c["thread"][order],
+            put=c["put"][order], am_copy=c["am_copy"][order],
+            src=c["src"][order], dst=c["dst"][order],
+            cfg=prep.cfg, n_vcis=prep.n_vcis, n_ranks=prep.n_ranks,
+            key=prep.memo_key)
+    # the trailing dict accumulates engine-lazy per-point state
+    # (pallas finish spec, sent-per-rank counts)
+    entry = (prep, order, item, {})
+    _GRID_MEMO.put(pkey, entry)
+    return entry
+
+
+def _stencil_grid(points: Sequence[Mapping], engine: str
+                  ) -> List[Optional[StencilResult]]:
+    """The body of :func:`simulate_stencil_grid`."""
+    from . import fabric_jax  # lazy: only the compiled engines need jax
+    if engine == "pallas":
+        from . import fabric_pallas
+    prepared = [_grid_entry(p, fabric_jax) for p in points]
     results: List[Optional[StencilResult]] = [None] * len(prepared)
     live = [(i, e) for i, e in enumerate(prepared) if e is not None]
     if engine == "pallas":
@@ -1281,30 +1302,33 @@ def simulate_stencil_grid(points: Sequence[Mapping], engine: str = "jax"
         fin_members, arr_members = [], []
         for i, (prep, order, item, aux) in live:
             if "finish" not in aux:
-                aux["finish"] = _pallas_finish_spec(prep, order)
+                with span("fabric.finish_spec"):
+                    aux["finish"] = _pallas_finish_spec(prep, order)
             (fin_members if aux["finish"] is not None
              else arr_members).append((i, prep, order, item, aux))
         if fin_members:
             rank_tts = fabric_pallas.transmit_grid_finish(
                 [m[3] for m in fin_members],
                 [m[4]["finish"] for m in fin_members])
-            for (i, prep, _, _, aux), tts in zip(fin_members, rank_tts):
-                results[i] = _result_from_rank_tts(prep, aux, tts)
+            with span("fabric.result"):
+                for (i, prep, _, _, aux), tts in zip(fin_members, rank_tts):
+                    results[i] = _result_from_rank_tts(prep, aux, tts)
         if arr_members:
             arrs = fabric_pallas.transmit_grid(
                 [m[3] for m in arr_members])
-            for (i, prep, order, _, _), sorted_arr in zip(arr_members,
-                                                          arrs):
-                arrivals = np.empty_like(sorted_arr)
-                arrivals[order] = sorted_arr
-                results[i] = _finish_prepared(prep, arrivals)
+            with span("fabric.result"):
+                for (i, prep, order, _, _), sorted_arr in zip(arr_members,
+                                                              arrs):
+                    arrivals = np.empty_like(sorted_arr)
+                    arrivals[order] = sorted_arr
+                    results[i] = _finish_prepared(prep, arrivals)
         return results
-    arrs = iter(fabric_jax.transmit_grid([e[2] for _, e in live]))
-    for i, (prep, order, _, _) in live:
-        sorted_arr = next(arrs)
-        arrivals = np.empty_like(sorted_arr)
-        arrivals[order] = sorted_arr
-        results[i] = _finish_prepared(prep, arrivals)
+    arrs = fabric_jax.transmit_grid([e[2] for _, e in live])
+    with span("fabric.result"):
+        for (i, (prep, order, _, _)), sorted_arr in zip(live, arrs):
+            arrivals = np.empty_like(sorted_arr)
+            arrivals[order] = sorted_arr
+            results[i] = _finish_prepared(prep, arrivals)
     return results
 
 

@@ -354,7 +354,12 @@ class GridItem:
     """One cold-start exchange of a whole-grid evaluation.
 
     Columns are already in global merge order (the caller's stable sort
-    by ``t_ready``); ``key`` memoizes the stage layouts.
+    by ``t_ready``); ``key`` memoizes the stage layouts.  ``order[p]`` is
+    the flow-major index of merge-ordered message p (None: the columns
+    are in flow-major order already).  ``plan_key`` names the exchange's
+    structure — its flow-major ``src``, ``dst``, ``vci`` columns and flows
+    — independent of times: the pallas engine keeps the operands' plan
+    of a structure under it across calls.
     """
     t_ready: np.ndarray
     nbytes: np.ndarray
@@ -368,6 +373,8 @@ class GridItem:
     n_vcis: int
     n_ranks: int
     key: Optional[Hashable] = None
+    order: Optional[np.ndarray] = None
+    plan_key: Optional[Hashable] = None
 
     def __len__(self) -> int:
         return self.t_ready.shape[0]

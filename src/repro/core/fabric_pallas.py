@@ -28,10 +28,19 @@ recurrence ``t[k] = max(r[k], t[k-1]) + c[k]`` run by a Pallas kernel:
     max arrival + affine finish offsets + per-rank max) run in XLA
     around the kernels, in the same jitted program — a 32k-rank point
     returns 32768 floats instead of 1.6M arrivals;
-  * each host stage of a grid call — assembly (``repro.fabric.operands``
-    and its children), the copy to the device (``h2d``), ``launch`` and
-    the wait for the result (``readback``) — is a profiler span
-    (:mod:`repro.runtime.spans`).
+  * the host assembly of a grid call's operands is split in two, as
+    MPI's ``MPI_Psend_init`` / ``MPI_Start``: a *plan* of everything the
+    exchange's structure fixes (stage groups, depth buckets, tiles and
+    slots, the finish groupings), built once per structure and kept
+    under the items' ``plan_key`` (:func:`plan_stats`), and a
+    *composition* run on every call, which re-sorts each group's
+    members by the call's merge order and places them through the
+    plan's slots.  The plan holds no time and no order, so it answers
+    no call by itself;
+  * each host stage of a grid call — assembly (``repro.fabric.operands``,
+    with ``plan_reused`` on it, and its children), the copy to the
+    device (``h2d``), ``launch`` and the wait for the result
+    (``readback``) — is a profiler span (:mod:`repro.runtime.spans`).
 
 Precision contract: the kernels compute in float32 on the chip (Mosaic
 has no float64), tolerance-close to ``ReferenceFabric``.  Under
@@ -48,7 +57,7 @@ from __future__ import annotations
 
 import functools
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -114,45 +123,95 @@ class FinishSpec:
     Valid only for *affine* finishes (``finish_batch(flows, None, x) ==
     x + foff`` elementwise — the caller probes this): the program then
     computes per-flow max arrival + ``foff`` and the per-rank max of
-    those, returning per-rank completion times directly.
+    those, returning per-rank completion times directly.  Flows are in
+    flow-major order: flow f owns the ``lens[f]`` messages after those
+    of flows ``0..f-1`` in the item's flow-major enumeration
+    (``GridItem.order``).
     """
-    fid: np.ndarray    # (n,) flow id of each merge-ordered message
+    lens: np.ndarray   # (F,) wire messages per flow
     foff: np.ndarray   # (F,) affine finish offset per flow
     fdst: np.ndarray   # (F,) destination rank per flow
-    n_ranks: int
 
 
 @dataclass
-class _Bucket:
-    """One depth-class of a stage: ``idx[k, g]`` is the id of the k-th
-    member of the bucket's g-th segment, or the stage's sentinel on
-    padded slots; ``sel`` names the segments as indices into the
-    stage's concatenated group list; ``meta`` is ``(K_pad, S_pad, TK,
-    TS, G, go)`` — padded shape, tiles, real segment count and the
-    bucket's first segment in bucket-major group order."""
-    idx: np.ndarray
-    sel: np.ndarray
-    meta: tuple
+class _StagePlan:
+    """One stage's grouping, independent of the merge order.
 
-
-def _stage_buckets(order: np.ndarray, counts: np.ndarray,
-                   offsets: np.ndarray, sentinel: int
-                   ) -> Tuple[List[_Bucket], np.ndarray, int]:
-    """Re-bucket one stage's jagged segments by depth class.
-
-    Returns ``(buckets, pos, size)``: ``pos[i]`` is member i's slot in
-    the stage's flat scan-output vector (concatenation of the buckets'
-    raveled padded ``(K_pad, S_pad * 128)`` matrices, ``size`` total
-    slots).  ``sentinel`` (the member count) fills padded slots.
+    ``members`` lists the stage's messages group-major (groups by
+    resource id) in *canonical* ids; ``slots[j]`` is where layout
+    position j — the j-th message of the group-major layout — lands in
+    the stage's flat scan-output vector (the concatenation of its
+    buckets' raveled padded ``(K_pad, S_pad * 128)`` matrices, ``size``
+    slots).  Each bucket is one depth class; ``metas`` holds its
+    ``(K_pad, S_pad, TK, TS, G, go)`` — padded shape, tiles, real
+    segment count and first segment in bucket-major group order — and
+    ``sels`` its segments as indices into the stage's group list.
+    ``depth`` is every group's depth when they share one (``seg`` is
+    then None), else 0 and ``seg[j]`` is ``n`` times position j's group.
     """
+    members: np.ndarray
+    offsets: np.ndarray
+    depth: int
+    seg: Optional[np.ndarray]
+    slots: np.ndarray
+    size: int
+    metas: tuple
+    sels: tuple
+
+    def merged(self, inv: np.ndarray) -> np.ndarray:
+        """The group-major layout in merge positions (``inv[c]`` is
+        canonical message c's): each group's members ascending, as a
+        stable grouping of the merge-ordered column would list them.
+        Uniform depths sort the rows of a ``(G, depth)`` reshape; jagged
+        ones sort each segment under its ``seg`` offset."""
+        v = inv[self.members]
+        if self.seg is None:
+            v = v.reshape(-1, self.depth)
+            v.sort(axis=1)
+            return v.reshape(-1)
+        return np.sort(self.seg + v) - self.seg
+
+    def idx(self, layout: np.ndarray, sentinel: int) -> List[np.ndarray]:
+        """Each bucket's ``(K_pad, W)`` matrix of member ids, placed from
+        the group-major ``layout``; ``sentinel`` fills padded slots."""
+        if self.seg is None:  # one bucket, member k of group g at (k, g)
+            Kp, S = self.metas[0][:2]
+            idx = np.full((Kp, S * LANES), sentinel, dtype=np.int32)
+            rows = layout.reshape(-1, self.depth)
+            idx[:self.depth, :len(rows)] = rows.T
+            return [idx]
+        flat = np.full(self.size, sentinel, dtype=np.int32)
+        flat[self.slots] = layout
+        out, base = [], 0
+        for Kp, S, *_ in self.metas:
+            out.append(flat[base:base + Kp * S * LANES].reshape(Kp, -1))
+            base += Kp * S * LANES
+        return out
+
+    def pos(self, layout: np.ndarray, sentinel: int) -> np.ndarray:
+        """Each member's slot, extended so ``pos[sentinel]`` is the
+        sentinel slot ``size`` (what a padded slot of the next stage
+        gathers)."""
+        pos = np.empty(sentinel + 1, dtype=np.int32)
+        pos[layout] = self.slots
+        pos[sentinel] = self.size
+        return pos
+
+
+def _stage_plan(members: np.ndarray, counts: np.ndarray,
+                offsets: np.ndarray) -> _StagePlan:
+    """Re-bucket one stage's jagged groups by depth class: exact depths
+    when there are at most :data:`MAX_EXACT_DEPTHS` of them, padded
+    power-of-two classes otherwise."""
+    n = len(members)
     exact = len(np.unique(counts)) <= MAX_EXACT_DEPTHS
     if exact:
         kcls = counts
     else:  # counts >= 1 always; log2 of an exact power of two is exact
         kcls = (1 << np.ceil(np.log2(np.maximum(counts, 1)))
                 .astype(np.int64))
-    pos = np.empty(sentinel, dtype=np.int64)
-    buckets: List[_Bucket] = []
+    slots = np.empty(n, dtype=np.int64)
+    metas, sels = [], []
     base = go = 0
     for K in np.unique(kcls).tolist():
         sel = np.nonzero(kcls == K)[0]
@@ -160,21 +219,23 @@ def _stage_buckets(order: np.ndarray, counts: np.ndarray,
         TK, Kp, TS, S = _tiles(K, G)
         W = S * LANES
         cnt = counts[sel]
-        offs = offsets[sel]
-        total = int(cnt.sum())
         starts = np.zeros(G, dtype=np.int64)
         np.cumsum(cnt[:-1], out=starts[1:])
-        within = np.arange(total, dtype=np.int64) - np.repeat(starts, cnt)
+        within = np.arange(int(cnt.sum()), dtype=np.int64) \
+            - np.repeat(starts, cnt)
         col = np.repeat(np.arange(G, dtype=np.int64), cnt)
-        members = order[np.repeat(offs, cnt) + within]
-        idx = np.full((Kp, W), sentinel, dtype=np.int32)
-        idx[within, col] = members
-        pos[members] = base + within * W + col
-        buckets.append(_Bucket(idx=idx, sel=sel,
-                               meta=(Kp, S, TK, TS, G, go)))
+        slots[np.repeat(offsets[sel], cnt) + within] = base + within * W + col
+        metas.append((Kp, S, TK, TS, G, go))
+        sels.append(sel)
         base += Kp * W
         go += G
-    return buckets, pos, base
+    uniform = len(counts) > 0 and bool((counts == counts[0]).all())
+    seg = None if uniform else np.repeat(
+        np.arange(len(counts), dtype=np.int64) * n, counts)
+    return _StagePlan(members=members, offsets=offsets,
+                      depth=int(counts[0]) if uniform else 0, seg=seg,
+                      slots=slots.astype(np.int32), size=base,
+                      metas=tuple(metas), sels=tuple(sels))
 
 
 def _cost_columns(t_ready, nbytes, thread, put, am_copy, cfg: NetConfig,
@@ -217,36 +278,32 @@ def _cost_columns(t_ready, nbytes, thread, put, am_copy, cfg: NetConfig,
     return c1, c3, rdv
 
 
-def _stage_ops(lays, n: int):
-    """The three scan stages' buckets and static operands, in the order
+def _stage_ops(plans: Sequence[_StagePlan], layouts, n: int):
+    """The three scan stages' static operands, in the order
     :func:`_build_call` consumes them: per stage-1 bucket ``idx``, per
     stage-2 bucket ``pos1[idx]``, per stage-3 bucket ``idx, pos2[idx]``
-    (each ``pos`` extended so a sentinel maps to the previous stage's
-    sentinel slot).  Returns ``(core, statics, pos3, s3, grp_orders)``:
-    the structure dict :func:`_runtime_meta` completes, the statics,
-    message slots in the stage-3 output, its size, and each stage's
-    bucket-major group permutation (for warm-state vectors)."""
-    (b1, pos1, s1), (b2, pos2, s2), (b3, pos3, s3) = (
-        _stage_buckets(lay[0], lay[2], lay[3], n) for lay in lays)
-    pos1x = np.append(pos1, s1)
-    pos2x = np.append(pos2, s2)
-    statics: List[np.ndarray] = [bk.idx for bk in b1]
-    statics += [pos1x[bk.idx].astype(np.int32) for bk in b2]
-    for bk in b3:
-        statics += [bk.idx, pos2x[bk.idx].astype(np.int32)]
-    core = dict(n=n, st1=tuple(bk.meta for bk in b1),
-                st2=tuple(bk.meta for bk in b2),
-                st3=tuple(bk.meta for bk in b3), sizes=(s1, s2, s3),
-                finf=(), n_flows=0, finr=())
-    grp_orders = tuple(np.concatenate([bk.sel for bk in bks])
-                       for bks in (b1, b2, b3))
-    return core, statics, pos3, s3, grp_orders
+    (a sentinel maps to the previous stage's sentinel slot).  ``layouts``
+    are the stages' group-major layouts in message ids.  Returns ``(core,
+    statics, pos3)``: the structure dict :func:`_runtime_meta` completes,
+    the statics, and each message's slot in the stage-3 output (extended
+    by the sentinel's)."""
+    (p1, p2, p3), (o1, o2, o3) = plans, layouts
+    statics: List[np.ndarray] = p1.idx(o1, n)
+    pos1 = p1.pos(o1, n)
+    statics += [pos1[idx] for idx in p2.idx(o2, n)]
+    pos2 = p2.pos(o2, n)
+    for idx in p3.idx(o3, n):
+        statics += [idx, pos2[idx]]
+    core = dict(n=n, st1=p1.metas, st2=p2.metas, st3=p3.metas,
+                sizes=(p1.size, p2.size, p3.size), finf=(), n_flows=0,
+                finr=())
+    return core, statics, p3.pos(o3, n)
 
 
 @dataclass(frozen=True)
 class _Meta:
     """Hashable shape/structure key of one program build (the
-    ``lru_cache`` key of :func:`_build_call`): per-bucket ``_Bucket.meta``
+    ``lru_cache`` key of :func:`_build_call`): per-bucket ``_StagePlan.metas``
     tuples plus the runtime switches that select a different trace."""
     mode: str           # "finish" | "arrivals"
     f64: bool
@@ -380,89 +437,172 @@ def _runtime_meta(core: dict, mode: str) -> _Meta:
 # Super-batch assembly (host side)
 # ---------------------------------------------------------------------------
 
+@dataclass
+class _Plan:
+    """The ready-independent structure of one super-batch's operands:
+    the three scan stages' groupings and, in finish mode, the messages
+    grouped by flow (``flows``) and the flows grouped by destination
+    rank, with everything those determine.  It holds no time and no
+    order, so it answers no call: each call composes its merge orders
+    into it (:func:`_assemble`).  Canonical ids concatenate the items'
+    flow-major enumerations."""
+    stages: Tuple[_StagePlan, _StagePlan, _StagePlan]
+    item_lens: List[int]
+    flows: Optional[_StagePlan] = None
+    fperm: Optional[np.ndarray] = None   # flow -> bucket-major segment
+    rank_idx: tuple = ()                 # flow ids per rank bucket
+    finr: tuple = ()                     # rank bucket metas
+    rank_out_ids: Optional[np.ndarray] = None
+    item_ranks: tuple = ()
+    n_ranks_total: int = 0
+
+
+def _build_plan(src: Sequence[np.ndarray], dst: Sequence[np.ndarray],
+                vci: Sequence[np.ndarray], n_vcis: Sequence[int],
+                n_ranks: Sequence[int],
+                lens: Optional[Sequence[np.ndarray]] = None,
+                fdst: Optional[Sequence[np.ndarray]] = None) -> _Plan:
+    """Build the plan of a super-batch from each item's flow-major
+    ``src``, ``dst`` and ``vci`` columns, its ``n_vcis`` and ``n_ranks``
+    and, for the finish, its per-flow message counts ``lens`` and
+    destinations ``fdst``.  Groups never span items: each item's groups
+    follow the previous item's, its ids offset by the messages before
+    it."""
+    st = tuple(([], [], []) for _ in range(3))
+    base = 0
+    for s, d, v, nv, R in zip(src, dst, vci, n_vcis, n_ranks):
+        for lay, gid in zip(st, (s * nv + v % nv, s, s * R + d)):
+            o, _, cnt, off = _fb._group_layout(gid)
+            lay[0].append(o + base)
+            lay[1].append(cnt)
+            lay[2].append(off + base)
+        base += len(s)
+    plan = _Plan(stages=tuple(_stage_plan(*map(np.concatenate, lay))
+                              for lay in st),
+                 item_lens=[len(s) for s in src])
+    if lens is None:
+        return plan
+    flens = np.concatenate(lens)
+    if np.any(flens <= 0):
+        raise ValueError("every flow needs at least one wire message")
+    F = len(flens)
+    starts = np.zeros(F, dtype=np.int64)
+    np.cumsum(flens[:-1], out=starts[1:])
+    plan.flows = _stage_plan(np.arange(base, dtype=np.int64), flens,
+                             starts)
+    plan.fperm = np.empty(F, dtype=np.int32)
+    for (_, _, _, _, G, go), sel in zip(plan.flows.metas, plan.flows.sels):
+        plan.fperm[sel] = go + np.arange(G, dtype=np.int32)
+    rbase, fdst_l, item_ranks = 0, [], []
+    for fd, R in zip(fdst, n_ranks):
+        fdst_l.append(fd + rbase)
+        item_ranks.append((rbase, R))
+        rbase += R
+    orr, ur, cr, fr = _fb._group_layout(np.concatenate(fdst_l))
+    ranks = _stage_plan(orr, cr, fr)
+    plan.rank_idx = tuple(ranks.idx(orr, F))  # flow ids: gathers from fin
+    plan.finr = ranks.metas
+    plan.rank_out_ids = np.concatenate([ur[sel] for sel in ranks.sels])
+    plan.item_ranks = tuple(item_ranks)
+    plan.n_ranks_total = rbase
+    return plan
+
+
+def _flow_major(col: np.ndarray, order: Optional[np.ndarray]) -> np.ndarray:
+    """A merge-ordered column back in flow-major order."""
+    if order is None:
+        return col
+    out = np.empty_like(col)
+    out[order] = col
+    return out
+
+
+def _plan_of(items: List[GridItem],
+             finishes: Optional[List[FinishSpec]]) -> Tuple[_Plan, bool]:
+    """The super-batch's plan and whether it was kept from an earlier
+    call: kept (by the mode and the items' ``plan_key``s) only when every
+    item has a key; an item without one builds a plan for the call."""
+    key = None
+    if all(it.plan_key is not None for it in items):
+        key = ("finish" if finishes is not None else "arrivals",
+               tuple(it.plan_key for it in items))
+    plan = _PLANS.get(key)
+    if plan is not None:
+        _PLAN_COUNTS["reuses"] += 1
+        return plan, True
+    with span("fabric.plan"):
+        plan = _build_plan(
+            [_flow_major(it.src, it.order) for it in items],
+            [_flow_major(it.dst, it.order) for it in items],
+            [_flow_major(it.vci, it.order) for it in items],
+            [it.n_vcis for it in items], [it.n_ranks for it in items],
+            None if finishes is None else [f.lens for f in finishes],
+            None if finishes is None else [f.fdst for f in finishes])
+    _PLAN_COUNTS["builds"] += 1
+    _PLANS.put(key, plan)
+    return plan, False
+
+
 def _assemble(items: List[GridItem],
               finishes: Optional[List[FinishSpec]]):
     """Flatten one cfg-uniform bucket of grid items into the program's
-    operands.  Per-item stage layouts (memoized, shared with the jax
-    engine) compose by message-base offset — no global argsort; only the
-    finish reduction's flow/rank groupings sort globally.  Returns
-    ``(core, dyn, statics, aux)``: the structure dict :func:`_runtime_meta`
-    completes, float64 dynamic operands, integer static operands, and
-    the host-side unpack info.  Its stages are the spans
-    ``repro.fabric.layouts``, ``cost_columns``, ``stage_ops`` and
-    ``finish_layout``."""
-    N = sum(len(it) for it in items)
-    tr = np.empty(N)
-    c1 = np.empty(N)
-    c3 = np.empty(N)
-    rdv = np.empty(N)
-    st_lays = tuple(([], [], [], []) for _ in range(3))
-    fid_l, foff_l, fdst_l, item_ranks = [], [], [], []
-    item_lens = []
-    base = fbase = rbase = 0
-    for k, it in enumerate(items):
-        n = len(it)
-        sl = slice(base, base + n)
-        with span("fabric.layouts"):
-            lays = _raw_layouts(it.src, it.dst, it.vci % it.n_vcis,
-                                it.n_vcis, it.n_ranks, it.key)
-        tr[sl] = it.t_ready
-        with span("fabric.cost_columns"):
-            c1[sl], c3[sl], rdv[sl] = _cost_columns(
-                it.t_ready, it.nbytes, it.thread, it.put, it.am_copy,
-                it.cfg, lays[0], None)
-        for s in range(3):
-            o, u, cnt, f = lays[s]
-            st_lays[s][0].append(o + base)
-            st_lays[s][1].append(u)
-            st_lays[s][2].append(cnt)
-            st_lays[s][3].append(f + base)
-        if finishes is not None:
-            fin = finishes[k]
-            fid_l.append(fin.fid + fbase)
-            foff_l.append(fin.foff)
-            fdst_l.append(fin.fdst + rbase)
-            item_ranks.append((rbase, fin.n_ranks))
-            fbase += len(fin.foff)
-            rbase += fin.n_ranks
-        item_lens.append(n)
-        base += n
+    operands, in two parts.  The *plan* (:func:`_build_plan`, span
+    ``repro.fabric.plan`` when built) is everything the items' structure
+    fixes — each stage's groups, depth buckets, tiles and slots, the
+    finish groupings — built once per structure and kept across calls.
+    The *composition* runs on every call: it inverts the items' merge
+    orders and re-sorts each group's members by merge position (span
+    ``repro.fabric.layouts``; the finish's flow groups in
+    ``finish_layout``), computes the cost columns (``cost_columns``),
+    and places the members through the plan's slots (``stage_ops``).
+    Returns ``(core, dyn, statics, aux)``: the structure dict
+    :func:`_runtime_meta` completes, float64 dynamic operands, integer
+    static operands, and the host-side unpack info (``plan_reused``
+    says whether the plan was kept from an earlier call)."""
+    plan, reused = _plan_of(items, finishes)
+    N = sum(plan.item_lens)
+    with span("fabric.layouts"):
+        inv = np.empty(N, dtype=np.int64)
+        base = 0
+        for it in items:
+            n = len(it)
+            merged = np.arange(base, base + n, dtype=np.int64)
+            if it.order is None:
+                inv[base:base + n] = merged
+            else:
+                inv[base + it.order] = merged
+            base += n
+        layouts = [sp.merged(inv) for sp in plan.stages]
+
+    def cat(name):
+        cols = [getattr(it, name) for it in items]
+        return cols[0] if len(cols) == 1 else np.concatenate(cols)
+
+    tr = cat("t_ready")
+    with span("fabric.cost_columns"):
+        c1, c3, rdv = _cost_columns(
+            tr, cat("nbytes"), cat("thread"), cat("put"), cat("am_copy"),
+            items[0].cfg, (layouts[0], None, None, plan.stages[0].offsets),
+            None)
     with span("fabric.stage_ops"):
-        lays = [tuple(np.concatenate(part) for part in lay)
-                for lay in st_lays]
-        core, statics, pos3, s3, _ = _stage_ops(lays, N)
-    n_groups = [len(lay[2]) for lay in lays]
-    dyn = [tr, c1, c3, rdv, np.zeros(n_groups[0]),
-           np.zeros(n_groups[1]), np.zeros(n_groups[2])]
-    aux: dict = {"item_lens": item_lens}
+        core, statics, pos3 = _stage_ops(plan.stages, layouts, N)
+    dyn = [tr, c1, c3, rdv] + [np.zeros(len(sp.offsets))
+                               for sp in plan.stages]
+    aux: dict = {"item_lens": plan.item_lens, "plan_reused": reused}
     if finishes is None:
-        statics.append(pos3.astype(np.int32))
+        statics.append(pos3[:N])
         return core, dyn, statics, aux
     with span("fabric.finish_layout"):
-        fid = np.concatenate(fid_l)
-        foff = np.concatenate(foff_l)
-        fdst = np.concatenate(fdst_l)
-        F = len(foff)
-        of, uf, cf, ff = _fb._group_layout(fid)
-        if len(uf) != F:
-            raise ValueError("every flow needs at least one wire message")
-        fbuckets, _, _ = _stage_buckets(of, cf, ff, N)
-        pos3x = np.append(pos3, s3)
-        fperm = np.empty(F, dtype=np.int32)
-        for bk in fbuckets:
-            statics.append(pos3x[bk.idx].astype(np.int32))
-            G, go = bk.meta[4], bk.meta[5]
-            fperm[uf[bk.sel]] = go + np.arange(G, dtype=np.int32)
-        statics.append(fperm)
-        orr, ur, cr, fr = _fb._group_layout(fdst)
-        rbuckets, _, _ = _stage_buckets(orr, cr, fr, F)
-        statics += [bk.idx for bk in rbuckets]  # flow ids: gathers from fin
-        dyn.append(foff)
-        aux.update(
-            rank_out_ids=np.concatenate([ur[bk.sel] for bk in rbuckets]),
-            item_ranks=item_ranks, n_ranks_total=rbase)
-        core.update(finf=tuple(bk.meta for bk in fbuckets), n_flows=F,
-                    finr=tuple(bk.meta for bk in rbuckets))
+        flows = plan.flows
+        statics += [pos3[idx] for idx in flows.idx(flows.merged(inv), N)]
+        statics.append(plan.fperm)
+        statics += plan.rank_idx
+        dyn.append(np.concatenate([f.foff for f in finishes]))
+        aux.update(rank_out_ids=plan.rank_out_ids,
+                   item_ranks=plan.item_ranks,
+                   n_ranks_total=plan.n_ranks_total)
+        core.update(finf=flows.metas, n_flows=len(plan.fperm),
+                    finr=plan.finr)
     return core, dyn, statics, aux
 
 
@@ -473,17 +613,30 @@ _OPS_MEMO = _fb.CappedMemo(8)
 # Single-batch arrivals-mode structure (stage buckets + static operands)
 # for the warm-state driver path, keyed by layout key + precision.
 _ARR_MEMO = _fb.CappedMemo(32)
+# Super-batch plans, keyed by mode + the items' plan keys.  Not one of
+# memo_stats()'s memos: a plan holds no time or order, so it cannot
+# answer a call from an earlier one; it spares rebuilding the structure.
+_PLANS = _fb.CappedMemo(4)
+_PLAN_COUNTS = {"builds": 0, "reuses": 0}
 
 
 def memo_stats() -> dict:
     return {"grid_ops": _OPS_MEMO.stats(), "arrivals": _ARR_MEMO.stats()}
 
 
+def plan_stats() -> dict:
+    """Plans built, and plans kept from an earlier call (reuses)."""
+    return dict(_PLAN_COUNTS)
+
+
 def clear_memos() -> None:
-    """Reset the pallas engine's operand caches and built programs with
-    their counters (``sweep --profile`` cold pass)."""
+    """Reset the pallas engine's operand caches, plans and built programs
+    with their counters (``sweep --profile`` cold pass; tests that change
+    the tiling constants)."""
     _OPS_MEMO.clear()
     _ARR_MEMO.clear()
+    _PLANS.clear()
+    _PLAN_COUNTS.update(builds=0, reuses=0)
     _build_call.cache_clear()
     _scan_call.cache_clear()
 
@@ -503,8 +656,9 @@ def _dispatch(items: List[GridItem],
                tuple(it.key for it in items))
     entry = _OPS_MEMO.get(key) if key is not None else None
     if entry is None:
-        with span("fabric.operands"):
+        with span("fabric.operands") as sp:
             core, dyn, statics, aux = _assemble(items, finishes)
+            sp.set_metadata(plan_reused=int(aux["plan_reused"]))
         with span("fabric.h2d") as sp:
             consts = jnp.asarray(np.array(_consts(items[0].cfg)), dtype)
             ops = ([consts] + [jnp.asarray(a, dtype) for a in dyn]
@@ -589,8 +743,11 @@ def transmit_grid_finish(items: List[GridItem],
 def _arr_structure(lays, n: int):
     """Stage buckets + committed static operands of one arrivals-mode
     batch (the warm driver path's per-layout structure cache entry)."""
-    core, statics, pos3, _, grp_orders = _stage_ops(lays, n)
-    statics.append(pos3.astype(np.int32))
+    plans = [_stage_plan(order, counts, offsets)
+             for order, _, counts, offsets in lays]
+    core, statics, pos3 = _stage_ops(plans, [lay[0] for lay in lays], n)
+    statics.append(pos3[:n])
+    grp_orders = tuple(np.concatenate(sp.sels) for sp in plans)
     return core, [jnp.asarray(a) for a in statics], grp_orders
 
 

@@ -1111,6 +1111,8 @@ class _PreparedStencil:
     fabric advance — the unit the vmapped whole-grid path stacks."""
     approach: str
     sched: Schedule
+    templates: List[Scenario]      # one intent class per dimension
+    class_idx: np.ndarray          # per-flow template index
     flows: List[Scenario]          # template refs per flow (finish_batch)
     lens: np.ndarray               # per-flow wire-message counts
     cols: Dict[str, np.ndarray]    # flow-major merged message columns
@@ -1123,6 +1125,7 @@ class _PreparedStencil:
     periodic: tuple
     face_bytes: tuple
     memo_key: tuple
+    plan_key: tuple                # the structure alone: no ready table
 
 
 def _prepare_stencil(approach: str, *, dims: Sequence[int] = (),
@@ -1156,12 +1159,16 @@ def _prepare_stencil(approach: str, *, dims: Sequence[int] = (),
     if asm is None:
         return None
     flows, lens, cols, memo_key = asm
+    # what fixes every column but the times: the plan's key
+    plan_key = ("stencil", approach, topo.dims, topo.periodic, n_threads,
+                theta, n_vcis, aggr_bytes, tuple(face_bytes), cfg)
     return _PreparedStencil(
-        approach=approach, sched=sched, flows=flows, lens=lens, cols=cols,
-        dsts=dsts, n_ranks=topo.n_ranks, n_vcis=n_vcis, cfg=cfg,
+        approach=approach, sched=sched, templates=templates,
+        class_idx=fdims, flows=flows, lens=lens, cols=cols, dsts=dsts,
+        n_ranks=topo.n_ranks, n_vcis=n_vcis, cfg=cfg,
         compute=float(ready_arr.max()), dims=topo.dims,
         periodic=topo.periodic, face_bytes=tuple(face_bytes),
-        memo_key=memo_key)
+        memo_key=memo_key, plan_key=plan_key)
 
 
 def _finish_prepared(prep: _PreparedStencil,
@@ -1182,7 +1189,7 @@ def _finish_prepared(prep: _PreparedStencil,
         n_messages=int(prep.lens.sum()))
 
 
-def _pallas_finish_spec(prep: _PreparedStencil, order: np.ndarray):
+def _pallas_finish_spec(prep: _PreparedStencil):
     """The point's in-kernel finish reduction, or None when its finish
     is not affine (the pallas path then falls back to arrivals mode +
     the host-side :func:`_finish_prepared`).
@@ -1190,22 +1197,27 @@ def _pallas_finish_spec(prep: _PreparedStencil, order: np.ndarray):
     Affinity is established by probing ``finish_batch`` at 0 and 1:
     ``finish(x) == x + finish(0)`` elementwise (bitwise under IEEE-754 —
     one commutative add) certifies the kernel's ``flow_max + offset``
-    reproduces the host reduction exactly.
+    reproduces the host reduction exactly.  ``finish_batch`` is pure and
+    elementwise, and every flow re-stamps one template, so the probe runs
+    once per template and its offsets reach the flows by class index.
+    The flows' grouping itself is the engine's plan (kept per structure,
+    :func:`repro.core.fabric_pallas._build_plan`); this runs per point.
     """
     from . import fabric_pallas
     F = len(prep.lens)
     if F == 0 or np.any(prep.lens <= 0):
         return None
-    foff = prep.sched.finish_batch(prep.flows, None, np.zeros(F))
+    C = len(prep.templates)
+    foff = prep.sched.finish_batch(prep.templates, None, np.zeros(C))
     if foff is None:
         return None
-    probe = prep.sched.finish_batch(prep.flows, None, np.ones(F))
+    probe = prep.sched.finish_batch(prep.templates, None, np.ones(C))
     if probe is None or not np.array_equal(probe, 1.0 + foff):
         return None
-    fid = np.repeat(np.arange(F, dtype=np.int64), prep.lens)[order]
     return fabric_pallas.FinishSpec(
-        fid=fid, foff=np.asarray(foff, dtype=np.float64),
-        fdst=prep.dsts.astype(np.int64), n_ranks=prep.n_ranks)
+        lens=prep.lens,
+        foff=np.asarray(foff, dtype=np.float64)[prep.class_idx],
+        fdst=prep.dsts.astype(np.int64))
 
 
 def _result_from_rank_tts(prep: _PreparedStencil, aux: dict,
@@ -1279,7 +1291,7 @@ def _grid_entry(p: Mapping, fabric_jax) -> Optional[tuple]:
             put=c["put"][order], am_copy=c["am_copy"][order],
             src=c["src"][order], dst=c["dst"][order],
             cfg=prep.cfg, n_vcis=prep.n_vcis, n_ranks=prep.n_ranks,
-            key=prep.memo_key)
+            key=prep.memo_key, order=order, plan_key=prep.plan_key)
     # the trailing dict accumulates engine-lazy per-point state
     # (pallas finish spec, sent-per-rank counts)
     entry = (prep, order, item, {})
@@ -1303,7 +1315,7 @@ def _stencil_grid(points: Sequence[Mapping], engine: str
         for i, (prep, order, item, aux) in live:
             if "finish" not in aux:
                 with span("fabric.finish_spec"):
-                    aux["finish"] = _pallas_finish_spec(prep, order)
+                    aux["finish"] = _pallas_finish_spec(prep)
             (fin_members if aux["finish"] is not None
              else arr_members).append((i, prep, order, item, aux))
         if fin_members:

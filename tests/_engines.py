@@ -58,8 +58,8 @@ def grid_items(points):
             put=c["put"][order], am_copy=c["am_copy"][order],
             src=c["src"][order], dst=c["dst"][order],
             cfg=prep.cfg, n_vcis=prep.n_vcis, n_ranks=prep.n_ranks,
-            key=prep.memo_key))
-        fins.append(sim._pallas_finish_spec(prep, order))
+            key=prep.memo_key, order=order, plan_key=prep.plan_key))
+        fins.append(sim._pallas_finish_spec(prep))
     return items, fins
 
 

@@ -85,6 +85,8 @@ def test_grid_point_trace_holds_every_stage_span(engine, scale, tmp_path):
     if engine == "pallas":
         ops = by["fabric.operands"][0]
         assert all(_inside(by["fabric." + s][0], ops) for s in inner)
+        # the warm-up point of the same topology built the plan
+        assert ops[3]["plan_reused"] == 1
         # float32 release times and costs of every message, at least
         assert by["fabric.h2d"][0][3]["bytes"] > 4 * 4 * res.n_messages
 

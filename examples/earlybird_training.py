@@ -51,8 +51,8 @@ def main():
     for mode in ("bulk", "per_leaf", "partitioned"):
         sync = SyncConfig(mode=mode, axes=("data",), aggr_bytes=64 << 10)
         vg = value_and_synced_grad(
-            lambda p, bt, param_hook=None: lm.loss_fn(cfg, p, bt,
-                                                      param_hook=param_hook),
+            lambda p, bt, param_hooks=None: lm.loss_fn(cfg, p, bt,
+                                                      param_hooks=param_hooks),
             sync)
         step = jax.jit(shard_map(
             lambda p, bt: vg(p, bt), mesh=mesh,

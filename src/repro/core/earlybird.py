@@ -40,6 +40,10 @@ from .bucketing import bucketed_apply
 
 Axes = Tuple[str, ...]
 
+# the parameter keys of the scanned layer stacks (lm: "layers", and the
+# leading dense layers' "prefix"), whose gradients the layer hooks sync
+STACKS = ("layers", "prefix")
+
 
 @dataclass(frozen=True)
 class SyncConfig:
@@ -134,13 +138,14 @@ def auto_sync_config(params, *, axes: Axes = ("data",),
 
 
 def make_layer_hook(sync: SyncConfig, layer_specs=None) -> Callable:
-    """Hook wrapping each scanned layer's params (see lm.forward param_hook).
+    """Hook wrapping each scanned layer's params (see lm.forward
+    param_hooks).
 
     Identity on the forward pass; the backward rule pins the layer's
     cotangents to the parameter sharding (TP axes) and pmean-reduces the
     gradient buckets — the MPI_Pready moment of this layer.
     ``layer_specs``: pytree of per-layer-slice PartitionSpecs (leading L
-    axis dropped).  Only active in 'partitioned' mode.
+    axis dropped) of one scanned stack.  Only active in 'partitioned' mode.
     """
     if sync.mode != "partitioned":
         return lambda lp: lp
@@ -161,14 +166,16 @@ def make_layer_hook(sync: SyncConfig, layer_specs=None) -> Callable:
     return hook
 
 
-def finalize_grads(grads, sync: SyncConfig, *, layers_key: str = "layers",
+def finalize_grads(grads, sync: SyncConfig, *,
+                   layers_keys: Tuple[str, ...] = STACKS,
                    param_specs=None):
     """Synchronize whatever the layer hooks did not.
 
     bulk:        everything, one bucket (aggr = inf).
     per_leaf:    everything, one collective per leaf (aggr = 0).
     partitioned: only the non-scanned params (embed/head/final_norm) —
-                 layer grads were already reduced inside the backward scan.
+                 the stacks' grads (``layers_keys``) were already reduced
+                 inside the backward scans.
     """
     grads = _constrain(grads, param_specs)
     if sync.mode == "bulk":
@@ -178,9 +185,9 @@ def finalize_grads(grads, sync: SyncConfig, *, layers_key: str = "layers",
     elif sync.mode == "per_leaf":
         out = _bucketed_pmean(grads, sync, aggr_override=0)
     else:
-        rest = {k: v for k, v in grads.items() if k != layers_key}
+        rest = {k: v for k, v in grads.items() if k not in layers_keys}
         rest_specs = ({k: v for k, v in param_specs.items()
-                       if k != layers_key} if param_specs else None)
+                       if k not in layers_keys} if param_specs else None)
         rest = _bucketed_pmean(rest, sync)
         rest = _constrain(rest, rest_specs)
         out = dict(grads)
@@ -190,27 +197,32 @@ def finalize_grads(grads, sync: SyncConfig, *, layers_key: str = "layers",
 
 def value_and_synced_grad(loss_fn: Callable, sync: SyncConfig,
                           *, has_aux: bool = False,
-                          param_specs=None, layers_key: str = "layers"
+                          param_specs=None,
+                          layers_keys: Tuple[str, ...] = STACKS
                           ) -> Callable:
     """jax.value_and_grad + the configured gradient synchronization.
 
-    ``loss_fn(params, *args, param_hook=...)`` must thread ``param_hook``
-    into its scan body (repro.models.lm.loss_fn does).
+    ``loss_fn(params, *args, param_hooks=...)`` must thread
+    ``param_hooks``, one hook per stack of ``layers_keys``, into its
+    scan bodies (repro.models.lm.loss_fn does).
     Must run inside shard_map with ``sync.axes`` as manual axes.
     ``param_specs``: full parameter PartitionSpec tree (TP axes) — used to
     pin gradient shardings inside the partial-auto shard_map.
     """
-    layer_specs = None
-    if param_specs is not None and layers_key in param_specs:
-        layer_specs = jax.tree.map(
+    def layer_specs(key):
+        if param_specs is None or key not in param_specs:
+            return None
+        return jax.tree.map(
             lambda s: type(s)(*s[1:]) if s is not None else None,
-            param_specs[layers_key],
+            param_specs[key],
             is_leaf=lambda x: x is None or hasattr(x, "index"))
-    hook = make_layer_hook(sync, layer_specs)
+
+    hooks = {key: make_layer_hook(sync, layer_specs(key))
+             for key in layers_keys}
 
     @functools.wraps(loss_fn)
     def wrapped(params, *args):
-        f = lambda p: loss_fn(p, *args, param_hook=hook)
+        f = lambda p: loss_fn(p, *args, param_hooks=hooks)
         if has_aux:
             (val, aux), grads = jax.value_and_grad(f, has_aux=True)(params)
         else:
@@ -220,7 +232,7 @@ def value_and_synced_grad(loss_fn: Callable, sync: SyncConfig,
         # the parameter dtype — the wire format — and let the optimizer
         # re-upcast for accumulation.
         grads = jax.tree.map(lambda g, p: g.astype(p.dtype), grads, params)
-        grads = finalize_grads(grads, sync, layers_key=layers_key,
+        grads = finalize_grads(grads, sync, layers_keys=layers_keys,
                                param_specs=param_specs)
         # the loss itself is cheap to sync; callers may also pmean it
         val = _pmean_flat(val, sync.axes)

@@ -42,6 +42,7 @@ from repro.launch import hlo_analysis
 from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import (StepConfig, make_decode_step,
                                 make_prefill_step, make_train_step)
+from repro.models import lm
 
 PEAK_FLOPS = 197e12      # bf16 per chip
 HBM_BW = 819e9           # B/s per chip
@@ -54,6 +55,10 @@ def lower_cell(arch_id: str, shape_name: str, *, multi_pod: bool,
     shape = SHAPES[shape_name]
     mesh = make_production_mesh(multi_pod=multi_pod)
     cfg = get_config(arch_id)
+    # a biased router's serving steps take the trained selection bias
+    bias = ({"route_bias": jax.eval_shape(
+        lambda: lm.init_route_state(cfg)["bias"])}
+        if cfg.moe is not None and cfg.moe.biased else {})
     with jax.set_mesh(mesh):
         if shape.kind == "train":
             step_fn, state_structs, batch_structs, _ = make_train_step(
@@ -66,14 +71,14 @@ def lower_cell(arch_id: str, shape_name: str, *, multi_pod: bool,
                 cfg, mesh, scfg, seq_len=shape.seq_len,
                 global_batch=shape.global_batch)
             lowered = jax.jit(step_fn, donate_argnums=2).lower(
-                p_structs, b_structs, c_structs)
+                p_structs, b_structs, c_structs, **bias)
         elif shape.kind == "decode":
             (step_fn, p_structs, c_structs, t_structs, pos_struct,
              extra) = make_decode_step(cfg, mesh, scfg,
                                        seq_len=shape.seq_len,
                                        global_batch=shape.global_batch)
             args = [p_structs, c_structs, t_structs, pos_struct]
-            kw = {}
+            kw = dict(bias)
             if extra:
                 kw["embeds"] = extra["embeds"]
             lowered = jax.jit(step_fn, donate_argnums=1).lower(*args, **kw)

@@ -31,6 +31,7 @@ from repro.optim.schedule import warmup_cosine
 
 from .mesh import all_axes, dp_axes, dp_size, model_size
 from repro.compat import shard_map
+from repro.runtime.spans import scope
 
 
 @dataclass(frozen=True)
@@ -138,11 +139,20 @@ def make_train_step(cfg, mesh, scfg: StepConfig, *, seq_len: int,
     step_fn(state, batch) -> (state, loss); state = {'params', 'opt'}.
     grad_fn(params, batch) -> (loss, grads): the synced gradients the
     step applies (what the sync modes must agree on).
+
+    A sigmoid-routed MoE (``cfg.moe.biased``) adds state['router'] =
+    {'bias', 'load'}, each (L_moe, E) f32 (``lm.init_route_state``): the
+    selection bias, which gets no gradient and no weight decay, and the
+    step's routed (token, slot) pairs per expert, summed over the data
+    axes.  The step then moves the bias by DeepSeek-V3's aux-loss-free
+    rule, ``bias += bias_rate * sign(mean load - load)``, and
+    grad_fn(params, batch, bias) -> ((loss, load), grads).
     """
     cfg = cfg.with_tp(model_size(mesh)).replace(param_dtype=scfg.param_dtype)
     cfg = _apply_overrides(cfg, scfg)
     dp = dp_axes(mesh)
     adam = scfg.adam
+    biased = cfg.moe is not None and cfg.moe.biased
 
     sync = SyncConfig(mode=scfg.sync_mode, axes=dp,
                       aggr_bytes=scfg.aggr_bytes,
@@ -152,33 +162,54 @@ def make_train_step(cfg, mesh, scfg: StepConfig, *, seq_len: int,
 
     e_shard = _e_shard_fn(mesh)
 
-    def local_loss(p, batch, param_hook=None):
-        return lm.loss_fn(cfg, p, batch, remat=scfg.remat,
-                          seq_shard=seq_shard, e_shard=e_shard,
-                          param_hook=param_hook or (lambda lp: lp),
-                          gather_targets=scfg.ce_gather_targets)
+    def local_loss(p, batch, *bias, param_hooks=None):
+        out = lm.loss_fn(cfg, p, batch, remat=scfg.remat,
+                         seq_shard=seq_shard, e_shard=e_shard,
+                         param_hooks=param_hooks,
+                         gather_targets=scfg.ce_gather_targets,
+                         route_bias=bias[0] if biased else None,
+                         with_stats=biased)
+        if not biased:
+            return out
+        loss, stats = out
+        load = stats["load"]
+        for ax in dp:
+            load = jax.lax.psum(load, ax)
+        return loss, load
 
-    vg = value_and_synced_grad(local_loss, sync, param_specs=pspecs)
+    vg = value_and_synced_grad(local_loss, sync, has_aux=biased,
+                               param_specs=pspecs)
 
     batch_structs, batch_local_specs = _batch_struct(
         cfg, seq_len, global_batch, mesh, with_labels=True)
 
     params_struct = lm.param_shapes(cfg)
+    whole = jax.tree.map(lambda _: P(), params_struct)
     grad_fn = shard_map(
         vg, mesh=mesh,
-        in_specs=(jax.tree.map(lambda _: P(), params_struct),
-                  batch_local_specs),
-        out_specs=(P(), jax.tree.map(lambda _: P(), params_struct)),
+        in_specs=(whole, batch_local_specs, *([P()] if biased else [])),
+        out_specs=((P(), P()) if biased else P(), whole),
         check_vma=False, axis_names=set(dp))
 
     def step_fn(state, batch):
-        loss, grads = grad_fn(state["params"], batch)
+        if biased:
+            bias = state["router"]["bias"]
+            (loss, load), grads = grad_fn(state["params"], batch, bias)
+        else:
+            loss, grads = grad_fn(state["params"], batch)
         lr = warmup_cosine(state["opt"]["step"], peak_lr=scfg.peak_lr,
                            warmup_steps=scfg.warmup_steps,
                            total_steps=scfg.total_steps)
-        new_params, new_opt = adamw_update(state["params"], grads,
-                                           state["opt"], lr, adam)
-        return {"params": new_params, "opt": new_opt}, loss
+        with scope("optim"):
+            new_params, new_opt = adamw_update(state["params"], grads,
+                                               state["opt"], lr, adam)
+        new_state = {"params": new_params, "opt": new_opt}
+        if biased:
+            mean = jnp.mean(load, axis=-1, keepdims=True)
+            new_state["router"] = {
+                "bias": bias + cfg.moe.bias_rate * jnp.sign(mean - load),
+                "load": load}
+        return new_state, loss
 
     # shardings / abstract inputs
     psh = param_shardings(cfg, mesh)
@@ -197,6 +228,11 @@ def make_train_step(cfg, mesh, scfg: StepConfig, *, seq_len: int,
 
     state_structs = {"params": with_sh(params_struct, psh),
                      "opt": with_sh(opt_struct, opt_sh)}
+    if biased:
+        state_structs["router"] = with_sh(
+            jax.eval_shape(lambda: lm.init_route_state(cfg)),
+            {"bias": NamedSharding(mesh, P()),
+             "load": NamedSharding(mesh, P())})
     return step_fn, state_structs, batch_structs, grad_fn
 
 
@@ -220,16 +256,18 @@ def _cache_shardings(cfg, mesh, global_batch: int):
 
 def make_prefill_step(cfg, mesh, scfg: StepConfig, *, seq_len: int,
                       global_batch: int):
-    """prefill_step(params, batch, cache) -> (logits, cache)."""
+    """prefill_step(params, batch, cache, route_bias=None) -> (logits,
+    cache); a biased router needs ``route_bias``, the trained bias."""
     cfg = cfg.with_tp(model_size(mesh)).replace(param_dtype=scfg.param_dtype)
     cfg = _apply_overrides(cfg, scfg)
     seq_shard = _seq_shard_fn(mesh, scfg.seq_parallel)
 
     e_shard = _e_shard_fn(mesh)
 
-    def prefill_step(params, batch, cache):
+    def prefill_step(params, batch, cache, route_bias=None):
         return lm.prefill(cfg, params, batch, cache=cache,
-                          seq_shard=seq_shard, e_shard=e_shard)
+                          seq_shard=seq_shard, e_shard=e_shard,
+                          route_bias=route_bias)
 
     batch_structs, _ = _batch_struct(cfg, seq_len, global_batch, mesh,
                                      with_labels=False)
@@ -282,7 +320,8 @@ def make_decode_step(cfg, mesh, scfg: StepConfig, *, seq_len: int,
                      global_batch: int):
     """decode_step(params, cache, tokens, pos) -> (logits, cache).
 
-    ``seq_len`` is the KV-cache length; one new token is decoded.
+    ``seq_len`` is the KV-cache length; one new token is decoded.  A
+    biased router needs ``route_bias``, the trained bias.
     """
     cfg = cfg.with_tp(model_size(mesh)).replace(param_dtype=scfg.param_dtype)
     cfg = _apply_overrides(cfg, scfg)
@@ -295,10 +334,12 @@ def make_decode_step(cfg, mesh, scfg: StepConfig, *, seq_len: int,
     decode_attn = (_flash_decode_fn(mesh, global_batch)
                    if scfg.flash_decode else None)
 
-    def decode_step(params, cache, tokens, pos, embeds=None):
+    def decode_step(params, cache, tokens, pos, embeds=None,
+                    route_bias=None):
         return lm.decode_step(cfg, params, cache, tokens, pos,
                               embeds=embeds, e_shard=e_shard,
-                              decode_attn=decode_attn)
+                              decode_attn=decode_attn,
+                              route_bias=route_bias)
 
     cache_struct = jax.eval_shape(
         lambda: lm.init_cache(cfg, global_batch, seq_len,

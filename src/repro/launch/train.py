@@ -40,7 +40,10 @@ def build_state(cfg, mesh, scfg):
                        is_leaf=lambda x: isinstance(x, P))
     params = jax.tree.map(jax.device_put, params, psh)
     opt = init_opt_state(params, AdamWConfig())
-    return {"params": params, "opt": opt}
+    state = {"params": params, "opt": opt}
+    if cfg.moe is not None and cfg.moe.biased:
+        state["router"] = lm.init_route_state(cfg)
+    return state
 
 
 def parse_args(argv=None):

@@ -204,19 +204,24 @@ def attention_fwd(p: Dict, x: jax.Array, *, positions: jax.Array,
 # ---------------------------------------------------------------------------
 
 def init_mla(key, *, d_model: int, n_heads_padded: int, n_heads: int,
-             q_lora: int, kv_lora: int, qk_nope: int, qk_rope: int,
-             v_dim: int, dtype) -> Dict:
+             q_lora: Optional[int], kv_lora: int, qk_nope: int,
+             qk_rope: int, v_dim: int, dtype) -> Dict:
+    """``q_lora`` None: the query is one projection ``w_q`` (DeepSeek's
+    ``q_lora_rank`` null), else a low-rank ``w_dq`` -> norm -> ``w_uq``."""
     ks = jax.random.split(key, 7)
-    w_uq = dense_init(ks[1], q_lora, (n_heads_padded, qk_nope + qk_rope), dtype)
+    wq = dense_init(ks[1], q_lora or d_model,
+                    (n_heads_padded, qk_nope + qk_rope), dtype)
     wo = dense_init(ks[6], n_heads_padded * v_dim, (d_model,), dtype
                     ).reshape(n_heads_padded, v_dim, d_model)
     if n_heads_padded > n_heads:
-        w_uq = w_uq.at[:, n_heads:, :].set(0.0)
+        wq = wq.at[:, n_heads:, :].set(0.0)
         wo = wo.at[n_heads:, :, :].set(0.0)
-    return {
+    q = {"w_q": wq} if q_lora is None else {
         "w_dq": dense_init(ks[0], d_model, (q_lora,), dtype),
         "norm_q": jnp.ones((q_lora,), dtype),
-        "w_uq": w_uq,
+        "w_uq": wq}
+    return {
+        **q,
         "w_dkv": dense_init(ks[2], d_model, (kv_lora,), dtype),
         "norm_kv": jnp.ones((kv_lora,), dtype),
         "w_uk": dense_init(ks[3], kv_lora, (n_heads_padded, qk_nope), dtype),
@@ -230,20 +235,27 @@ def mla_fwd(p: Dict, x: jax.Array, *, positions: jax.Array, qk_nope: int,
             qk_rope: int, rope_theta: float = 1e4, window=0,
             cache: Optional[Dict] = None,
             cache_pos: Optional[jax.Array] = None, q_chunk: int = 512,
+            eps: float = 1e-6,
             ) -> Tuple[jax.Array, Optional[Dict]]:
     """MLA: the KV cache stores only the compressed latent + shared rope key.
 
     cache: {'ckv': (B, S_max, kv_lora), 'kr': (B, S_max, qk_rope)}.
     MLA's latent is itself an *aggregated* per-token buffer — the
     architecture-level cousin of the paper's message aggregation.
+    Rotary positions turn the ``qk_rope`` dims as halves (rotate-half);
+    DeepSeek's checkpoints pair them interleaved, which is a fixed
+    permutation of the rope columns of the query projection and ``w_kr``.
     """
     scale = (qk_nope + qk_rope) ** -0.5
-    cq = rms_norm(x @ p["w_dq"], p["norm_q"])
-    q = jnp.einsum("bsr,rhk->bshk", cq, p["w_uq"])
+    if "w_q" in p:
+        q = jnp.einsum("bsd,dhk->bshk", x, p["w_q"])
+    else:
+        cq = rms_norm(x @ p["w_dq"], p["norm_q"], eps)
+        q = jnp.einsum("bsr,rhk->bshk", cq, p["w_uq"])
     q_nope, q_rope = q[..., :qk_nope], q[..., qk_nope:]
     q_rope = apply_rope(q_rope, positions, rope_theta)
 
-    ckv = rms_norm(x @ p["w_dkv"], p["norm_kv"])          # (B, S, r)
+    ckv = rms_norm(x @ p["w_dkv"], p["norm_kv"], eps)     # (B, S, r)
     kr = apply_rope((x @ p["w_kr"])[:, :, None, :], positions,
                     rope_theta)[:, :, 0, :]               # (B, S, qk_rope)
 

@@ -12,42 +12,44 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.runtime.spans import scope
+
 from .attention import attention_fwd, mla_fwd
-from .layers import rms_norm, silu
+from .layers import rms_norm, swiglu
 from .mamba import mamba_fwd
-from .moe import moe_fwd
-
-
-def mlp_fwd(p: Dict, x: jax.Array) -> jax.Array:
-    """SwiGLU MLP."""
-    return (silu(x @ p["w_gate"]) * (x @ p["w_up"])) @ p["w_down"]
+from .moe import moe_fwd, routed_moe_fwd
 
 
 def block_fwd(cfg, lp: Dict, h: jax.Array, *, positions, window,
               cache: Optional[Dict] = None, cache_pos=None,
               seq_shard=lambda x: x, e_shard=lambda x: x,
-              decode_attn=None) -> Tuple[jax.Array, Optional[Dict]]:
+              decode_attn=None, route_bias=None,
+              ) -> Tuple[jax.Array, Optional[Dict], Optional[Dict]]:
     """One decoder layer.  ``cfg`` is a ModelConfig (static).
 
     cache (decode/prefill): per-layer slice of the stacked cache pytree.
-    Returns (h', new per-layer cache or None).
+    ``route_bias``: the layer's selection bias (sigmoid-routed MoE).
+    Returns (h', new per-layer cache or None, routing stats or None).
     """
     zc = cfg.zero_centered_norm
+    eps = cfg.norm_eps
     h = seq_shard(h)
     new_cache: Dict = {}
+    stats = None
 
     # ---- mixer ----
-    hin = rms_norm(h, lp["ln1"], zero_centered=zc)
+    hin = rms_norm(h, lp["ln1"], eps, zero_centered=zc)
     outs = []
     if cfg.mixer in ("attn", "hybrid"):
         if cfg.mla is not None:
-            a_out, kvc = mla_fwd(
-                lp["attn"], hin, positions=positions,
-                qk_nope=cfg.mla.qk_nope, qk_rope=cfg.mla.qk_rope,
-                rope_theta=cfg.rope_theta, window=window,
-                cache=None if cache is None else
-                {"ckv": cache["ckv"], "kr": cache["kr"]},
-                cache_pos=cache_pos, q_chunk=cfg.q_chunk)
+            with scope("mla"):
+                a_out, kvc = mla_fwd(
+                    lp["attn"], hin, positions=positions,
+                    qk_nope=cfg.mla.qk_nope, qk_rope=cfg.mla.qk_rope,
+                    rope_theta=cfg.rope_theta, window=window,
+                    cache=None if cache is None else
+                    {"ckv": cache["ckv"], "kr": cache["kr"]},
+                    cache_pos=cache_pos, q_chunk=cfg.q_chunk, eps=eps)
         else:
             a_out, kvc = attention_fwd(
                 lp["attn"], hin, positions=positions,
@@ -72,24 +74,30 @@ def block_fwd(cfg, lp: Dict, h: jax.Array, *, positions, window,
 
     if cfg.mixer == "hybrid":
         # Hymba: per-branch normalization, then mean-combine.
-        mix = (rms_norm(outs[0][1], lp["norm_attn"], zero_centered=zc)
-               + rms_norm(outs[1][1], lp["norm_mamba"], zero_centered=zc)) * 0.5
+        mix = (rms_norm(outs[0][1], lp["norm_attn"], eps, zero_centered=zc)
+               + rms_norm(outs[1][1], lp["norm_mamba"], eps,
+                          zero_centered=zc)) * 0.5
     else:
         mix = outs[0][1]
     if cfg.post_norm:
-        mix = rms_norm(mix, lp["ln1_post"], zero_centered=zc)
+        mix = rms_norm(mix, lp["ln1_post"], eps, zero_centered=zc)
     h = h + mix
 
     # ---- FFN ----
     if cfg.d_ff > 0 or cfg.moe is not None:
-        hin2 = rms_norm(h, lp["ln2"], zero_centered=zc)
-        if cfg.moe is not None:
+        hin2 = rms_norm(h, lp["ln2"], eps, zero_centered=zc)
+        if cfg.moe is not None and cfg.moe.biased:
+            f_out, stats = routed_moe_fwd(
+                lp["moe"], hin2, mo=cfg.moe, bias=route_bias,
+                e_shard=e_shard, tok_shard=seq_shard)
+        elif cfg.moe is not None:
             f_out = moe_fwd(lp["moe"], hin2, mo=cfg.moe, e_shard=e_shard,
                             tok_shard=seq_shard)
         else:
-            f_out = mlp_fwd(lp["mlp"], hin2)
+            with scope("mlp.dense"):
+                f_out = swiglu(lp["mlp"], hin2)
         if cfg.post_norm:
-            f_out = rms_norm(f_out, lp["ln2_post"], zero_centered=zc)
+            f_out = rms_norm(f_out, lp["ln2_post"], eps, zero_centered=zc)
         h = h + f_out
 
-    return h, (new_cache if cache is not None else None)
+    return h, (new_cache if cache is not None else None), stats

@@ -36,6 +36,11 @@ def silu(x: jax.Array) -> jax.Array:
     return x * jax.nn.sigmoid(x)
 
 
+def swiglu(p, x: jax.Array) -> jax.Array:
+    """SwiGLU MLP: (silu(x @ w_gate) * (x @ w_up)) @ w_down."""
+    return (silu(x @ p["w_gate"]) * (x @ p["w_up"])) @ p["w_down"]
+
+
 def gelu(x: jax.Array) -> jax.Array:
     return jax.nn.gelu(x, approximate=True)
 

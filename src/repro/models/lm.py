@@ -6,6 +6,14 @@ a leading L axis and executed with ``jax.lax.scan`` (optionally remat'ed),
 which keeps compile time flat in depth and is the structural hook for the
 paper's technique: per-layer gradient collectives issued *inside* the
 backward scan (see repro.core.earlybird).
+
+A model may lead with ``first_dense`` dense SwiGLU layers (DeepSeek's
+``first_k_dense_replace``) before its MoE layers: they are a stack of
+their own, ``params["prefix"]`` (and ``cache["prefix"]``), scanned
+before ``params["layers"]``.  A sigmoid-routed MoE (``MoEConfig.biased``)
+takes a selection bias per layer and expert, ``route_bias`` (L_moe, E),
+which lives in the train state, not in the parameters: every forward
+pass of such a model is handed it, and raises without it.
 """
 
 from __future__ import annotations
@@ -20,6 +28,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro.runtime.spans import scope
+
 from .attention import head_to_kv_map, init_attention, init_mla
 from .blocks import block_fwd
 from .layers import chunked_cross_entropy, embed_init, rms_norm, softcap
@@ -31,7 +41,7 @@ MODEL_AXIS = "model"
 
 @dataclass(frozen=True)
 class MLAConfig:
-    q_lora: int = 768
+    q_lora: Optional[int] = 768   # None: one direct query projection
     kv_lora: int = 256
     qk_nope: int = 64
     qk_rope: int = 32
@@ -66,6 +76,8 @@ class ModelConfig:
     moe: Optional[MoEConfig] = None
     mamba: Optional[MambaConfig] = None
     q_scale: Optional[float] = None
+    first_dense: int = 0        # leading dense layers (FFN width d_ff)
+    norm_eps: float = 1e-6      # every RMSNorm's epsilon
     q_chunk: int = 512
     loss_chunk: int = 512
     tp_pad: int = 1             # pad heads/experts to a multiple of this
@@ -119,8 +131,14 @@ class ModelConfig:
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
 
+    @property
+    def n_main_layers(self) -> int:
+        """Layers of the main stack, after the leading dense ones."""
+        return self.n_layers - self.first_dense
+
     # ---- parameter counting (logical, for MODEL_FLOPS) ----
     def param_count(self, padded: bool = False) -> int:
+        """Parameters held (of an expert share: its experts alone)."""
         nh = self.n_heads_padded if padded else self.n_heads
         hd = self.head_dim_
         d = self.d_model
@@ -132,8 +150,10 @@ class ModelConfig:
         if self.mixer in ("attn", "hybrid"):
             if self.mla is not None:
                 m = self.mla
-                per_layer += (d * m.q_lora + m.q_lora * nh * (m.qk_nope + m.qk_rope)
-                              + d * m.kv_lora + m.kv_lora * nh * m.qk_nope
+                qk = nh * (m.qk_nope + m.qk_rope)
+                per_layer += (d * qk if m.q_lora is None
+                              else d * m.q_lora + m.q_lora * qk)
+                per_layer += (d * m.kv_lora + m.kv_lora * nh * m.qk_nope
                               + m.kv_lora * nh * m.v_dim + d * m.qk_rope
                               + nh * m.v_dim * d)
             else:
@@ -143,22 +163,25 @@ class ModelConfig:
             di = mc.d_inner(d)
             gn = mc.n_groups * mc.d_state
             per_layer += 2 * d * di + 2 * d * gn + d * mc.n_heads(d) + di * d
+        dense = 3 * d * self.d_ff
+        ffn = dense
         if self.moe is not None:
-            e = self.moe.e_pad if padded else self.moe.n_experts
-            per_layer += d * e + e * 3 * d * self.moe.d_expert
-        elif self.d_ff > 0:
-            per_layer += 3 * d * self.d_ff
-        return n + self.n_layers * per_layer
+            mo = self.moe
+            e = mo.e_pad if padded else mo.n_experts
+            ffn = d * e + (mo.held or e) * 3 * d * mo.d_expert \
+                + mo.n_shared * 3 * d * mo.d_expert
+        return (n + self.n_layers * per_layer
+                + self.first_dense * dense + self.n_main_layers * ffn)
 
     def active_param_count(self) -> int:
         """Per-token active parameters (MoE: top_k experts only)."""
         if self.moe is None:
             return self.param_count()
+        mo = self.moe
         full = self.param_count()
-        all_experts = self.n_layers * self.moe.n_experts * 3 * self.d_model \
-            * self.moe.d_expert
-        active = self.n_layers * self.moe.top_k * 3 * self.d_model \
-            * self.moe.d_expert
+        expert = 3 * self.d_model * mo.d_expert
+        all_experts = self.n_main_layers * (mo.held or mo.n_experts) * expert
+        active = self.n_main_layers * mo.top_k * expert
         return full - all_experts + active
 
 
@@ -230,9 +253,29 @@ def init_params(cfg: ModelConfig, key) -> Dict:
         if cfg.vocab_padded > cfg.vocab:
             params["head"] = params["head"].at[:, cfg.vocab:].set(0.0)
     layer_keys = jax.random.split(k_layers, cfg.n_layers)
-    layers = [_init_layer(cfg, k) for k in layer_keys]
-    params["layers"] = jax.tree.map(lambda *xs: jnp.stack(xs), *layers)
+    nd = cfg.first_dense
+
+    def stack(c, keys):
+        layers = [_init_layer(c, k) for k in keys]
+        return jax.tree.map(lambda *xs: jnp.stack(xs), *layers)
+
+    if nd:
+        params["prefix"] = stack(dense_config(cfg), layer_keys[:nd])
+    params["layers"] = stack(cfg, layer_keys[nd:])
     return params
+
+
+def dense_config(cfg: ModelConfig) -> ModelConfig:
+    """The config of the leading dense stack (``first_dense`` layers)."""
+    return cfg.replace(moe=None, n_layers=cfg.first_dense, first_dense=0)
+
+
+def init_route_state(cfg: ModelConfig) -> Dict:
+    """The train state's routing part: each MoE layer's selection bias
+    and the last step's routed (token, slot) pairs per expert."""
+    shape = (cfg.n_main_layers, cfg.moe.n_experts)
+    return {"bias": jnp.zeros(shape, jnp.float32),
+            "load": jnp.zeros(shape, jnp.float32)}
 
 
 def param_shapes(cfg: ModelConfig):
@@ -251,9 +294,11 @@ def param_specs(cfg: ModelConfig, axis: str = MODEL_AXIS) -> Dict:
 
     def attn_specs():
         if cfg.mla is not None:
+            q = ({"w_q": P(None, None, A, None)} if cfg.mla.q_lora is None
+                 else {"w_dq": P(None, None, None), "norm_q": P(None, None),
+                       "w_uq": P(None, None, A, None)})
             return {
-                "w_dq": P(None, None, None), "norm_q": P(None, None),
-                "w_uq": P(None, None, A, None),
+                **q,
                 "w_dkv": P(None, None, None), "norm_kv": P(None, None),
                 "w_uk": P(None, None, A, None),
                 "w_uv": P(None, None, A, None),
@@ -304,6 +349,10 @@ def param_specs(cfg: ModelConfig, axis: str = MODEL_AXIS) -> Dict:
                 "w_up": P(None, A, None, None),
                 "w_down": P(None, A, None, None),
             }
+            if cfg.moe.n_shared:
+                lp["moe"]["shared"] = {"w_gate": P(None, None, A),
+                                       "w_up": P(None, None, A),
+                                       "w_down": P(None, A, None)}
         else:
             lp["mlp"] = {"w_gate": P(None, None, A), "w_up": P(None, None, A),
                          "w_down": P(None, A, None)}
@@ -315,6 +364,8 @@ def param_specs(cfg: ModelConfig, axis: str = MODEL_AXIS) -> Dict:
         "final_norm": P(None),
         "layers": lp,
     }
+    if cfg.first_dense:
+        specs["prefix"] = param_specs(dense_config(cfg), axis)["layers"]
     if not cfg.tie_embeddings:
         specs["head"] = P(None, A)
     return specs
@@ -326,10 +377,11 @@ def param_specs(cfg: ModelConfig, axis: str = MODEL_AXIS) -> Dict:
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int,
                dtype=None) -> Dict:
-    """Stacked (L-leading) decode cache for the configured mixer."""
+    """Stacked (L-leading) decode cache for the configured mixer; the
+    dense prefix's under ``"prefix"``."""
     dt = dtype or cfg.dtype
-    L = cfg.n_layers
-    c: Dict[str, jax.Array] = {}
+    L = cfg.n_main_layers
+    c: Dict[str, Any] = {}
     if cfg.mixer in ("attn", "hybrid"):
         if cfg.mla is not None:
             c["ckv"] = jnp.zeros((L, batch, max_len, cfg.mla.kv_lora), dt)
@@ -341,6 +393,8 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
         one = init_mamba_cache(batch, cfg.d_model, cfg.mamba, dt)
         for k, v in one.items():
             c[k] = jnp.broadcast_to(v[None], (L, *v.shape)).copy()
+    if cfg.first_dense:
+        c["prefix"] = init_cache(dense_config(cfg), batch, max_len, dt)
     return c
 
 
@@ -360,6 +414,9 @@ def cache_specs(cfg: ModelConfig, axis: str = MODEL_AXIS,
         c["conv_x"] = P(None, data_axis, None, axis)
         c["conv_B"] = P(None, data_axis, None, None)
         c["conv_C"] = P(None, data_axis, None, None)
+    if cfg.first_dense:
+        c["prefix"] = cache_specs(dense_config(cfg), axis, data_axis,
+                                  seq_axis)
     return c
 
 
@@ -400,37 +457,78 @@ def forward(cfg: ModelConfig, params: Dict, batch: Dict, *,
             cache: Optional[Dict] = None, cache_pos=None,
             remat: bool = False, seq_shard: Callable = lambda x: x,
             e_shard: Callable = lambda x: x,
-            param_hook: Callable = lambda lp: lp,
-            decode_attn=None,
+            param_hooks: Optional[Dict[str, Callable]] = None,
+            decode_attn=None, route_bias=None,
             ) -> Tuple[jax.Array, Optional[Dict]]:
     """Run the decoder stack.
 
-    ``param_hook`` wraps each layer's parameter slice inside the scan body —
-    the attach point for the early-bird gradient-sync engine.
+    ``param_hooks``: by stack key (``"layers"``, ``"prefix"``), a hook that
+    wraps each of that stack's layer parameter slices inside the scan
+    body — the attach point for the early-bird gradient-sync engine.
     Returns (hidden (B,S,D), new stacked cache or None).
     """
+    h, new_cache, _ = forward_stats(
+        cfg, params, batch, cache=cache, cache_pos=cache_pos, remat=remat,
+        seq_shard=seq_shard, e_shard=e_shard, param_hooks=param_hooks,
+        decode_attn=decode_attn, route_bias=route_bias)
+    return h, new_cache
+
+
+def forward_stats(cfg: ModelConfig, params: Dict, batch: Dict, *,
+                  cache: Optional[Dict] = None, cache_pos=None,
+                  remat: bool = False, seq_shard: Callable = lambda x: x,
+                  e_shard: Callable = lambda x: x,
+                  param_hooks: Optional[Dict[str, Callable]] = None,
+                  decode_attn=None, route_bias=None,
+                  ) -> Tuple[jax.Array, Optional[Dict], Optional[Dict]]:
+    """:func:`forward`, and the sigmoid-routed layers' stats stacked over
+    the MoE layers ({'load': (L, E), 'balance': (L,)}; None without
+    them).  ``route_bias``: (L, E) selection bias, which a biased router
+    needs: the trained bias (the train state's ``router['bias']``), or
+    ``init_route_state(cfg)['bias']`` for a model that was never trained."""
     h = _embed_inputs(cfg, params, batch)
     b, s = h.shape[0], h.shape[1]
     positions = _positions(cfg, batch, b, s, cache_pos)
-    windows = jnp.asarray(cfg.windows(), jnp.int32)
+    windows = cfg.windows()
+    nd = cfg.first_dense
+    hooks = param_hooks or {}
 
-    def body(carry, xs):
-        lp, window, layer_cache = xs
-        lp = param_hook(lp)
-        h_new, c_new = block_fwd(cfg, lp, carry, positions=positions,
-                                 window=window, cache=layer_cache,
-                                 cache_pos=cache_pos, seq_shard=seq_shard,
-                                 e_shard=e_shard, decode_attn=decode_attn)
-        return h_new, c_new
+    def run(key, c, h, win, stack_cache, bias):
+        hook = hooks.get(key, lambda lp: lp)
 
-    if remat:
-        body = jax.checkpoint(body)
+        def body(carry, xs):
+            lp, window, layer_cache, lb = xs
+            lp = hook(lp)
+            h_new, c_new, st = block_fwd(
+                c, lp, carry, positions=positions, window=window,
+                cache=layer_cache, cache_pos=cache_pos, seq_shard=seq_shard,
+                e_shard=e_shard, decode_attn=decode_attn, route_bias=lb)
+            return h_new, (c_new, st)
 
-    xs = (params["layers"], windows, cache)
-    h, new_cache = jax.lax.scan(body, h, xs)
-    h = rms_norm(h, params["final_norm"],
+        if remat:
+            body = jax.checkpoint(body)
+        xs = (params[key], jnp.asarray(win, jnp.int32), stack_cache, bias)
+        h, (c_new, st) = jax.lax.scan(body, h, xs)
+        return h, c_new, st
+
+    if nd:
+        h, prefix_cache, _ = run(
+            "prefix", dense_config(cfg), h, windows[:nd],
+            None if cache is None else cache["prefix"], None)
+    if cfg.moe is not None and cfg.moe.biased and route_bias is None:
+        raise ValueError("a router with a selection bias needs route_bias: "
+                         "the trained bias, as the train state's "
+                         "router['bias'] holds it")
+    stack_cache = cache
+    if nd and cache is not None:
+        stack_cache = {k: v for k, v in cache.items() if k != "prefix"}
+    h, new_cache, stats = run("layers", cfg, h, windows[nd:], stack_cache,
+                              route_bias)
+    if nd and cache is not None:
+        new_cache = {**new_cache, "prefix": prefix_cache}
+    h = rms_norm(h, params["final_norm"], cfg.norm_eps,
                  zero_centered=cfg.zero_centered_norm)
-    return h, new_cache
+    return h, new_cache, stats
 
 
 def output_head(cfg: ModelConfig, params: Dict) -> jax.Array:
@@ -451,23 +549,34 @@ def _final_logits(cfg: ModelConfig, h_last: jax.Array,
 def loss_fn(cfg: ModelConfig, params: Dict, batch: Dict, *,
             remat: bool = True, seq_shard: Callable = lambda x: x,
             e_shard: Callable = lambda x: x,
-            param_hook: Callable = lambda lp: lp,
-            gather_targets: bool = False) -> jax.Array:
-    """Next-token cross entropy (labels = batch['labels'])."""
-    h, _ = forward(cfg, params, batch, remat=remat, seq_shard=seq_shard,
-                   e_shard=e_shard, param_hook=param_hook)
-    return chunked_cross_entropy(
-        h, output_head(cfg, params), batch["labels"],
-        chunk=cfg.loss_chunk, final_softcap=cfg.final_softcap,
-        mask=batch.get("loss_mask"),
-        valid_vocab=(cfg.vocab if cfg.vocab_padded > cfg.vocab else None),
-        gather_targets=gather_targets)
+            param_hooks: Optional[Dict[str, Callable]] = None,
+            gather_targets: bool = False, route_bias=None,
+            with_stats: bool = False):
+    """Next-token cross entropy (labels = batch['labels']), plus the
+    sequence-wise balance loss of sigmoid-routed layers.  With
+    ``with_stats``: (loss, routing stats), as :func:`forward_stats`."""
+    h, _, stats = forward_stats(cfg, params, batch, remat=remat,
+                                seq_shard=seq_shard, e_shard=e_shard,
+                                param_hooks=param_hooks,
+                                route_bias=route_bias)
+    with scope("head"):
+        loss = chunked_cross_entropy(
+            h, output_head(cfg, params), batch["labels"],
+            chunk=cfg.loss_chunk, final_softcap=cfg.final_softcap,
+            mask=batch.get("loss_mask"),
+            valid_vocab=(cfg.vocab if cfg.vocab_padded > cfg.vocab
+                         else None),
+            gather_targets=gather_targets)
+    if stats is not None:
+        loss = loss + jnp.sum(stats["balance"])
+    return (loss, stats) if with_stats else loss
 
 
 def prefill(cfg: ModelConfig, params: Dict, batch: Dict, *,
             cache: Optional[Dict] = None,
             seq_shard: Callable = lambda x: x,
-            e_shard: Callable = lambda x: x) -> Tuple[jax.Array, Dict]:
+            e_shard: Callable = lambda x: x,
+            route_bias=None) -> Tuple[jax.Array, Dict]:
     """Forward pass that fills a KV cache; returns last-token logits."""
     tokens_like = batch.get("tokens", batch.get("embeds"))
     b, s = tokens_like.shape[0], tokens_like.shape[1]
@@ -475,7 +584,7 @@ def prefill(cfg: ModelConfig, params: Dict, batch: Dict, *,
         cache = init_cache(cfg, b, s)
     h, new_cache = forward(cfg, params, batch, cache=cache,
                            cache_pos=jnp.int32(0), seq_shard=seq_shard,
-                           e_shard=e_shard)
+                           e_shard=e_shard, route_bias=route_bias)
     return _final_logits(cfg, h[:, -1, :], params), new_cache
 
 
@@ -484,7 +593,7 @@ def decode_step(cfg: ModelConfig, params: Dict, cache: Dict,
                 embeds: Optional[jax.Array] = None,
                 seq_shard: Callable = lambda x: x,
                 e_shard: Callable = lambda x: x,
-                decode_attn=None) -> Tuple[jax.Array, Dict]:
+                decode_attn=None, route_bias=None) -> Tuple[jax.Array, Dict]:
     """One decode step: tokens (B,) int32, pos scalar write offset.
 
     Returns (logits (B, V) f32, updated cache).
@@ -496,5 +605,5 @@ def decode_step(cfg: ModelConfig, params: Dict, cache: Dict,
         batch["tokens"] = tokens[:, None]
     h, new_cache = forward(cfg, params, batch, cache=cache, cache_pos=pos,
                            seq_shard=seq_shard, e_shard=e_shard,
-                           decode_attn=decode_attn)
+                           decode_attn=decode_attn, route_bias=route_bias)
     return _final_logits(cfg, h[:, -1, :], params), new_cache

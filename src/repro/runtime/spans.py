@@ -46,3 +46,12 @@ def span(name: str, **counts):
     if jax is None:
         return _NO_SPAN
     return jax.profiler.TraceAnnotation(PREFIX + name, **counts)
+
+
+def scope(name: str):
+    """A ``jax.named_scope`` ``repro.<name>`` around traced model code: it
+    lands in the ``op_name`` metadata of every HLO instruction made inside
+    it (forward, backward and recomputation alike), which is how device
+    ops in a profiler trace are put down to the model's layers."""
+    import jax
+    return jax.named_scope(PREFIX + name)

@@ -43,12 +43,12 @@ ref_loss, ref_grads = jax.value_and_grad(
 def make_step(mode, aggr=1 << 12):
     sync = SyncConfig(mode=mode, axes=("data",), aggr_bytes=aggr)
 
-    def local_loss(p, bt, param_hook):
-        return lm.loss_fn(cfg, p, bt, param_hook=param_hook)
+    def local_loss(p, bt, param_hooks):
+        return lm.loss_fn(cfg, p, bt, param_hooks=param_hooks)
 
     vg = value_and_synced_grad(
-        lambda p, bt, param_hook=None: lm.loss_fn(cfg, p, bt,
-                                                  param_hook=param_hook),
+        lambda p, bt, param_hooks=None: lm.loss_fn(cfg, p, bt,
+                                                  param_hooks=param_hooks),
         sync)
 
     def step(p, bt):

@@ -6,7 +6,11 @@ context a naive softmax would need ~8 GB/chip of scores.  Each chunk's
 softmax is exact (full key range), so this is numerically identical to the
 reference formulation; the Pallas flash-attention kernel
 (repro.kernels.flash_attention) is the TPU-tiled version of the same
-contraction.
+contraction.  The backward of the chunked path (a custom VJP) keeps only
+q, k, v, the output and each row's log-sum-exp, and recomputes one
+chunk's scores at a time, so neither pass holds more than one chunk's
+scores.  A call of one chunk is one block under plain autodiff, whose
+backward keeps that block's scores.
 
 Head-count padding for tensor parallelism: query heads may be padded up to
 a multiple of the TP degree; padded slots are zero-initialized in both the
@@ -16,6 +20,7 @@ head-count output exactly.
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -42,14 +47,12 @@ def _mask(q_pos, k_pos, window):
     return m & jnp.where(window > 0, (q - k) < window, True)
 
 
-def _attn_block(q, k, v, q_pos, k_pos, window, cap, scale, out_dtype):
-    """q: (B,Sq,H,D); k/v: (B,Sk,Kv,D) with Kv | H — grouped einsums, the
-    expanded (B,Sk,H,D) KV is never materialized (at 32k decode that
-    expansion was ~2 GiB x2 per layer)."""
+def _scores(q, k, q_pos, k_pos, window, cap, scale):
+    """Scaled, soft-capped f32 scores (B,Kv,G,Sq,Sk) of q: (B,Sq,H,D)
+    against k: (B,Sk,Kv,D), Kv | H, and the mask that keeps them."""
     b, sq, h, d = q.shape
     kv = k.shape[2]
-    g = h // kv
-    qg = q.reshape(b, sq, kv, g, d)
+    qg = q.reshape(b, sq, kv, h // kv, d)
     # f32 accumulation via preferred_element_type: casting the result
     # instead makes XLA convert the OPERANDS to f32 — measured to
     # materialize a full f32 copy of the KV cache on decode cells.
@@ -61,6 +64,15 @@ def _attn_block(q, k, v, q_pos, k_pos, window, cap, scale, out_dtype):
         m = _mask(q_pos, k_pos, window)[None, None, None]
     else:  # per-batch positions (decode)
         m = _mask(q_pos, k_pos[None, :], window)[:, None, None]
+    return scores, m
+
+
+def _attn_block(q, k, v, q_pos, k_pos, window, cap, scale, out_dtype):
+    """q: (B,Sq,H,D); k/v: (B,Sk,Kv,D) with Kv | H — grouped einsums, the
+    expanded (B,Sk,H,D) KV is never materialized (at 32k decode that
+    expansion was ~2 GiB x2 per layer)."""
+    b, sq, h, _ = q.shape
+    scores, m = _scores(q, k, q_pos, k_pos, window, cap, scale)
     scores = jnp.where(m, scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1).astype(out_dtype)
     out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v)
@@ -74,26 +86,131 @@ def masked_attention(q, k, v, *, q_pos, k_pos, window=0,
     grouping: q head i attends kv head i // (H/Kv)) -> (B,Sq,H,Dv).
 
     Scans over query chunks; each chunk sees the full key range, so the
-    softmax is exact.
+    softmax is exact.  The chunked path's backward recomputes each
+    chunk's scores (``_chunked``).
     """
-    b, sq, h, dk = q.shape
+    sq = q.shape[1]
     if sq <= q_chunk or sq % q_chunk != 0 or q_pos.ndim > 2:
         return _attn_block(q, k, v, q_pos, k_pos, window, attn_softcap,
                            scale, q.dtype)
-    nc = sq // q_chunk
-    qs = q.reshape(b, nc, q_chunk, h, dk).transpose(1, 0, 2, 3, 4)
+    return _chunked(q, k, v, q_pos, k_pos, window, attn_softcap, scale,
+                    q_chunk)
+
+
+_RECOMPUTE = {"chunked_vjp": 0}
+
+
+def recompute_stats() -> dict:
+    """Chunked attention calls traced with the recompute backward."""
+    return dict(_RECOMPUTE)
+
+
+def _split(x, nc):
+    """(B, Sq, ...) -> (nc, B, Sq/nc, ...): the query chunks as scan xs."""
+    b, sq = x.shape[:2]
+    return jnp.moveaxis(x.reshape(b, nc, sq // nc, *x.shape[2:]), 1, 0)
+
+
+def _merge(x):
+    """(nc, B, c, ...) -> (B, nc * c, ...): the inverse of ``_split``."""
+    x = jnp.moveaxis(x, 0, 1)
+    return x.reshape(x.shape[0], -1, *x.shape[3:])
+
+
+def _chunk_pos(q_pos, nc):
+    """1-D (Sq,) -> (nc, c); per-batch (B, Sq), e.g. M-RoPE -> (nc, B, c)."""
     if q_pos.ndim == 1:
-        ps = q_pos.reshape(nc, q_chunk)
-    else:  # per-batch positions (e.g. M-RoPE): (B, Sq) -> (nc, B, qc)
-        ps = q_pos.reshape(b, nc, q_chunk).transpose(1, 0, 2)
+        return q_pos.reshape(nc, -1)
+    return _split(q_pos, nc)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def _chunked(q, k, v, q_pos, k_pos, window, cap, scale, q_chunk):
+    """The query-chunk scan.  Its backward (FlashAttention-2's at chunk
+    granularity) keeps ``q, k, v``, the output and each row's
+    log-sum-exp, and recomputes a chunk's scores from them: autodiff of
+    the scan would stack every chunk's f32 scores, (Sq, Sk) per head."""
+    nc = q.shape[1] // q_chunk
 
     def body(_, xs):
         qc, pc = xs
-        return (), _attn_block(qc, k, v, pc, k_pos, window, attn_softcap,
-                               scale, q.dtype)
+        return (), _attn_block(qc, k, v, pc, k_pos, window, cap, scale,
+                               q.dtype)
 
-    _, out = jax.lax.scan(body, (), (qs, ps))
-    return out.transpose(1, 0, 2, 3, 4).reshape(b, sq, h, -1)
+    _, out = jax.lax.scan(body, (), (_split(q, nc), _chunk_pos(q_pos, nc)))
+    return _merge(out)
+
+
+def _chunked_fwd(q, k, v, q_pos, k_pos, window, cap, scale, q_chunk):
+    """The same scan, with each row's log-sum-exp (B,Kv,G,c) per chunk."""
+    nc = q.shape[1] // q_chunk
+
+    def body(_, xs):
+        qc, pc = xs
+        s, m = _scores(qc, k, pc, k_pos, window, cap, scale)
+        # NEG_INF as a function of s: under a remat'd layer a constant
+        # fill is hoisted out of the scan into a tile-sized buffer of its
+        # own, outside the caller's scope
+        s = jnp.where(m, s, jnp.minimum(s, NEG_INF))
+        top = jnp.max(s, axis=-1, keepdims=True)
+        e = jnp.exp(s - top)
+        tot = jnp.sum(e, axis=-1, keepdims=True)
+        o = jnp.einsum("bkgqs,bskd->bqkgd", (e / tot).astype(q.dtype), v)
+        return (), (o.reshape(*qc.shape[:3], -1),
+                    (top + jnp.log(tot))[..., 0])
+
+    _, (out, lse) = jax.lax.scan(body, (), (_split(q, nc),
+                                            _chunk_pos(q_pos, nc)))
+    out = _merge(out)
+    return out, (q, k, v, q_pos, k_pos, window, out, lse)
+
+
+def _chunked_bwd(cap, scale, q_chunk, res, d_out):
+    """Per chunk: p = exp(s - lse); dv += p^T dO; dp = dO v^T;
+    ds = p (dp - rowsum(dO o)) through the softcap and the scale;
+    dq = ds k; dk += ds^T q, summed over each kv head's query group.
+    dk and dv accumulate in f32."""
+    q, k, v, q_pos, k_pos, window, out, lse = res
+    _RECOMPUTE["chunked_vjp"] += 1
+    b, sq, h, _ = q.shape
+    kv = k.shape[2]
+    nc = sq // q_chunk
+    f32 = jnp.float32
+    # rowsum(dO * o), the softmax backward's row term: (B, Sq, Kv, G)
+    rows = jnp.sum(d_out.astype(f32) * out.astype(f32), axis=-1)
+    rows = rows.reshape(b, sq, kv, h // kv)
+
+    def body(acc, xs):
+        dk_acc, dv_acc = acc
+        qc, pc, lc, doc, rc = xs
+        c = qc.shape[1]
+        qg = qc.reshape(b, c, kv, h // kv, -1)
+        dog = doc.reshape(b, c, kv, h // kv, -1)
+        t, m = _scores(qc, k, pc, k_pos, window, cap, scale)
+        p = jnp.exp(jnp.where(m, t, NEG_INF) - lc[..., None])
+        dv_acc = dv_acc + jnp.einsum("bkgqs,bqkgd->bskd", p.astype(q.dtype),
+                                     dog, preferred_element_type=f32)
+        dp = jnp.einsum("bqkgd,bskd->bkgqs", dog, v,
+                        preferred_element_type=f32)
+        ds = p * (dp - jnp.moveaxis(rc, 1, -1)[..., None])
+        if cap is not None:  # d(cap tanh(x / cap))/dx = 1 - tanh^2
+            ds = ds * (1.0 - jnp.square(t / cap))
+        ds = ds * scale
+        dq = jnp.einsum("bkgqs,bskd->bqkgd", ds, k,
+                        preferred_element_type=f32)
+        dk_acc = dk_acc + jnp.einsum("bkgqs,bqkgd->bskd", ds, qg,
+                                     preferred_element_type=f32)
+        return (dk_acc, dv_acc), dq.reshape(qc.shape).astype(q.dtype)
+
+    acc = (jnp.zeros(k.shape, f32), jnp.zeros(v.shape, f32))
+    (dk_acc, dv_acc), dq = jax.lax.scan(
+        body, acc, (_split(q, nc), _chunk_pos(q_pos, nc), lse,
+                    _split(d_out, nc), _split(rows, nc)))
+    return (_merge(dq), dk_acc.astype(k.dtype), dv_acc.astype(v.dtype),
+            None, None, None)
+
+
+_chunked.defvjp(_chunked_fwd, _chunked_bwd)
 
 
 # ---------------------------------------------------------------------------

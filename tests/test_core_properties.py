@@ -1,5 +1,7 @@
 """Property tests on the core engine's invariants (hypothesis)."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,6 +14,7 @@ except ImportError:  # env without hypothesis: deterministic fallback
 from repro.core import bucketing
 from repro.core.flash_decode import flash_decode_ref
 from repro.kernels.flash_attention import flash_attention
+from repro.models import attention as attn_mod
 from repro.models.attention import masked_attention
 
 
@@ -92,3 +95,67 @@ class TestAttentionConsistency:
                                   scale=d ** -0.5)
         np.testing.assert_allclose(np.asarray(model[:, 0]),
                                    np.asarray(oracle), rtol=2e-5, atol=2e-5)
+
+    # (query heads per kv head, dk, dv, window, softcap, positions)
+    @pytest.mark.parametrize("group,dk,dv,window,cap,pos", [
+        (1, 32, 32, 0, None, "1d"),
+        (2, 32, 32, 32, None, "1d"),
+        (4, 32, 32, 0, 30.0, "1d"),
+        (2, 48, 32, 0, None, "batch"),
+        (4, 48, 32, 32, 30.0, "batch"),
+        (1, 48, 32, 32, 30.0, "1d"),
+    ])
+    def test_chunked_gradient_matches_one_block(self, group, dk, dv, window,
+                                                cap, pos):
+        """The chunked path's recompute backward against autodiff of one
+        unchunked block, in q, k and v."""
+        b, s, kv, qc = 2, 128, 2, 32
+        ks = jax.random.split(jax.random.PRNGKey(group + dk + window), 4)
+        q = jax.random.normal(ks[0], (b, s, kv * group, dk))
+        k = jax.random.normal(ks[1], (b, s, kv, dk))
+        v = jax.random.normal(ks[2], (b, s, kv, dv))
+        ct = jax.random.normal(ks[3], (b, s, kv * group, dv))
+        k_pos = jnp.arange(s)
+        # per-batch rows: the second sequence starts 16 positions later
+        q_pos = k_pos if pos == "1d" else k_pos + jnp.array([[0], [16]])
+        scale = dk ** -0.5
+
+        def chunked(q, k, v):
+            return jnp.sum(ct * masked_attention(
+                q, k, v, q_pos=q_pos, k_pos=k_pos, window=window,
+                attn_softcap=cap, scale=scale, q_chunk=qc))
+
+        def block(q, k, v):
+            return jnp.sum(ct * attn_mod._attn_block(
+                q, k, v, q_pos, k_pos, window, cap, scale, q.dtype))
+
+        got = jax.jit(jax.grad(chunked, (0, 1, 2)))(q, k, v)
+        want = jax.jit(jax.grad(block, (0, 1, 2)))(q, k, v)
+        for g, w in zip(got, want):
+            assert g.dtype == w.dtype
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       rtol=2e-5, atol=2e-5)
+
+    def test_chunked_gradient_keeps_no_score_stack(self):
+        """The compiled gradient of the chunked path holds no buffer of
+        every chunk's scores (n_chunks x q_chunk x Sk per head: autodiff of
+        the scan stacks f32[8,1,4,1,128,1024] here), and only a
+        differentiated trace takes the recompute backward."""
+        b, s, h, d, qc = 1, 1024, 4, 32, 128
+        x = jnp.ones((b, s, h, d))
+        pos = jnp.arange(s)
+
+        def fwd(q, k, v):
+            return masked_attention(q, k, v, q_pos=pos, k_pos=pos,
+                                    scale=d ** -0.5, q_chunk=qc)
+
+        before = attn_mod.recompute_stats()["chunked_vjp"]
+        jax.jit(fwd).lower(x, x, x).compile()
+        assert attn_mod.recompute_stats()["chunked_vjp"] == before
+        text = jax.jit(jax.grad(lambda q, k, v: jnp.sum(fwd(q, k, v)),
+                                (0, 1, 2))).lower(x, x, x).compile().as_text()
+        assert attn_mod.recompute_stats()["chunked_vjp"] >= before + 1
+        largest = max(int(np.prod([int(n) for n in dims.split(",")]))
+                      for dims in re.findall(r"\b(?:f32|bf16)\[([\d,]+)\]",
+                                             text))
+        assert largest < b * h * s * s, largest

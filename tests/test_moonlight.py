@@ -13,6 +13,7 @@ compared exactly: with random weights no two scores tie.
 """
 
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,10 +22,10 @@ import pytest
 
 from chipbench import harness
 from chipbench.drivers import lm_program
-from chipbench.drivers.train_deepseek import model_config
+from chipbench.drivers.train_deepseek import model_config, scope_map
 from chipbench.tests import small_moonlight
 from repro.launch import steps as S
-from repro.models import lm
+from repro.models import attention, lm
 from repro.models import moe as moe_mod
 
 REF = harness.load_module(harness.PKG / "configs" / "moonlight_ref.py")
@@ -224,3 +225,40 @@ def test_each_planted_fault_changes_the_loss(fault):
         static_argnums=2)
     sound, bad = loss(p, bias, None), loss(p, bias, fault)
     assert abs(float(bad) - float(sound)) > 1e-4 * abs(float(sound))
+
+
+def test_attention_backward_stays_in_its_scope():
+    """Every instruction of the compiled train step that holds a
+    (q_chunk, Sk) score tile carries the ``repro.mla`` scope, by the join
+    the benchmark's scope split makes (``scope_map``), so that the
+    recompute backward is counted in ``moonlight.mla_ms``.  Parameters and
+    tuple elements are names of values, not device ops."""
+    t, qc = 80, 20   # sizes no other tensor of the small model has
+    c = small_moonlight.config()
+    cfg = model_config(c, "float32", 1.25).replace(q_chunk=qc)
+    opt = c["train"]["optimizer"]
+    mesh = lm_program.mesh_for(1)
+    scfg = S.StepConfig(param_dtype="float32", seq_parallel=False,
+                        capacity_factor=1.25, peak_lr=opt["peak_lr"],
+                        warmup_steps=opt["warmup_steps"],
+                        total_steps=opt["total_steps"])
+    before = attention.recompute_stats()["chunked_vjp"]
+    with jax.set_mesh(mesh):
+        step_fn, ss, bs, _ = S.make_train_step(cfg, mesh, scfg, seq_len=t,
+                                               global_batch=B)
+        text = jax.jit(step_fn).lower(ss, bs).compile().as_text()
+    assert attention.recompute_stats()["chunked_vjp"] > before
+    scopes = scope_map(text)
+    tile = re.compile(rf"\b(?:f32|bf16)\[[\d,]*\b(?:{qc},{t}|{t},{qc})\]")
+    instr = re.compile(r"^\s*(?:ROOT )?(%[\w.\-]+) = (\S+) ([a-z][\w\-]*)\(")
+    seen, outside = 0, []
+    for line in text.splitlines():
+        m = instr.match(line)
+        if not m or not tile.search(m.group(2)) or m.group(3) in (
+                "parameter", "get-tuple-element"):
+            continue
+        seen += 1
+        if scopes.get(m.group(1)) != "repro.mla":
+            outside.append(line.strip()[:200])
+    assert seen > 0
+    assert not outside, outside

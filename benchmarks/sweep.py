@@ -5,21 +5,18 @@
   python -m benchmarks.sweep --smoke --check BENCH_scenarios.json
   python -m benchmarks.sweep --update BENCH_scenarios.json   # regenerate
   python -m benchmarks.sweep --full --engine reference       # scalar oracle
-  python -m benchmarks.sweep --full --engine jax     # XLA-compiled engine
+  python -m benchmarks.sweep --full --engine pallas  # compiled engine
   python -m benchmarks.sweep --full --cache .sweep_cache.json  # reuse runs
   python -m benchmarks.sweep --bench-engine --smoke \\
       --bench-engines vector,reference \\
       --bench-check BENCH_engine.json                 # throughput gate (CI)
   JAX_ENABLE_X64=1 python -m benchmarks.sweep --bench-engine --smoke \\
-      --bench-engines vector,jax \\
-      --bench-check BENCH_engine.json                 # jax gate (CI)
-  JAX_ENABLE_X64=1 python -m benchmarks.sweep --bench-engine --smoke \\
-      --bench-engines jax,pallas \\
+      --bench-engines vector,pallas \\
       --bench-check BENCH_engine.json                 # pallas gate (CI)
   JAX_ENABLE_X64=1 python -m benchmarks.sweep --bench-engine --full \\
       --bench-out BENCH_engine.json   # regenerate throughput (x64: the
-      #                                 jax cells must match the CI gate's
-      #                                 precision mode)
+      #                                 pallas cells must match the CI
+      #                                 gate's precision mode)
   python -m benchmarks.sweep --profile --specs weak_scaling  # cProfile top-N
 
 ``--check`` diffs the fresh results against a committed golden baseline
@@ -39,12 +36,12 @@ time and events/sec (wire messages simulated per second of engine wall
 time) and writes the document to ``--bench-out`` when given.
 ``--bench-check`` gates against a committed ``BENCH_engine.json``: the
 compared quantities are the per-spec speedups of each ``BENCH_PAIRS``
-engine pair (vector-vs-reference, jax-vs-vector and pallas-vs-jax) —
+engine pair (vector-vs-reference and pallas-vs-vector) —
 both engines of a pair are measured in the same run on the same
 machine, so the ratio is hardware-independent — and a >2x relative
 slowdown fails; only pairs whose engines were both measured in this run
 are gated.  ``BENCH_SPEC_ENGINES`` restricts scalar-intractable grids
-(the 32k-rank XXL sweep) to the compiled engines.  The Fig-5/Fig-6
+(the 32k-rank XXL sweep) to the compiled engine.  The Fig-5/Fig-6
 contention crossover (part/many ~ single at 32 VCIs, >> single at 1 VCI)
 is printed whenever the fig6 spec ran.
 """
@@ -63,20 +60,19 @@ from repro.experiments import (SPECS, compare_to_baseline,
 from repro.experiments import engine as _engine_mod
 from repro.runtime.compile_cache import enable_compile_cache
 
-BENCH_ENGINES = ("vector", "reference", "jax", "pallas")
+BENCH_ENGINES = ("vector", "reference", "pallas")
 BENCH_VERSION = 1
 # Engine pairs whose same-job throughput ratio the regression gate
 # tracks: (numerator, denominator).  Both engines of a pair run in the
 # same process on the same machine, so the ratio is hardware-independent.
-BENCH_PAIRS = (("vector", "reference"), ("jax", "vector"),
-               ("pallas", "jax"))
+BENCH_PAIRS = (("vector", "reference"), ("pallas", "vector"))
 # Specs whose grids are tractable only on a subset of the engines: the
 # 32k-rank XXL sweep takes minutes per record on the scalar/NumPy
-# engines, so its bench cells are measured on the compiled engines
+# engines, so its bench cells are measured on the compiled engine
 # only.  Pair speedups are summed over the specs where BOTH engines of
 # the pair have cells, so a skipped cell narrows a pair's coverage
 # instead of skewing its ratio.
-BENCH_SPEC_ENGINES = {"weak_scaling_xxl": ("jax", "pallas")}
+BENCH_SPEC_ENGINES = {"weak_scaling_xxl": ("pallas",)}
 # Runners excluded from --bench-engine: the autotune runner re-simulates
 # a whole candidate grid of mostly tiny (scalar-path) scenarios per
 # record, so its wall time measures planner overhead, not fabric
@@ -114,10 +110,9 @@ def _parse_args(argv):
     ap.add_argument("--jobs", type=int, default=1,
                     help="process-pool width for scenario runs")
     ap.add_argument("--engine", default="vector",
-                    choices=("vector", "reference", "jax", "pallas"),
+                    choices=("vector", "reference", "pallas"),
                     help="fabric engine (vector = batched NumPy,"
-                         " reference = scalar oracle, jax = XLA-compiled"
-                         " with the vmapped whole-grid path, pallas ="
+                         " reference = scalar oracle, pallas ="
                          " fused single-kernel pipeline)")
     ap.add_argument("--cache", default="",
                     help="persistent JSON run cache: load before running,"
@@ -134,8 +129,8 @@ def _parse_args(argv):
     ap.add_argument("--bench-engines", default=",".join(BENCH_ENGINES),
                     help="comma-separated engines to measure with"
                          " --bench-engine (CI steps restrict this so the"
-                         " vector/reference and jax/vector gates each"
-                         " measure only their own pair)")
+                         " vector/reference and pallas/vector gates"
+                         " each measure only their own pair)")
     ap.add_argument("--bench-out", default="",
                     help="write the throughput document to this path"
                          " (omit to measure/check without writing)")
@@ -235,10 +230,10 @@ def run_bench_engine(specs, mode: str,
     _engine_mod._CACHE.clear()  # leave no half-measured state behind
     doc = {"version": BENCH_VERSION, "mode": mode, "entries": entries,
            "totals": totals}
-    if "jax" in engines or "pallas" in engines:
-        # record the precision mode: jax/pallas float64 vs float32
+    if "pallas" in engines:
+        # record the precision mode: pallas float64 vs float32
         # throughput differs, so a gate should compare like against like
-        # (the committed document and the CI compiled-engine gates all
+        # (the committed document and the CI compiled-engine gate both
         # run under JAX_ENABLE_X64=1)
         from repro.compat import x64_enabled
         doc["jax_enable_x64"] = x64_enabled()
@@ -270,7 +265,7 @@ def check_bench_regression(doc: dict, ref: dict) -> list:
     ratio — is hardware-independent: a slower CI runner slows both
     engines alike, while an engine code regression shows up directly.
     A pair is only gated when the fresh document measured both of its
-    engines (CI's vector/reference and jax/vector steps each restrict
+    engines (CI's vector/reference and pallas/vector steps each restrict
     ``--bench-engines`` to their own pair); specs under
     ``BENCH_MIN_EVENTS`` events are timer noise and exempt.
     """
@@ -398,17 +393,15 @@ def main(argv=None) -> int:
               f" {st['evictions']} evictions,"
               f" {st['messages_saved']} message re-sorts avoided",
               file=sys.stderr)
-        if args.engine in ("jax", "pallas"):
-            from repro.core import fabric_jax as _fj
+        if args.engine == "pallas":
+            from repro.core import fabric_pallas as _fp
             gst = _sim.grid_memo_stats()
-            lst = _fj.layout_memo_stats()
+            lst = _fp.layout_memo_stats()
             print(f"# grid-point memo: {gst['hits']} hits,"
                   f" {gst['misses']} misses, {gst['evictions']} evictions;"
                   f" stage-layout memo: {lst['hits']} hits,"
                   f" {lst['misses']} misses, {lst['evictions']} evictions",
                   file=sys.stderr)
-        if args.engine == "pallas":
-            from repro.core import fabric_pallas as _fp
             for name, ps in sorted(_fp.memo_stats().items()):
                 print(f"# pallas {name} memo: {ps['hits']} hits,"
                       f" {ps['misses']} misses, {ps['evictions']}"

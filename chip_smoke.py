@@ -19,7 +19,7 @@ Phases (one chip):
 
 * fabric — the ``weak_scaling_xxl`` smoke points (a 32x32x32-rank
   periodic torus, ~1.6M wire messages per partitioned record) on the
-  compiled pallas engine and on the jax engine, checked against
+  compiled pallas engine, checked against
   ``BENCH_scenarios.json`` by the sweep's ``--check`` comparison (2%
   relative, message counts exact); then two warm admission waves
   through one live pallas fabric against the NumPy engine, and the
@@ -124,21 +124,19 @@ def phase_fabric(spec_name: str = FABRIC_SPEC) -> None:
     baseline = json.loads((REPO / "BENCH_scenarios.json").read_text())
     ref = baseline["specs"][spec_name]["records"]
     spec = SPECS[spec_name]
-    for engine in ("pallas", "jax"):
-        walls = []
-        for _ in range(2):  # cold (compiles), then warm
-            xeng._CACHE.clear()
-            t0 = time.perf_counter()
-            results = run_spec(spec, mode="smoke", engine=engine)
-            walls.append(time.perf_counter() - t0)
-        violations = compare_to_baseline(baseline, {spec_name: results})
-        check(not violations, f"{spec_name} on {engine}: " +
-              "; ".join(violations))
-        emit("fabric", spec=spec_name, engine=engine, records=len(results),
-             events=int(sum(m["n_messages"] for m in results.values())),
-             max_rel_err_time_us=_max_rel(results, ref, "time_us"),
-             first_run_s=walls[0], warm_run_s=walls[1],
-             baseline_violations=len(violations))
+    walls = []
+    for _ in range(2):  # cold (compiles), then warm
+        xeng._CACHE.clear()
+        t0 = time.perf_counter()
+        results = run_spec(spec, mode="smoke", engine="pallas")
+        walls.append(time.perf_counter() - t0)
+    violations = compare_to_baseline(baseline, {spec_name: results})
+    check(not violations, f"{spec_name} on pallas: " + "; ".join(violations))
+    emit("fabric", spec=spec_name, engine="pallas", records=len(results),
+         events=int(sum(m["n_messages"] for m in results.values())),
+         max_rel_err_time_us=_max_rel(results, ref, "time_us"),
+         first_run_s=walls[0], warm_run_s=walls[1],
+         baseline_violations=len(violations))
 
     # Warm state: two admission waves through one live fabric (the
     # online ``advance`` entry point), the second starting while the

@@ -1,11 +1,11 @@
-"""Precision switch of the compiled fabric engines, and the repo's one
+"""Precision switch of the compiled fabric engine, and the repo's one
 ``shard_map`` spelling.
 
 This module owns the **x64 guard** for the compiled fabric engine
-(:mod:`repro.core.fabric_jax`): under ``JAX_ENABLE_X64`` the jax engine
-computes in float64 and is bit-for-bit identical to the scalar
-``ReferenceFabric``; under the float32 default it is tolerance-gated
-only.  :func:`x64_enabled` reports the active mode and :func:`x64_mode`
+(:mod:`repro.core.fabric_pallas`): under ``JAX_ENABLE_X64`` on the CPU
+backend the pallas engine computes in float64 and is bit-for-bit
+identical to the scalar ``ReferenceFabric``; under the float32 default
+it is tolerance-gated only.  :func:`x64_enabled` reports the active mode and :func:`x64_mode`
 forces one for a scope (the differential tests exercise both).
 """
 
@@ -17,7 +17,7 @@ import jax
 def x64_enabled() -> bool:
     """True when jax computes in float64 (``JAX_ENABLE_X64`` / config).
 
-    This is the jax engine's precision contract switch: x64 means
+    This is the pallas engine's precision contract switch: x64 means
     bit-for-bit equality with ``ReferenceFabric``; float32 means results
     are only tolerance-close (~1e-4 relative on arrival times).
     """

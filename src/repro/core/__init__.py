@@ -27,7 +27,6 @@ from .topology import CartTopology, HaloSpec  # noqa: F401
 _LAZY_EXPORTS = {
     "bucketing": ("bucketing", None),
     "earlybird": ("earlybird", None),
-    "fabric_jax": ("fabric_jax", None),
     "Bucket": ("bucketing", "Bucket"),
     "BucketPlan": ("bucketing", "BucketPlan"),
     "bucketed_apply": ("bucketing", "bucketed_apply"),

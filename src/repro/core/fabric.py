@@ -222,7 +222,7 @@ class ReferenceFabric:
         batched engines override this with their staged paths —
         bit-for-bit identical by the engine contract.  ``layout_key``
         names the wave's layout class for engines that memoize stage
-        layouts (the jax/pallas engines); it is ignored here.
+        layouts (the pallas engine); it is ignored here.
         """
         return np.array([
             self.transmit(float(t_ready[i]), float(nbytes[i]),
@@ -358,9 +358,9 @@ class Fabric(ReferenceFabric):
         Same contract as :meth:`ReferenceFabric.advance` — state carries
         across waves — routed through :meth:`transmit_arrays`, so a wide
         wave takes the staged grouped scans and a narrow one falls back
-        to the scalar path (bit-identical either way).  The jax/pallas
-        engines inherit this and supply their own ``transmit_arrays``,
-        giving all four engines one streaming entry point.
+        to the scalar path (bit-identical either way).  The pallas
+        engine inherits this and supplies its own ``transmit_arrays``,
+        giving all three engines one streaming entry point.
         """
         return self.transmit_arrays(t_ready, nbytes, vci, thread, put,
                                     am_copy, src, dst,
@@ -377,7 +377,7 @@ class Fabric(ReferenceFabric):
         flows by ``t_ready`` with a stable sort, exactly as the scalar
         ``_run_flows`` does).  Returns per-message receiver arrival times
         in the same row order.  ``layout_key`` is accepted for engine
-        interchangeability (the jax engine memoizes its stage layouts
+        interchangeability (the pallas engine memoizes its stage layouts
         under it); this engine recomputes groupings per call.
         """
         n = t_ready.shape[0]

@@ -1,9 +1,10 @@
 """The Pallas fabric engine: queue-scan kernels inside one jitted program.
 
-Fourth engine of the fabric family (``engine="pallas"``).  It advances
-the same three-stage resource model — per-rank VCI banks, per-rank NIC,
-per-directed-link wires — as the jax engine, with each stage's queue
-recurrence ``t[k] = max(r[k], t[k-1]) + c[k]`` run by a Pallas kernel:
+The compiled engine of the fabric family (``engine="pallas"``).  It
+advances the same three-stage resource model as
+:class:`repro.core.fabric.Fabric` — per-rank VCI banks, per-rank NIC,
+per-directed-link wires — with each stage's queue recurrence
+``t[k] = max(r[k], t[k-1]) + c[k]`` run by a Pallas kernel:
 
   * the whole grid of sweep points is flattened into one cfg-bucketed
     super-batch; per-stage jagged groups are re-bucketed by segment
@@ -57,23 +58,24 @@ from __future__ import annotations
 
 import functools
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, Hashable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from . import fabric as _fb
-from .fabric import NetConfig
-from .fabric_jax import (HAVE_JAX, GridItem, JaxFabric, _consts,
-                         _raw_layouts, _require_jax, x64_enabled)
+from .fabric import Fabric, NetConfig, _group_layout
 from ..kernels import runtime as _rt
 from ..runtime.spans import span
 
-if HAVE_JAX:
+try:  # gate the import so the NumPy engines keep working without jax
     import jax
     import jax.numpy as jnp
     from jax import lax
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
+    HAVE_JAX = True
+except ImportError:  # pragma: no cover - exercised only without jax
+    HAVE_JAX = False
 
 # A stage whose groups span at most this many distinct depths is
 # bucketed by *exact* depth — no padding rows, no wasted sublanes.
@@ -84,6 +86,31 @@ LANES = 128
 VMEM_BLOCK_BUDGET = 8 << 20
 # Deepest K tile; deeper buckets carry the recurrence across grid steps.
 MAX_TILE_DEPTH = 256
+
+
+def _require_jax():
+    if not HAVE_JAX:  # pragma: no cover
+        raise ImportError(
+            "engine='pallas' needs jax installed; use engine='vector' (the "
+            "batched NumPy engine) or engine='reference' instead")
+
+
+def x64_enabled() -> bool:
+    """float64 mode active (the bit-for-bit contract switch)."""
+    _require_jax()
+    from repro.compat import x64_enabled as _x64
+    return _x64()
+
+
+def _consts(cfg: NetConfig) -> Tuple[np.float64, ...]:
+    """NetConfig costs as *dynamic* scalars.  Passing them as jit
+    arguments (not trace-time constants) blocks XLA's
+    divide-by-constant -> multiply-by-reciprocal rewrite, which would
+    break the bit-for-bit contract under x64."""
+    return tuple(np.float64(v) for v in (
+        cfg.beta, cfg.beta_copy, cfg.alpha_wire, cfg.alpha_first,
+        cfg.alpha_msg, cfg.chi_switch, cfg.alpha_nic, cfg.alpha_put,
+        cfg.alpha_put_first, cfg.alpha_recv, cfg.eager_max, cfg.bcopy_max))
 
 
 def _kernel_dtype():
@@ -98,7 +125,7 @@ def _kernel_dtype():
             f"{backend!r} backend: Mosaic kernels have no float64, so the "
             "engine's bit-for-bit x64 contract holds only in interpret mode"
             " on the CPU.  Disable x64 (float32 is tolerance-close) or use"
-            " engine='jax'.")
+            " engine='vector'.")
     return jnp.float64
 
 
@@ -114,6 +141,37 @@ def _tiles(K: int, G: int) -> Tuple[int, int, int, int]:
     ns = -(-S // ts_max)
     TS = -(-(-(-S // ns)) // 8) * 8  # ceil(S / ns) rounded up to 8
     return TK, TK * nk, TS, TS * ns
+
+
+@dataclass
+class GridItem:
+    """One cold-start exchange of a whole-grid evaluation.
+
+    Columns are already in global merge order (the caller's stable sort
+    by ``t_ready``); ``key`` memoizes the assembled operands.
+    ``order[p]`` is the flow-major index of merge-ordered message p
+    (None: the columns are in flow-major order already).  ``plan_key``
+    names the exchange's structure — its flow-major ``src``, ``dst``,
+    ``vci`` columns and flows — independent of times: the engine keeps
+    the operands' plan of a structure under it across calls.
+    """
+    t_ready: np.ndarray
+    nbytes: np.ndarray
+    vci: np.ndarray
+    thread: np.ndarray
+    put: np.ndarray
+    am_copy: np.ndarray
+    src: np.ndarray
+    dst: np.ndarray
+    cfg: NetConfig
+    n_vcis: int
+    n_ranks: int
+    key: Optional[Hashable] = None
+    order: Optional[np.ndarray] = None
+    plan_key: Optional[Hashable] = None
+
+    def __len__(self) -> int:
+        return self.t_ready.shape[0]
 
 
 @dataclass
@@ -629,12 +687,18 @@ def plan_stats() -> dict:
     return dict(_PLAN_COUNTS)
 
 
+def layout_memo_stats() -> dict:
+    """Hit/miss counters of the warm driver path's stage layouts."""
+    return _LAYOUT_MEMO.stats()
+
+
 def clear_memos() -> None:
-    """Reset the pallas engine's operand caches, plans and built programs
-    with their counters (``sweep --profile`` cold pass; tests that change
-    the tiling constants)."""
+    """Reset the pallas engine's operand caches, stage layouts, plans
+    and built programs with their counters (``sweep --profile`` cold
+    pass; tests that change the tiling constants)."""
     _OPS_MEMO.clear()
     _ARR_MEMO.clear()
+    _LAYOUT_MEMO.clear()
     _PLANS.clear()
     _PLAN_COUNTS.update(builds=0, reuses=0)
     _build_call.cache_clear()
@@ -690,8 +754,7 @@ def _cfg_buckets(items: List[GridItem]) -> Dict[tuple, List[int]]:
 def transmit_grid(items: List[GridItem]) -> List[np.ndarray]:
     """Evaluate many independent cold-start exchanges through the scan
     kernels; returns each item's per-message arrival times in its input
-    (merge) order.  Drop-in for :func:`repro.core.fabric_jax
-    .transmit_grid` — used for points without an affine finish."""
+    (merge) order — used for points without an affine finish."""
     _require_jax()
     if not items:
         return []
@@ -740,6 +803,34 @@ def transmit_grid_finish(items: List[GridItem],
 # The warm-state driver fabric
 # ---------------------------------------------------------------------------
 
+# One stage's grouping of a batch: ``order`` permutes messages into
+# group-major layout, ``uniq`` names each group's resource id (bank /
+# rank / directed link), ``counts``/``offsets`` delimit the groups.
+RawLayout = Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]
+
+# Stage layouts of the warm driver path, keyed by the batch's layout
+# key (its merge key).  Not one of memo_stats()'s memos: a layout holds
+# no time, so it cannot answer a call from an earlier one.
+_LAYOUT_MEMO = _fb.CappedMemo(64)
+
+
+def _raw_layouts(src: np.ndarray, dst: np.ndarray, vci: np.ndarray,
+                 n_vcis: int, n_ranks: int,
+                 key: Optional[Hashable]) -> Tuple[RawLayout, ...]:
+    """Group the batch by each stage's resource id (memoized by ``key``).
+
+    The layouts depend only on the (src, dst, vci) columns — which the
+    memo key fully determines — never on times or sizes.
+    """
+    lays = _LAYOUT_MEMO.get(key)
+    if lays is None:
+        lays = (_group_layout(src * n_vcis + vci),
+                _group_layout(src),
+                _group_layout(src * n_ranks + dst))
+        _LAYOUT_MEMO.put(key, lays)
+    return lays
+
+
 def _arr_structure(lays, n: int):
     """Stage buckets + committed static operands of one arrivals-mode
     batch (the warm driver path's per-layout structure cache entry)."""
@@ -751,11 +842,11 @@ def _arr_structure(lays, n: int):
     return core, [jnp.asarray(a) for a in statics], grp_orders
 
 
-class PallasFabric(JaxFabric):
+class PallasFabric(Fabric):
     """Kernel fabric: one jitted scan-kernel program per staged batch.
 
     Scalar state stays authoritative on the Python side exactly as in
-    the jax engine — warm semantics (steady-state iterations, dependent
+    the NumPy engine — warm semantics (steady-state iterations, dependent
     RMA traffic between batches) are identical.  A staged batch folds
     the warm VCI owners into the host cost precompute, passes the
     per-resource busy-until clocks as the kernels' init vectors, and
@@ -764,6 +855,7 @@ class PallasFabric(JaxFabric):
     """
 
     def __init__(self, cfg: NetConfig, n_vcis: int, n_ranks: int = 2):
+        _require_jax()
         super().__init__(cfg, n_vcis, n_ranks=n_ranks)
         _kernel_dtype()  # fail at construction, not mid-simulation
 

@@ -282,10 +282,10 @@ class FaultyFabric(_DegradedWireMixin, Fabric):
 
 def make_faulty_fabric(engine: str, cfg: NetConfig, n_vcis: int,
                        n_ranks: int, faults: FaultSpec):
-    """Fabric factory for runs with active faults.  The jax and pallas
-    engines have no faulty kernels — retransmission rounds re-enter the
-    queues data-dependently, which defeats their whole-batch layouts —
-    so they fall back to the batched NumPy engine (documented in
+    """Fabric factory for runs with active faults.  The pallas engine
+    has no faulty kernels — retransmission rounds re-enter the queues
+    data-dependently, which defeats its whole-batch layouts — so it
+    falls back to the batched NumPy engine (documented in
     docs/robustness.md); ``fault_rate=0`` runs never get here and keep
     their compiled paths."""
     if engine == "reference":

@@ -592,7 +592,7 @@ def execute(module: Module, engine: str = "vector",
     the engines stay bit-for-bit with each other (x64).  With an active
     fault spec the retransmission loop of
     :func:`repro.core.simulator.simulate_faulty` re-queues dropped
-    messages into the live fabric (jax/pallas fall back to the batched
+    messages into the live fabric (pallas falls back to the batched
     NumPy fabric there, exactly like the faulty driver).
     """
     sched, flows, lens, cols = lower(module)

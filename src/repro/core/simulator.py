@@ -88,7 +88,7 @@ from .topology import CartTopology, HaloSpec
 from ..runtime.spans import span
 
 # The fabric engines selectable via the drivers' ``engine`` argument.
-ENGINES = ("vector", "reference", "jax", "pallas")
+ENGINES = ("vector", "reference", "pallas")
 
 # Backward-compatible alias: the scalar fabric used to live here.
 _Fabric = ReferenceFabric
@@ -100,11 +100,8 @@ def _make_fabric(engine: str, cfg: NetConfig, n_vcis: int,
         return Fabric(cfg, n_vcis, n_ranks=n_ranks)
     if engine == "reference":
         return ReferenceFabric(cfg, n_vcis, n_ranks=n_ranks)
-    if engine == "jax":
-        from . import fabric_jax  # lazy: keeps the NumPy path jax-free
-        return fabric_jax.JaxFabric(cfg, n_vcis, n_ranks=n_ranks)
     if engine == "pallas":
-        from . import fabric_pallas  # lazy, as above
+        from . import fabric_pallas  # lazy: keeps the NumPy path jax-free
         return fabric_pallas.PallasFabric(cfg, n_vcis, n_ranks=n_ranks)
     raise ValueError(f"unknown engine {engine!r}; one of {ENGINES}")
 
@@ -679,17 +676,13 @@ def merge_memo_stats() -> dict:
 
 
 def clear_merge_memo() -> None:
-    """Reset the merge-order, assembled-grid-point and (when the jax or
-    pallas engine is loaded) stage-layout/bucket/operand memos with
-    their counters — `sweep --profile` calls this so its cold pass is
-    cold."""
+    """Reset the merge-order, assembled-grid-point and (when the pallas
+    engine is loaded) its stage-layout/operand/plan memos with their
+    counters — `sweep --profile` calls this so its cold pass is cold."""
     import sys
     _MERGE_MEMO.clear()
     _MERGE_MESSAGES_SAVED[0] = 0
     _GRID_MEMO.clear()
-    fj = sys.modules.get("repro.core.fabric_jax")
-    if fj is not None:
-        fj.clear_layout_memo()
     fpl = sys.modules.get("repro.core.fabric_pallas")
     if fpl is not None:
         fpl.clear_memos()
@@ -741,7 +734,7 @@ def _merge_transmit(sched: Schedule, fab: Fabric,
     ``(finished, arrivals, starts)`` with arrivals back in flow-major
     order.  ``memo_key`` (when the caller can name the merge's
     equivalence class) reuses the hoisted argsort permutation and, on
-    the jax engine, the fabric's stage layouts.  This is the single
+    the pallas engine, the fabric's stage layouts.  This is the single
     bit-for-bit-critical copy of the merge: ordering or finish fixes
     land here for every caller.
     """
@@ -1099,7 +1092,7 @@ _GRID_MEMO = CappedMemo(32)
 
 
 def grid_memo_stats() -> dict:
-    """Hit/miss counters of the assembled-grid-point memo (the jax
+    """Hit/miss counters of the assembled-grid-point memo (the
     whole-grid path's outermost cache; when it hits, the merge/layout
     memos underneath are never even consulted)."""
     return _GRID_MEMO.stats()
@@ -1108,7 +1101,7 @@ def grid_memo_stats() -> dict:
 @dataclass
 class _PreparedStencil:
     """One stencil sweep point, assembled up to (but not including) the
-    fabric advance — the unit the vmapped whole-grid path stacks."""
+    fabric advance — the unit the whole-grid path batches."""
     approach: str
     sched: Schedule
     templates: List[Scenario]      # one intent class per dimension
@@ -1234,38 +1227,36 @@ def _result_from_rank_tts(prep: _PreparedStencil, aux: dict,
         tts_s=tts, n_messages=int(prep.lens.sum()))
 
 
-def simulate_stencil_grid(points: Sequence[Mapping], engine: str = "jax"
+def simulate_stencil_grid(points: Sequence[Mapping], engine: str = "pallas"
                           ) -> List[Optional[StencilResult]]:
     """Evaluate many stencil sweep points as one compiled grid.
 
     Each entry of ``points`` is a kwargs mapping for
     :func:`simulate_stencil` (``approach`` included, ``engine`` absent —
-    it is this function's argument).  Points are assembled into stamped
-    intent-batch tensors and merged with memoized sorts; the advance is
-    then ``engine="jax"`` — :func:`repro.core.fabric_jax.transmit_grid`,
-    the whole (approach x theta x n_vcis x size) grid in a few vmapped
-    XLA dispatches — or ``engine="pallas"`` — the fused single-kernel
+    it is this function's argument; ``"pallas"`` is its one value).
+    Points are assembled into stamped intent-batch tensors and merged
+    with memoized sorts; the advance is the fused single-kernel
     super-batch of :mod:`repro.core.fabric_pallas`, which also runs each
     point's (affine) finish reduction in-kernel and returns per-rank
     times directly.  Returns one :class:`StencilResult` per point, with
     None for points the batched path cannot evaluate (the caller falls
-    back to :func:`simulate_stencil`).  Both engines are bit-for-bit
-    identical to the per-point engines under ``JAX_ENABLE_X64``;
-    tolerance-close under float32.
+    back to :func:`simulate_stencil`).  Bit-for-bit identical to the
+    per-point engines under ``JAX_ENABLE_X64``; tolerance-close under
+    float32.
 
     Host stages are profiler spans (:mod:`repro.runtime.spans`):
     ``repro.fabric.grid`` around the call, and inside it ``prepare``,
-    ``merge``, ``permute`` and ``result`` (and, on the pallas engine,
-    ``finish_spec`` and the engine's own stages).
+    ``merge``, ``permute``, ``finish_spec``, the engine's own stages and
+    ``result``.
     """
-    if engine not in ("jax", "pallas"):
+    if engine != "pallas":
         raise ValueError(
-            f"unknown grid engine {engine!r}; one of ('jax', 'pallas')")
+            f"unknown grid engine {engine!r}; one of ('pallas',)")
     with span("fabric.grid"):
-        return _stencil_grid(points, engine)
+        return _stencil_grid(points)
 
 
-def _grid_entry(p: Mapping, fabric_jax) -> Optional[tuple]:
+def _grid_entry(p: Mapping, fabric_pallas) -> Optional[tuple]:
     """One point assembled, merge-sorted and permuted (memoized by its
     parameters when they hash): ``(prep, order, item, aux)``, or None
     when the batched path cannot take it."""
@@ -1285,62 +1276,50 @@ def _grid_entry(p: Mapping, fabric_jax) -> Optional[tuple]:
         order = _merge_order(prep.cols["t_ready"], prep.memo_key)
     with span("fabric.permute"):
         c = prep.cols
-        item = fabric_jax.GridItem(
+        item = fabric_pallas.GridItem(
             t_ready=c["t_ready"][order], nbytes=c["nbytes"][order],
             vci=c["vci"][order], thread=c["thread"][order],
             put=c["put"][order], am_copy=c["am_copy"][order],
             src=c["src"][order], dst=c["dst"][order],
             cfg=prep.cfg, n_vcis=prep.n_vcis, n_ranks=prep.n_ranks,
             key=prep.memo_key, order=order, plan_key=prep.plan_key)
-    # the trailing dict accumulates engine-lazy per-point state
-    # (pallas finish spec, sent-per-rank counts)
+    # the trailing dict accumulates lazy per-point state (finish spec,
+    # sent-per-rank counts)
     entry = (prep, order, item, {})
     _GRID_MEMO.put(pkey, entry)
     return entry
 
 
-def _stencil_grid(points: Sequence[Mapping], engine: str
+def _stencil_grid(points: Sequence[Mapping]
                   ) -> List[Optional[StencilResult]]:
     """The body of :func:`simulate_stencil_grid`."""
-    from . import fabric_jax  # lazy: only the compiled engines need jax
-    if engine == "pallas":
-        from . import fabric_pallas
-    prepared = [_grid_entry(p, fabric_jax) for p in points]
+    from . import fabric_pallas  # lazy: only the compiled engine needs jax
+    prepared = [_grid_entry(p, fabric_pallas) for p in points]
     results: List[Optional[StencilResult]] = [None] * len(prepared)
     live = [(i, e) for i, e in enumerate(prepared) if e is not None]
-    if engine == "pallas":
-        # split points by finish affinity: affine points reduce to
-        # per-rank times in-kernel, the rest return arrivals
-        fin_members, arr_members = [], []
-        for i, (prep, order, item, aux) in live:
-            if "finish" not in aux:
-                with span("fabric.finish_spec"):
-                    aux["finish"] = _pallas_finish_spec(prep)
-            (fin_members if aux["finish"] is not None
-             else arr_members).append((i, prep, order, item, aux))
-        if fin_members:
-            rank_tts = fabric_pallas.transmit_grid_finish(
-                [m[3] for m in fin_members],
-                [m[4]["finish"] for m in fin_members])
-            with span("fabric.result"):
-                for (i, prep, _, _, aux), tts in zip(fin_members, rank_tts):
-                    results[i] = _result_from_rank_tts(prep, aux, tts)
-        if arr_members:
-            arrs = fabric_pallas.transmit_grid(
-                [m[3] for m in arr_members])
-            with span("fabric.result"):
-                for (i, prep, order, _, _), sorted_arr in zip(arr_members,
-                                                              arrs):
-                    arrivals = np.empty_like(sorted_arr)
-                    arrivals[order] = sorted_arr
-                    results[i] = _finish_prepared(prep, arrivals)
-        return results
-    arrs = fabric_jax.transmit_grid([e[2] for _, e in live])
-    with span("fabric.result"):
-        for (i, (prep, order, _, _)), sorted_arr in zip(live, arrs):
-            arrivals = np.empty_like(sorted_arr)
-            arrivals[order] = sorted_arr
-            results[i] = _finish_prepared(prep, arrivals)
+    # split points by finish affinity: affine points reduce to per-rank
+    # times in-kernel, the rest return arrivals
+    fin_members, arr_members = [], []
+    for i, (prep, order, item, aux) in live:
+        if "finish" not in aux:
+            with span("fabric.finish_spec"):
+                aux["finish"] = _pallas_finish_spec(prep)
+        (fin_members if aux["finish"] is not None
+         else arr_members).append((i, prep, order, item, aux))
+    if fin_members:
+        rank_tts = fabric_pallas.transmit_grid_finish(
+            [m[3] for m in fin_members],
+            [m[4]["finish"] for m in fin_members])
+        with span("fabric.result"):
+            for (i, prep, _, _, aux), tts in zip(fin_members, rank_tts):
+                results[i] = _result_from_rank_tts(prep, aux, tts)
+    if arr_members:
+        arrs = fabric_pallas.transmit_grid([m[3] for m in arr_members])
+        with span("fabric.result"):
+            for (i, prep, order, _, _), sorted_arr in zip(arr_members, arrs):
+                arrivals = np.empty_like(sorted_arr)
+                arrivals[order] = sorted_arr
+                results[i] = _finish_prepared(prep, arrivals)
     return results
 
 
@@ -1859,9 +1838,9 @@ def simulate_faulty(approach: str, *, faults: Optional[FaultSpec],
     Engine handling: a **no-op spec** (no drops, no degradations)
     delegates straight to :func:`simulate_stencil` on the requested
     engine — bit-for-bit identical to the fault-free scenario on all
-    four engines by construction.  With active faults the jax/pallas
-    engines fall back to the batched NumPy fabric (retransmission
-    re-entry is data-dependent, which defeats their whole-batch
+    three engines by construction.  With active faults the pallas
+    engine falls back to the batched NumPy fabric (retransmission
+    re-entry is data-dependent, which defeats its whole-batch
     layouts); the result is identical to ``engine="vector"``.
 
     Schedules with dependent traffic (the RMA epochs) cannot be

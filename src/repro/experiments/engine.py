@@ -25,9 +25,8 @@ so changing a spec's constants invalidates its baseline records loudly
 
 Every entry point takes an ``engine`` argument (``"vector"`` — the
 batched NumPy fabric, the default — ``"reference"`` — the scalar
-oracle — ``"jax"`` — the XLA-compiled fabric — or ``"pallas"`` — the
-fused-kernel fabric; the compiled engines' stencil grids additionally
-take the whole-grid path of
+oracle — or ``"pallas"`` — the fused-kernel fabric, whose stencil grids
+additionally take the whole-grid path of
 :func:`run_records_batched`); the engine is deliberately *not* part of
 the record key, because every engine must reproduce the same baseline
 records, but it does key the run caches so different engines' results
@@ -221,7 +220,7 @@ def run_faulty(params: Mapping[str, Any],
 
     ``fault_rate`` is the per-partition drop probability; a
     ``fault_rate = 0`` point must reproduce the healthy stencil record
-    bit-for-bit (the no-op gate CI holds on all four engines).  The
+    bit-for-bit (the no-op gate CI holds on all three engines).  The
     goodput metrics make the paper's trade-off quantitative on the
     robustness axis: the bulk message stakes every partition on one
     drop draw and resends the whole buffer, the partitioned plan
@@ -665,22 +664,22 @@ def _stencil_sim_kwargs(params: Mapping[str, Any]) -> Dict[str, Any]:
 
 
 def run_records_batched(runner: str, points: Sequence[Mapping[str, Any]],
-                        engine: str = "jax"
+                        engine: str = "pallas"
                         ) -> Optional[List[Optional[Dict[str, float]]]]:
-    """Whole-grid evaluation: every sweep point in one vmapped jit call.
+    """Whole-grid evaluation: every sweep point in one compiled call.
 
-    On the jax and pallas engines, stencil-runner grids stack all their
-    points into stamped intent-batch tensors and run through
-    :func:`repro.core.simulator.simulate_stencil_grid` — a few XLA
-    dispatches (jax: vmapped pipeline; pallas: one fused kernel with
-    in-kernel finish reductions) for the entire (approach x theta x
-    n_vcis x size) grid instead of one Python-driven fabric per record.
+    On the pallas engine, stencil-runner grids stack all their points
+    into stamped intent-batch tensors and run through
+    :func:`repro.core.simulator.simulate_stencil_grid` — one fused
+    kernel program with in-kernel finish reductions for the entire
+    (approach x theta x n_vcis x size) grid instead of one
+    Python-driven fabric per record.
     Returns one metrics dict per point, with None for points the batched
     path cannot evaluate (dependent-traffic schedules, per-rank ready
     tables) — the caller runs those per point — or None wholesale when
     the (runner, engine) pair has no batched path at all.
     """
-    if engine not in ("jax", "pallas") or runner != "stencil":
+    if engine != "pallas" or runner != "stencil":
         return None
     results = sim.simulate_stencil_grid(
         [_stencil_sim_kwargs(p) for p in points], engine=engine)
@@ -695,10 +694,10 @@ def run_records(runner: str, points: Sequence[Mapping[str, Any]],
                 jobs: int = 1,
                 engine: str = DEFAULT_ENGINE) -> Dict[str, Dict[str, float]]:
     """Run deduplicated points through one runner; returns key -> metrics."""
-    if jobs > 1 and engine in ("jax", "pallas"):
+    if jobs > 1 and engine == "pallas":
         # the batched path below runs in this process, which then holds
         # the accelerator, and a forked worker that touches it fails or
-        # hangs: the compiled engines run in one process
+        # hangs: the compiled engine runs in one process
         raise ValueError(f"jobs={jobs} needs a NumPy engine; engine="
                          f"{engine!r} runs on the device in this process")
     keyed: Dict[str, Dict[str, Any]] = {}

@@ -133,8 +133,8 @@ WEAK_SCALING_XL = SweepSpec(
     smoke={"approach": ("pt2pt_single", "part"), "dims": ((16, 16, 16),)},
     baseline_approach="pt2pt_single",
     note="XL weak scaling to a 4096-rank periodic torus (196k wire"
-         " messages per partitioned record); sized for the jax engine's"
-         " vmapped whole-grid path",
+         " messages per partitioned record); sized for the compiled"
+         " engine's whole-grid path",
 )
 
 WEAK_SCALING_XXL = SweepSpec(

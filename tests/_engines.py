@@ -1,7 +1,7 @@
 """Shared engine-differential harness: one driver table, every engine.
 
-The four engine suites (``test_engine_diff.py``, ``test_engine_jax.py``,
-``test_engine_pallas.py`` and the serving/faults diff classes) grew
+The engine suites (``test_engine_diff.py``, ``test_engine_pallas.py``
+and the serving/faults diff classes) grew
 near-identical copies of the approach lists, the randomized ready-table
 builder, the forced-scan cutoff switching and the per-driver result
 comparison loops.  This module is the single copy: a :data:`DRIVERS`
@@ -28,7 +28,7 @@ from repro.core import simulator as sim
 APPROACHES = sorted(sim.APPROACHES)
 PIPELINED = ("part", "part_old", "pt2pt_single", "pt2pt_many")
 
-# Relative tolerance of the compiled engines' float32 mode (x64 off):
+# Relative tolerance of the compiled engine's float32 mode (x64 off):
 # single-precision rounding over a few thousand serial queue updates
 # stays well inside 1e-4 relative.
 F32_RTOL = 1e-4
@@ -46,13 +46,13 @@ def ready(n_threads, theta, seed):
 def grid_items(points):
     """Assemble GridItems + FinishSpecs for the low-level grid entry
     points, the way ``simulate_stencil_grid`` does internally."""
-    from repro.core import fabric_jax as fj
+    from repro.core import fabric_pallas as fp
     items, fins = [], []
     for p in points:
         prep = sim._prepare_stencil(**p)
         order = sim._merge_order(prep.cols["t_ready"], prep.memo_key)
         c = prep.cols
-        items.append(fj.GridItem(
+        items.append(fp.GridItem(
             t_ready=c["t_ready"][order], nbytes=c["nbytes"][order],
             vci=c["vci"][order], thread=c["thread"][order],
             put=c["put"][order], am_copy=c["am_copy"][order],
@@ -143,7 +143,7 @@ def assert_results_equal(a, b, fields, context=""):
 
 
 def assert_results_close(a, b, rtol=F32_RTOL):
-    """The compiled engines' float32 contract: structural counters stay
+    """The compiled engine's float32 contract: structural counters stay
     exact, times within ``rtol`` — ``time_s`` subtracts compute from
     tts, so its tolerance is anchored to the tts magnitude, not its own
     (possibly tiny) value."""
@@ -160,7 +160,7 @@ def assert_engines_agree(driver, approach, *,
 
     ``forced`` pushes every non-reference engine through the staged
     scans / fused kernels regardless of batch width (the reference
-    oracle has no batched path to force).  The compiled engines need
+    oracle has no batched path to force).  The compiled engine needs
     x64 for exact equality — callers wrap in ``compat.x64_mode(True)``.
     """
     case = DRIVERS[driver]

@@ -1,10 +1,8 @@
-"""Differential suite for the fused-kernel fabric engine
-(``engine="pallas"``).
+"""Differential suite for the compiled fabric engine (``engine="pallas"``).
 
-Mirrors ``tests/test_engine_jax.py`` for the fourth engine: the three
-grouped queue scans (VCI banks, NIC serialization, wire links) run as
-one fused Pallas program, so every driver and approach is diffed
-against the vectorized engine — and therefore the scalar
+The three grouped queue scans (VCI banks, NIC serialization, wire
+links) run as one fused Pallas program, so every driver and approach is
+diffed against the vectorized engine — and therefore the scalar
 ``ReferenceFabric`` — under both precision modes:
 
 * ``JAX_ENABLE_X64``: bit-for-bit, no tolerance.  The kernel consumes
@@ -21,9 +19,9 @@ Driver invocation and comparison fields come from the shared table in
 XLA — the differential guarantees carry to compiled TPU runs because
 the operand protocol and program are identical; their v5e compile
 is pinned by ``tests/test_tpu_compile.py``.  Lane and depth tiling of
-the scan kernels is diffed against the single-tile layout.  The 32768-rank
-``weak_scaling_xxl`` smoke tier must finish within budget and
-reproduce the committed baseline; the full XXL grid is ``slow``-marked.
+the scan kernels is diffed against the single-tile layout.  The 4096-
+and 32768-rank smoke tiers must finish within budget and reproduce the
+committed baseline; the full XXL grid is ``slow``-marked.
 """
 
 import json
@@ -39,7 +37,7 @@ from _engines import (APPROACHES, F32_RTOL, PIPELINED,  # noqa: E402
                       assert_engines_agree, assert_results_close,
                       forced_scans as forced, grid_items, ready)
 from repro import compat  # noqa: E402
-from repro.core import fabric_jax as fj  # noqa: E402
+from repro.core import fabric as fb  # noqa: E402
 from repro.core import fabric_pallas as fp  # noqa: E402
 from repro.core import perfmodel as pm  # noqa: E402
 from repro.core import simulator as sim  # noqa: E402
@@ -66,6 +64,18 @@ class TestX64BitForBit:
                     theta=theta, n_threads=n, n_vcis=vcis,
                     local_shape=(24, 8, 4)[:len(dims)],
                     ready=ready(n, theta, seed))
+
+    @pytest.mark.parametrize("ap", APPROACHES)
+    def test_stencil_deployment_shapes(self, ap):
+        """A non-periodic torus with a two-deep halo and aggregation:
+        boundary ranks send fewer faces, and the faces' sizes and the
+        aggregation threshold set every flow's message count."""
+        with compat.x64_mode(True):
+            assert_engines_agree(
+                "stencil", ap, engines=PV, forced=True, dims=(3, 2, 2),
+                periodic=False, theta=4, n_threads=2, n_vcis=2,
+                local_shape=(24, 8, 4), halo_width=2, aggr_bytes=4096.0,
+                ready=ready(2, 4, 17))
 
     @pytest.mark.parametrize("ap", APPROACHES)
     def test_halo_all_approaches(self, ap):
@@ -132,7 +142,7 @@ class TestX64BitForBit:
 class TestFloat32Tolerance:
     """Without x64 the engine is tolerance-gated, counters stay exact."""
 
-    @pytest.mark.parametrize("ap", PIPELINED)
+    @pytest.mark.parametrize("ap", APPROACHES)
     def test_stencil(self, ap):
         kw = dict(dims=(2, 2, 2), theta=4, n_threads=2, n_vcis=2,
                   local_shape=(24, 8, 4), ready=ready(2, 4, 11))
@@ -143,6 +153,23 @@ class TestFloat32Tolerance:
         np.testing.assert_allclose(rp.rank_tts_s, rv.rank_tts_s,
                                    rtol=F32_RTOL)
         assert_results_close(rp, rv)
+
+    @pytest.mark.parametrize("ap", APPROACHES)
+    def test_halo(self, ap):
+        kw = dict(n_ranks=4, theta=4, part_bytes=4096, n_threads=2,
+                  n_vcis=2, ready=ready(2, 4, 3))
+        with compat.x64_mode(False), forced():
+            rp = sim.simulate_halo(ap, engine="pallas", **kw)
+        rv = sim.simulate_halo(ap, engine="vector", **kw)
+        np.testing.assert_allclose(rp.rank_tts_s, rv.rank_tts_s,
+                                   rtol=F32_RTOL)
+        assert_results_close(rp, rv)
+
+    def test_x64_guard_reports_mode(self):
+        with compat.x64_mode(True):
+            assert fp.x64_enabled()
+        with compat.x64_mode(False):
+            assert not fp.x64_enabled()
 
 
 class TestGridPath:
@@ -166,16 +193,23 @@ class TestGridPath:
                 assert r.n_messages == rv.n_messages
                 assert r.time_s == rv.time_s and r.tts_s == rv.tts_s
 
-    def test_grid_matches_jax_engine_bitwise(self):
-        """Same grid through both compiled engines: identical records,
-        so BENCH speedups compare equal outputs."""
+    @pytest.mark.parametrize("dims", [(2, 2, 2), (3, 2, 2), (4, 4, 2)],
+                             ids=lambda d: "x".join(map(str, d)))
+    @pytest.mark.parametrize("ap", PIPELINED)
+    def test_grid_point_matches_vector_x64(self, ap, dims):
+        """One point a call, with its own ready table, as the fabric
+        cell sends them: the grid path's finish equals the per-point
+        vector engine."""
+        p = dict(self.POINTS[0], approach=ap, dims=dims,
+                 ready=ready(2, 4, sum(dims)))
         with compat.x64_mode(True):
-            rp = sim.simulate_stencil_grid(self.POINTS, engine="pallas")
-            rj = sim.simulate_stencil_grid(self.POINTS, engine="jax")
-            for a, b in zip(rp, rj):
-                assert a.rank_tts_s == b.rank_tts_s
-                assert a.n_messages == b.n_messages
-                assert a.time_s == b.time_s and a.tts_s == b.tts_s
+            r = sim.simulate_stencil_grid([p], engine="pallas")[0]
+            rv = sim.simulate_stencil(engine="vector", **p)
+        assert r is not None
+        assert r.rank_tts_s == rv.rank_tts_s
+        assert r.sent_per_rank == rv.sent_per_rank
+        assert r.n_messages == rv.n_messages
+        assert r.time_s == rv.time_s and r.tts_s == rv.tts_s
 
     def test_dependent_traffic_falls_back_to_none(self):
         with compat.x64_mode(True):
@@ -183,15 +217,21 @@ class TestGridPath:
             assert sim.simulate_stencil_grid(pts, engine="pallas") \
                 == [None]
 
-    def test_arrivals_mode_matches_jax_grid(self):
+    @pytest.mark.parametrize("ap", PIPELINED)
+    def test_arrivals_mode_matches_vector(self, ap):
         """The in-kernel arrivals output (the non-affine-finish escape
-        hatch) equals the jax engine's grid arrivals bit-for-bit."""
+        hatch) equals a cold vector fabric's arrivals bit-for-bit."""
+        pts = [dict(self.POINTS[0], approach=ap, dims=d)
+               for d in ((2, 2, 2), (3, 2, 2))]
         with compat.x64_mode(True):
-            items, _ = grid_items(self.POINTS)
+            items, _ = grid_items(pts)
             got = fp.transmit_grid(items)
-            ref = fj.transmit_grid(items)
-            for g, r in zip(got, ref):
-                assert np.array_equal(np.asarray(g), np.asarray(r))
+        for it, g in zip(items, got):
+            vec = fb.Fabric(it.cfg, it.n_vcis, n_ranks=it.n_ranks)
+            want = vec.transmit_arrays(it.t_ready, it.nbytes, it.vci,
+                                       it.thread, it.put, it.am_copy,
+                                       it.src, it.dst)
+            assert np.array_equal(np.asarray(g), want)
 
     def test_tiled_grid_matches_single_tile(self, monkeypatch):
         """Tiny VMEM and depth budgets split every scan bucket into
@@ -239,6 +279,65 @@ class TestGridPath:
     def test_batched_path_declines_other_runners(self):
         from repro.experiments.engine import run_records_batched
         assert run_records_batched("halo", [], engine="pallas") is None
+        assert run_records_batched("stencil", [], engine="vector") is None
+
+
+# the XLA stage-scan engine's name, retired with its engine
+RETIRED = "jax"
+
+
+@pytest.mark.parametrize("entry", ["simulate_stencil",
+                                   "simulate_stencil_grid", "run_records"])
+def test_retired_engine_is_refused(entry):
+    """The retired engine's name is refused at every entry."""
+    from repro.experiments.engine import run_records
+    p = dict(TestGridPath.POINTS[0])
+    calls = {
+        "simulate_stencil": lambda: sim.simulate_stencil(engine=RETIRED,
+                                                         **p),
+        "simulate_stencil_grid": lambda: sim.simulate_stencil_grid(
+            [p], engine=RETIRED),
+        "run_records": lambda: run_records("stencil", [p], engine=RETIRED),
+    }
+    with pytest.raises(ValueError, match=f"unknown (grid )?engine "
+                                         f"'{RETIRED}'"):
+        calls[entry]()
+
+
+class TestMemos:
+    """The host-side memos of the warm driver path and the plan key."""
+
+    COLS = dict(src=np.array([0, 1, 0, 2, 1, 0]),
+                dst=np.array([1, 2, 2, 0, 0, 1]),
+                vci=np.array([0, 1, 1, 0, 0, 1]))
+
+    def test_raw_layouts_reuse_by_key(self):
+        fp.clear_memos()
+        a = fp._raw_layouts(**self.COLS, n_vcis=2, n_ranks=3, key=("k",))
+        assert fp._raw_layouts(**self.COLS, n_vcis=2, n_ranks=3,
+                               key=("k",)) is a
+        st = fp.layout_memo_stats()
+        assert (st["hits"], st["misses"], st["size"]) == (1, 1, 1)
+        fresh = fp._raw_layouts(**self.COLS, n_vcis=2, n_ranks=3, key=None)
+        assert fresh is not a
+        for la, lf in zip(a, fresh):
+            assert all(np.array_equal(x, y) for x, y in zip(la, lf))
+        assert fp.layout_memo_stats()["size"] == 1
+
+    def test_plan_key_ignores_the_ready_table(self):
+        p = dict(TestGridPath.POINTS[1])
+        (a, b), _ = grid_items([dict(p, ready=ready(2, 4, 1)),
+                                dict(p, ready=ready(2, 4, 2))])
+        assert a.plan_key == b.plan_key
+        assert a.key != b.key
+        assert not np.array_equal(a.t_ready, b.t_ready)
+
+    def test_clear_merge_memo_clears_layout_memo(self):
+        fp._raw_layouts(**self.COLS, n_vcis=2, n_ranks=3, key=("c",))
+        assert fp.layout_memo_stats()["size"] >= 1
+        sim.clear_merge_memo()
+        st = fp.layout_memo_stats()
+        assert (st["size"], st["hits"], st["misses"]) == (0, 0, 0)
 
 
 class TestInterpretResolver:
@@ -271,7 +370,6 @@ class TestInterpretResolver:
         """Mosaic has no float64: under x64 on an accelerator backend the
         engine refuses at construction instead of running interpreted or
         silently in float32."""
-        from repro.core import fabric as fb
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         with compat.x64_mode(True):
             with pytest.raises(RuntimeError, match="float64"):
@@ -296,6 +394,25 @@ class TestInterpretResolver:
             assert ri.time_s == rv.time_s and ri.tts_s == rv.tts_s
 
 
+class TestWeakScalingXL:
+    """Acceptance: the 4096-rank tier is tractable in tier-1."""
+
+    def test_4096_rank_smoke_under_budget(self):
+        from repro.experiments import SPECS, compare_to_baseline, run_spec
+        spec = SPECS["weak_scaling_xl"]
+        t0 = time.perf_counter()
+        results = run_spec(spec, mode="smoke", engine="pallas")
+        wall = time.perf_counter() - t0
+        assert wall < 30.0, f"4096-rank smoke tier took {wall:.1f}s"
+        assert any("dims=16x16x16" in k for k in results)
+        baseline = json.loads(
+            (pathlib.Path(__file__).resolve().parent.parent /
+             "BENCH_scenarios.json").read_text())
+        violations = compare_to_baseline(
+            baseline, {"weak_scaling_xl": results})
+        assert not violations, "\n".join(violations)
+
+
 class TestWeakScalingXXL:
     """Acceptance: the 32768-rank tier is tractable in tier-1."""
 
@@ -316,19 +433,22 @@ class TestWeakScalingXXL:
 
     @pytest.mark.slow
     def test_32k_rank_full_grid_matches_jax(self):
-        """Full XXL grid (12 records, ~6.3M wire messages) through both
-        compiled engines: records bit-identical under x64."""
+        """Full XXL grid (12 records, ~6.3M wire messages) under x64:
+        every record bit-identical to the committed baseline."""
         from repro.experiments import SPECS, run_spec
         from repro.experiments.engine import _CACHE
         spec = SPECS["weak_scaling_xxl"]
         with compat.x64_mode(True):
             _CACHE.clear()
             rp = run_spec(spec, mode="full", engine="pallas")
-            rj = run_spec(spec, mode="full", engine="jax")
-        assert set(rp) == set(rj) and len(rp) == 12
+        want = json.loads(
+            (pathlib.Path(__file__).resolve().parent.parent /
+             "BENCH_scenarios.json").read_text()
+        )["specs"]["weak_scaling_xxl"]["records"]
+        assert set(rp) == set(want) and len(rp) == 12
         for key in rp:
             for metric, val in rp[key].items():
-                assert val == rj[key][metric], (key, metric)
+                assert val == want[key][metric], (key, metric)
 
 
 class TestWarmWaves:
@@ -336,13 +456,14 @@ class TestWarmWaves:
     each starting while the previous still holds VCIs, NICs and wires —
     the kernels' init vectors carry that state in and out."""
 
-    def test_waves_match_vector_bitwise(self):
+    @pytest.mark.parametrize("n_ranks,per_rank,n_vcis",
+                             [(512, 16, 4), (1024, 8, 2), (2048, 4, 1)])
+    def test_waves_match_vector_bitwise(self, n_ranks, per_rank, n_vcis):
         from chip_smoke import wave_traffic
-        from repro.core import fabric as fb
-        cols = wave_traffic(seed=3, n_ranks=512, per_rank=16)
+        cols = wave_traffic(seed=3, n_ranks=n_ranks, per_rank=per_rank)
         with compat.x64_mode(True):
-            pal = fp.PallasFabric(fb.DEFAULT_NET, 4, n_ranks=512)
-            vec = fb.Fabric(fb.DEFAULT_NET, 4, n_ranks=512)
+            pal = fp.PallasFabric(fb.DEFAULT_NET, n_vcis, n_ranks=n_ranks)
+            vec = fb.Fabric(fb.DEFAULT_NET, n_vcis, n_ranks=n_ranks)
             calls = fp._build_call.cache_info()
             shift = 0.0
             for _ in range(3):

@@ -5,13 +5,13 @@ Four contracts are pinned here:
 
 * **the no-op gate** — a fault-free :class:`FaultSpec` run of
   ``simulate_faulty`` is bit-for-bit the healthy ``simulate_stencil``
-  on *all four engines* (a ``factor == 1.0`` degradation window is
+  on *all three engines* (a ``factor == 1.0`` degradation window is
   likewise bitwise invisible: ``nbytes / (beta * 1.0)``);
 * **engine independence under faults** — drop verdicts are pure
   functions of (flow-major message id, attempt) from the spec's
   ``SeedSequence``, so the vector engine (staged scans forced on
   included) equals the scalar oracle bit-for-bit with faults active,
-  and the jax/pallas engines' documented fallback equals vector;
+  and the pallas engine's documented fallback equals vector;
 * **the robustness claim** — at the committed sweep operating point the
   partitioned approach beats the bulk message on goodput-under-drops,
   and serving p99 inflates several-fold for bulk vs marginally for
@@ -131,7 +131,7 @@ class TestDropDraws:
 
 
 # ---------------------------------------------------------------------------
-# The no-op gate: fault_rate=0 is bit-for-bit on all four engines
+# The no-op gate: fault_rate=0 is bit-for-bit on all three engines
 # ---------------------------------------------------------------------------
 
 class TestNoopGate:
@@ -184,7 +184,7 @@ class TestDrops:
             "faulty", "part", forced=True,
             faults=FaultSpec(drop_prob=0.05, seed=2), **STENCIL_KW)
 
-    @pytest.mark.parametrize("engine", ("jax", "pallas"))
+    @pytest.mark.parametrize("engine", ("pallas",))
     def test_compiled_engines_fall_back_to_vector(self, engine):
         spec = FaultSpec(drop_prob=0.05, seed=2)
         rv = sim.simulate_faulty("part", faults=spec, engine="vector",

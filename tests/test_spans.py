@@ -25,9 +25,8 @@ from repro.runtime import spans  # noqa: E402
 REPO = Path(__file__).resolve().parent.parent
 
 # the stages directly inside ``repro.fabric.grid``, in the order they run
-SHARED = ["prepare", "merge", "permute"]
-PALLAS = SHARED + ["finish_spec", "operands", "h2d", "launch", "readback",
-                   "result"]
+PALLAS = ["prepare", "merge", "permute", "finish_spec", "operands", "h2d",
+          "launch", "readback", "result"]
 OPERANDS = ["layouts", "cost_columns", "stage_ops", "finish_layout"]
 
 
@@ -66,29 +65,26 @@ def _inside(inner, outer) -> bool:
     return outer[1] <= inner[1] and inner[2] <= outer[2]
 
 
-@pytest.mark.parametrize("engine,scale", [("jax", 1.5), ("pallas", 1.75)])
+@pytest.mark.parametrize("engine,scale", [("pallas", 1.75)])
 def test_grid_point_trace_holds_every_stage_span(engine, scale, tmp_path):
     got, res = _traced_point(engine, scale, tmp_path)
     by = {}
     for s in got:
         by.setdefault(s[0], []).append(s)
-    stages = PALLAS if engine == "pallas" else SHARED + ["result"]
-    inner = OPERANDS if engine == "pallas" else []
     assert sorted(by) == sorted(["fabric.grid"]
-                                + ["fabric." + s for s in stages + inner])
+                                + ["fabric." + s for s in PALLAS + OPERANDS])
     assert all(len(v) == 1 for v in by.values()), by
     grid = by["fabric.grid"][0]
-    seq = [by["fabric." + s][0] for s in stages]
+    seq = [by["fabric." + s][0] for s in PALLAS]
     assert all(_inside(s, grid) for s in seq)
     # the stages run one after the other
     assert all(a[2] <= b[1] for a, b in zip(seq, seq[1:]))
-    if engine == "pallas":
-        ops = by["fabric.operands"][0]
-        assert all(_inside(by["fabric." + s][0], ops) for s in inner)
-        # the warm-up point of the same topology built the plan
-        assert ops[3]["plan_reused"] == 1
-        # float32 release times and costs of every message, at least
-        assert by["fabric.h2d"][0][3]["bytes"] > 4 * 4 * res.n_messages
+    ops = by["fabric.operands"][0]
+    assert all(_inside(by["fabric." + s][0], ops) for s in OPERANDS)
+    # the warm-up point of the same topology built the plan
+    assert ops[3]["plan_reused"] == 1
+    # float32 release times and costs of every message, at least
+    assert by["fabric.h2d"][0][3]["bytes"] > 4 * 4 * res.n_messages
 
 
 def test_fabric_program_module_is_jit_run(monkeypatch):

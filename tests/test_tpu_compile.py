@@ -25,7 +25,6 @@ import jax.numpy as jnp  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
 from _engines import grid_items  # noqa: E402
-from repro.core import fabric_jax as fj  # noqa: E402
 from repro.core import fabric_pallas as fp  # noqa: E402
 
 SHAPES = {"weak_scaling_xl": (16, 16, 16), "weak_scaling_xxl": (32, 32, 32)}
@@ -79,7 +78,7 @@ def _operands(mode, dims):
         core, dyn, statics, _ = fp._assemble(items, fins)
         return core, [np.shape(a) for a in dyn], statics
     it = items[1]  # the warm driver path advances one batch at a time
-    lays = fj._raw_layouts(it.src, it.dst, it.vci % it.n_vcis, it.n_vcis,
+    lays = fp._raw_layouts(it.src, it.dst, it.vci % it.n_vcis, it.n_vcis,
                            it.n_ranks, it.key)
     core, statics, _ = fp._arr_structure(lays, len(it))
     n = len(it)

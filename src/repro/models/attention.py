@@ -3,8 +3,11 @@
 All attention goes through ``masked_attention``, which scans over *query
 chunks* so the (B, H, Sq, Sk) score matrix never materializes — at 32k
 context a naive softmax would need ~8 GB/chip of scores.  Each chunk's
-softmax is exact (full key range), so this is numerically identical to the
-reference formulation; the Pallas flash-attention kernel
+softmax is exact, so this is numerically identical to the reference
+formulation.  Where the positions are the token indices (training, no
+cache) a chunk scores only the key blocks the causal mask leaves it, the
+prefix up to its own last row; with position arrays (a cache, per-batch
+rows) it scores the full key range.  The Pallas flash-attention kernel
 (repro.kernels.flash_attention) is the TPU-tiled version of the same
 contraction.  The backward of the chunked path (a custom VJP) keeps only
 q, k, v, the output and each row's log-sum-exp, and recomputes one
@@ -79,18 +82,27 @@ def _attn_block(q, k, v, q_pos, k_pos, window, cap, scale, out_dtype):
     return out.reshape(b, sq, h, -1)
 
 
-def masked_attention(q, k, v, *, q_pos, k_pos, window=0,
+def masked_attention(q, k, v, *, q_pos=None, k_pos=None, window=0,
                      attn_softcap: Optional[float] = None,
                      scale: float, q_chunk: int = 512) -> jax.Array:
     """q: (B,Sq,H,Dk), k: (B,Sk,Kv,Dk), v: (B,Sk,Kv,Dv), Kv | H (uniform
     grouping: q head i attends kv head i // (H/Kv)) -> (B,Sq,H,Dv).
 
-    Scans over query chunks; each chunk sees the full key range, so the
-    softmax is exact.  The chunked path's backward recomputes each
-    chunk's scores (``_chunked``).
+    ``q_pos`` and ``k_pos`` None: the positions are the token indices, a
+    self-attention over one sequence (Sq == Sk).  Position arrays (a
+    cache, per-batch rows, M-RoPE) are taken as they are.
+
+    Scans over query chunks, each chunk's softmax exact.  With position
+    arrays each chunk scores the full key range; with index positions
+    chunk i scores only the keys ``[0, (i+1) q_chunk)`` it can see, since
+    the causal mask takes every later key block whole.  The chunked
+    path's backward recomputes each chunk's scores (``_chunked``).
     """
     sq = q.shape[1]
-    if sq <= q_chunk or sq % q_chunk != 0 or q_pos.ndim > 2:
+    short = sq <= q_chunk or sq % q_chunk != 0
+    if q_pos is None and short:
+        q_pos = k_pos = jnp.arange(sq)
+    if short or (q_pos is not None and q_pos.ndim > 2):
         return _attn_block(q, k, v, q_pos, k_pos, window, attn_softcap,
                            scale, q.dtype)
     return _chunked(q, k, v, q_pos, k_pos, window, attn_softcap, scale,
@@ -98,11 +110,33 @@ def masked_attention(q, k, v, *, q_pos, k_pos, window=0,
 
 
 _RECOMPUTE = {"chunked_vjp": 0}
+_BLOCKS = {"scored": 0, "grid": 0}
 
 
 def recompute_stats() -> dict:
     """Chunked attention calls traced with the recompute backward."""
     return dict(_RECOMPUTE)
+
+
+def block_stats() -> dict:
+    """Key blocks (``q_chunk`` keys wide) that the traced chunked passes
+    score, and the blocks of their full (query chunk, key block) grids;
+    each traced primal, forward and backward adds its own."""
+    return dict(_BLOCKS)
+
+
+def _count_blocks(q_pos, nc, sk, c):
+    grid = nc * -(-sk // c)
+    _BLOCKS["grid"] += grid
+    _BLOCKS["scored"] += grid if q_pos is not None else nc * (nc + 1) // 2
+
+
+def _prefixes(nc, c):
+    """Index positions: query chunk i's rows, the end of the key prefix it
+    can see, and the positions of both."""
+    for i in range(nc):
+        hi = (i + 1) * c
+        yield slice(i * c, hi), hi, jnp.arange(i * c, hi), jnp.arange(hi)
 
 
 def _split(x, nc):
@@ -129,8 +163,16 @@ def _chunked(q, k, v, q_pos, k_pos, window, cap, scale, q_chunk):
     """The query-chunk scan.  Its backward (FlashAttention-2's at chunk
     granularity) keeps ``q, k, v``, the output and each row's
     log-sum-exp, and recomputes a chunk's scores from them: autodiff of
-    the scan would stack every chunk's f32 scores, (Sq, Sk) per head."""
+    the scan would stack every chunk's f32 scores, (Sq, Sk) per head.
+    Index positions (``q_pos`` None) unroll the chunks, each over its
+    static key prefix."""
     nc = q.shape[1] // q_chunk
+    _count_blocks(q_pos, nc, k.shape[1], q_chunk)
+    if q_pos is None:
+        return jnp.concatenate(
+            [_attn_block(q[:, rows], k[:, :hi], v[:, :hi], qp, kp, window,
+                         cap, scale, q.dtype)
+             for rows, hi, qp, kp in _prefixes(nc, q_chunk)], axis=1)
 
     def body(_, xs):
         qc, pc = xs
@@ -141,72 +183,110 @@ def _chunked(q, k, v, q_pos, k_pos, window, cap, scale, q_chunk):
     return _merge(out)
 
 
+def _chunk_fwd(qc, k, v, pc, k_pos, window, cap, scale):
+    """One query chunk's output and each row's log-sum-exp (B,Kv,G,c)."""
+    s, m = _scores(qc, k, pc, k_pos, window, cap, scale)
+    # NEG_INF as a function of s: under a remat'd layer a constant fill
+    # is hoisted out of the scan into a tile-sized buffer of its own,
+    # outside the caller's scope
+    s = jnp.where(m, s, jnp.minimum(s, NEG_INF))
+    top = jnp.max(s, axis=-1, keepdims=True)
+    e = jnp.exp(s - top)
+    tot = jnp.sum(e, axis=-1, keepdims=True)
+    o = jnp.einsum("bkgqs,bskd->bqkgd", (e / tot).astype(qc.dtype), v)
+    return o.reshape(*qc.shape[:3], -1), (top + jnp.log(tot))[..., 0]
+
+
 def _chunked_fwd(q, k, v, q_pos, k_pos, window, cap, scale, q_chunk):
-    """The same scan, with each row's log-sum-exp (B,Kv,G,c) per chunk."""
+    """The same chunks, with each row's log-sum-exp (nc, B,Kv,G,c)."""
     nc = q.shape[1] // q_chunk
+    _count_blocks(q_pos, nc, k.shape[1], q_chunk)
+    if q_pos is None:
+        out, lse = zip(*(_chunk_fwd(q[:, rows], k[:, :hi], v[:, :hi], qp,
+                                    kp, window, cap, scale)
+                         for rows, hi, qp, kp in _prefixes(nc, q_chunk)))
+        out, lse = jnp.concatenate(out, axis=1), jnp.stack(lse)
+    else:
+        def body(_, xs):
+            qc, pc = xs
+            return (), _chunk_fwd(qc, k, v, pc, k_pos, window, cap, scale)
 
-    def body(_, xs):
-        qc, pc = xs
-        s, m = _scores(qc, k, pc, k_pos, window, cap, scale)
-        # NEG_INF as a function of s: under a remat'd layer a constant
-        # fill is hoisted out of the scan into a tile-sized buffer of its
-        # own, outside the caller's scope
-        s = jnp.where(m, s, jnp.minimum(s, NEG_INF))
-        top = jnp.max(s, axis=-1, keepdims=True)
-        e = jnp.exp(s - top)
-        tot = jnp.sum(e, axis=-1, keepdims=True)
-        o = jnp.einsum("bkgqs,bskd->bqkgd", (e / tot).astype(q.dtype), v)
-        return (), (o.reshape(*qc.shape[:3], -1),
-                    (top + jnp.log(tot))[..., 0])
-
-    _, (out, lse) = jax.lax.scan(body, (), (_split(q, nc),
-                                            _chunk_pos(q_pos, nc)))
-    out = _merge(out)
+        _, (out, lse) = jax.lax.scan(body, (), (_split(q, nc),
+                                                _chunk_pos(q_pos, nc)))
+        out = _merge(out)
     return out, (q, k, v, q_pos, k_pos, window, out, lse)
+
+
+def _chunk_bwd(qc, k, v, pc, k_pos, window, cap, scale, lc, doc, rc):
+    """One query chunk's dq and its f32 dk, dv over the keys it scored."""
+    b, c, h, _ = qc.shape
+    kv = k.shape[2]
+    f32 = jnp.float32
+    qg = qc.reshape(b, c, kv, h // kv, -1)
+    dog = doc.reshape(b, c, kv, h // kv, -1)
+    t, m = _scores(qc, k, pc, k_pos, window, cap, scale)
+    p = jnp.exp(jnp.where(m, t, NEG_INF) - lc[..., None])
+    dv = jnp.einsum("bkgqs,bqkgd->bskd", p.astype(qc.dtype), dog,
+                    preferred_element_type=f32)
+    dp = jnp.einsum("bqkgd,bskd->bkgqs", dog, v, preferred_element_type=f32)
+    ds = p * (dp - jnp.moveaxis(rc, 1, -1)[..., None])
+    if cap is not None:  # d(cap tanh(x / cap))/dx = 1 - tanh^2
+        ds = ds * (1.0 - jnp.square(t / cap))
+    ds = ds * scale
+    dq = jnp.einsum("bkgqs,bskd->bqkgd", ds, k, preferred_element_type=f32)
+    dk = jnp.einsum("bkgqs,bqkgd->bskd", ds, qg, preferred_element_type=f32)
+    return dq.reshape(qc.shape).astype(qc.dtype), dk, dv
 
 
 def _chunked_bwd(cap, scale, q_chunk, res, d_out):
     """Per chunk: p = exp(s - lse); dv += p^T dO; dp = dO v^T;
     ds = p (dp - rowsum(dO o)) through the softcap and the scale;
     dq = ds k; dk += ds^T q, summed over each kv head's query group.
-    dk and dv accumulate in f32."""
+    dk and dv accumulate in f32, with index positions into the key
+    prefix each chunk scored."""
     q, k, v, q_pos, k_pos, window, out, lse = res
     _RECOMPUTE["chunked_vjp"] += 1
     b, sq, h, _ = q.shape
     kv = k.shape[2]
     nc = sq // q_chunk
+    _count_blocks(q_pos, nc, k.shape[1], q_chunk)
     f32 = jnp.float32
     # rowsum(dO * o), the softmax backward's row term: (B, Sq, Kv, G)
     rows = jnp.sum(d_out.astype(f32) * out.astype(f32), axis=-1)
     rows = rows.reshape(b, sq, kv, h // kv)
+    dk_acc, dv_acc = jnp.zeros(k.shape, f32), jnp.zeros(v.shape, f32)
 
-    def body(acc, xs):
-        dk_acc, dv_acc = acc
-        qc, pc, lc, doc, rc = xs
-        c = qc.shape[1]
-        qg = qc.reshape(b, c, kv, h // kv, -1)
-        dog = doc.reshape(b, c, kv, h // kv, -1)
-        t, m = _scores(qc, k, pc, k_pos, window, cap, scale)
-        p = jnp.exp(jnp.where(m, t, NEG_INF) - lc[..., None])
-        dv_acc = dv_acc + jnp.einsum("bkgqs,bqkgd->bskd", p.astype(q.dtype),
-                                     dog, preferred_element_type=f32)
-        dp = jnp.einsum("bqkgd,bskd->bkgqs", dog, v,
-                        preferred_element_type=f32)
-        ds = p * (dp - jnp.moveaxis(rc, 1, -1)[..., None])
-        if cap is not None:  # d(cap tanh(x / cap))/dx = 1 - tanh^2
-            ds = ds * (1.0 - jnp.square(t / cap))
-        ds = ds * scale
-        dq = jnp.einsum("bkgqs,bskd->bqkgd", ds, k,
-                        preferred_element_type=f32)
-        dk_acc = dk_acc + jnp.einsum("bkgqs,bqkgd->bskd", ds, qg,
-                                     preferred_element_type=f32)
-        return (dk_acc, dv_acc), dq.reshape(qc.shape).astype(q.dtype)
+    if q_pos is None:
+        dq = []
+        for i, (r, hi, qp, kp) in enumerate(_prefixes(nc, q_chunk)):
+            # a chunk's operands wait for the sums of the one before: left
+            # free, XLA interleaves several chunks' score tiles (2.8x the
+            # scan's temporaries for one MLA layer, compiled for a v5e)
+            xs, dk_acc, dv_acc = jax.lax.optimization_barrier(
+                ((q[:, r], k[:, :hi], v[:, :hi], lse[i], d_out[:, r],
+                  rows[:, r]), dk_acc, dv_acc))
+            qc, kc, vc, lc, doc, rc = xs
+            dqc, dk, dv = _chunk_bwd(qc, kc, vc, qp, kp, window, cap, scale,
+                                     lc, doc, rc)
+            dk_acc = jax.lax.dynamic_update_slice_in_dim(
+                dk_acc, dk_acc[:, :hi] + dk, 0, axis=1)
+            dv_acc = jax.lax.dynamic_update_slice_in_dim(
+                dv_acc, dv_acc[:, :hi] + dv, 0, axis=1)
+            dq.append(dqc)
+        dq = jnp.concatenate(dq, axis=1)
+    else:
+        def body(acc, xs):
+            qc, pc, lc, doc, rc = xs
+            dqc, dk, dv = _chunk_bwd(qc, k, v, pc, k_pos, window, cap,
+                                     scale, lc, doc, rc)
+            return (acc[0] + dk, acc[1] + dv), dqc
 
-    acc = (jnp.zeros(k.shape, f32), jnp.zeros(v.shape, f32))
-    (dk_acc, dv_acc), dq = jax.lax.scan(
-        body, acc, (_split(q, nc), _chunk_pos(q_pos, nc), lse,
-                    _split(d_out, nc), _split(rows, nc)))
-    return (_merge(dq), dk_acc.astype(k.dtype), dv_acc.astype(v.dtype),
+        (dk_acc, dv_acc), dq = jax.lax.scan(
+            body, (dk_acc, dv_acc),
+            (_split(q, nc), _chunk_pos(q_pos, nc), lse, _split(d_out, nc),
+             _split(rows, nc)))
+        dq = _merge(dq)
+    return (dq, dk_acc.astype(k.dtype), dv_acc.astype(v.dtype),
             None, None, None)
 
 
@@ -283,9 +363,8 @@ def attention_fwd(p: Dict, x: jax.Array, *, positions: jax.Array,
         k, v = ck, cv
         k_pos = jnp.arange(k.shape[1])
         q_pos = tpos if tpos.ndim >= 1 else tpos[None]
-    else:
-        k_pos = jnp.arange(k.shape[1])
-        q_pos = jnp.arange(q.shape[1])
+    else:  # the token indices
+        q_pos = k_pos = None
 
     n_kv = k.shape[2]
     h_padded = q.shape[2]
@@ -386,10 +465,9 @@ def mla_fwd(p: Dict, x: jax.Array, *, positions: jax.Array, qk_nope: int,
         ckv_att, kr_att = ckv_full, kr_full
         k_pos = jnp.arange(ckv_full.shape[1])
         q_pos = positions if positions.ndim >= 1 else positions[None]
-    else:
+    else:  # the token indices
         ckv_att, kr_att = ckv, kr
-        k_pos = jnp.arange(ckv.shape[1])
-        q_pos = jnp.arange(x.shape[1])
+        q_pos = k_pos = None
 
     k_nope = jnp.einsum("bsr,rhk->bshk", ckv_att, p["w_uk"])
     v = jnp.einsum("bsr,rhk->bshk", ckv_att, p["w_uv"])

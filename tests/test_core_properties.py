@@ -11,10 +11,12 @@ try:
 except ImportError:  # env without hypothesis: deterministic fallback
     from _hypo import given, settings, st
 
+from repro.configs import get_smoke_config
 from repro.core import bucketing
 from repro.core.flash_decode import flash_decode_ref
 from repro.kernels.flash_attention import flash_attention
 from repro.models import attention as attn_mod
+from repro.models import lm
 from repro.models.attention import masked_attention
 
 
@@ -53,6 +55,16 @@ class TestBucketingProperties:
         leaves = [jnp.zeros((16,)) for _ in range(12)]
         base = bucketing.make_plan(leaves, 0).n_buckets
         assert bucketing.make_plan(leaves, aggr).n_buckets <= base
+
+
+def _blocks_traced(f, *args):
+    """(scored, grid) key blocks that tracing ``jit(f)(*args)`` adds to
+    ``attention.block_stats()``."""
+    before = attn_mod.block_stats()
+    jax.jit(f).lower(*args)
+    after = attn_mod.block_stats()
+    return (after["scored"] - before["scored"],
+            after["grid"] - before["grid"])
 
 
 class TestAttentionConsistency:
@@ -135,6 +147,101 @@ class TestAttentionConsistency:
             assert g.dtype == w.dtype
             np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                        rtol=2e-5, atol=2e-5)
+
+    # (query heads per kv head, dk, dv, window, softcap, query chunks)
+    @pytest.mark.parametrize("group,dk,dv,window,cap,nc", [
+        (1, 32, 32, 0, None, 2),
+        (2, 48, 32, 32, None, 4),
+        (4, 48, 32, 0, 30.0, 8),
+        (1, 48, 32, 32, 30.0, 4),
+        (2, 32, 32, 0, 30.0, 8),
+        (4, 48, 32, 32, None, 2),
+        (2, 48, 32, 32, 30.0, 8),
+    ])
+    def test_index_positions_match_one_block(self, group, dk, dv, window,
+                                             cap, nc):
+        """Positions None (the token indices): the chunked path, which
+        scores only each chunk's causal key prefix, against one
+        unchunked block with explicit aranges, in the output and in the
+        gradient in q, k and v."""
+        b, s, kv = 2, 128, 2
+        ks = jax.random.split(jax.random.PRNGKey(group + dk + nc), 4)
+        q = jax.random.normal(ks[0], (b, s, kv * group, dk))
+        k = jax.random.normal(ks[1], (b, s, kv, dk))
+        v = jax.random.normal(ks[2], (b, s, kv, dv))
+        ct = jax.random.normal(ks[3], (b, s, kv * group, dv))
+        pos = jnp.arange(s)
+        scale = dk ** -0.5
+
+        def chunked(q, k, v):
+            return masked_attention(q, k, v, window=window,
+                                    attn_softcap=cap, scale=scale,
+                                    q_chunk=s // nc)
+
+        def block(q, k, v):
+            return attn_mod._attn_block(q, k, v, pos, pos, window, cap,
+                                        scale, q.dtype)
+
+        np.testing.assert_allclose(np.asarray(jax.jit(chunked)(q, k, v)),
+                                   np.asarray(jax.jit(block)(q, k, v)),
+                                   rtol=2e-5, atol=2e-5)
+        got, want = (jax.jit(jax.grad(lambda *a: jnp.sum(ct * f(*a)),
+                                      (0, 1, 2)))(q, k, v)
+                     for f in (chunked, block))
+        for g, w in zip(got, want):
+            assert g.dtype == w.dtype
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       rtol=2e-5, atol=2e-5)
+
+    @pytest.mark.parametrize("s,qc", [(128, 64), (256, 64), (4096, 512)])
+    def test_block_counts(self, s, qc):
+        """Each traced chunked pass (the primal; under a gradient its
+        forward and its backward) scores nc(nc+1)/2 of the nc^2 key
+        blocks with index positions (36 of 64 for 4,096 tokens in chunks
+        of 512), and every block with position arrays."""
+        b, h, d, nc = 1, 2, 8, s // qc
+        x = jax.ShapeDtypeStruct((b, s, h, d), jnp.float32)
+        pos = jnp.arange(s)
+
+        def blocks(**kw):
+            def fwd(q, k, v):
+                return masked_attention(q, k, v, scale=d ** -0.5,
+                                        q_chunk=qc, **kw)
+
+            grad = jax.grad(lambda *a: jnp.sum(fwd(*a)), (0, 1, 2))
+            return [_blocks_traced(f, x, x, x) for f in (fwd, grad)]
+
+        causal = nc * (nc + 1) // 2
+        assert blocks() == [(causal, nc * nc), (2 * causal, 2 * nc * nc)]
+        assert blocks(q_pos=pos, k_pos=pos) == [(nc * nc, nc * nc),
+                                                (2 * nc * nc, 2 * nc * nc)]
+
+    @pytest.mark.parametrize("arch_id", ["granite-moe-3b-a800m",
+                                         "moonshot-v1-16b-a3b"])
+    def test_model_block_counts(self, arch_id):
+        """Through a small model: prefill, which writes a cache and so
+        passes position arrays, skips no key block; the training loss's
+        gradient scores the causal share of them."""
+        s, qc = 32, 8
+        nc = s // qc
+        cfg = get_smoke_config(arch_id).replace(q_chunk=qc)
+        params = jax.eval_shape(lambda: lm.init_params(
+            cfg, jax.random.PRNGKey(0)))
+        tok = jax.ShapeDtypeStruct((2, s), jnp.int32)
+        bias = ({"route_bias": lm.init_route_state(cfg)["bias"]}
+                if cfg.moe is not None and cfg.moe.biased else {})
+
+        def prefill(p, t):
+            return lm.prefill(cfg, p, {"tokens": t}, **bias)
+
+        def grad(p, t):
+            return jax.grad(lambda p: lm.loss_fn(
+                cfg, p, {"tokens": t, "labels": t}, **bias))(p)
+
+        for f, share in ((prefill, 1), (grad, (nc + 1) / (2 * nc))):
+            scored, grid = _blocks_traced(f, params, tok)
+            assert grid > 0
+            assert scored == grid * share
 
     def test_chunked_gradient_keeps_no_score_stack(self):
         """The compiled gradient of the chunked path holds no buffer of

@@ -228,11 +228,16 @@ def test_each_planted_fault_changes_the_loss(fault):
 
 
 def test_attention_backward_stays_in_its_scope():
-    """Every instruction of the compiled train step that holds a
-    (q_chunk, Sk) score tile carries the ``repro.mla`` scope, by the join
-    the benchmark's scope split makes (``scope_map``), so that the
-    recompute backward is counted in ``moonlight.mla_ms``.  Parameters and
-    tuple elements are names of values, not device ops."""
+    """Every instruction of the compiled train step that holds a score
+    tile of a query chunk against its key prefix, (q_chunk, j q_chunk)
+    for every j up to Sk / q_chunk, carries the ``repro.mla`` scope, by
+    the join the benchmark's scope split makes (``scope_map``), so that
+    every chunk of the recompute backward is counted in
+    ``moonlight.mla_ms``.  Parameters and tuple elements are names of
+    values, not device ops, and so is a scalar constant's splat inside a
+    fusion: it is an operand of the fusion's elementwise ops, which take
+    their scope from their own metadata, while the splat keeps the
+    constant's, the scope of the remat'd layer around it."""
     t, qc = 80, 20   # sizes no other tensor of the small model has
     c = small_moonlight.config()
     cfg = model_config(c, "float32", 1.25).replace(q_chunk=qc)
@@ -249,16 +254,23 @@ def test_attention_backward_stays_in_its_scope():
         text = jax.jit(step_fn).lower(ss, bs).compile().as_text()
     assert attention.recompute_stats()["chunked_vjp"] > before
     scopes = scope_map(text)
-    tile = re.compile(rf"\b(?:f32|bf16)\[[\d,]*\b(?:{qc},{t}|{t},{qc})\]")
+    tiles = {w: re.compile(
+        rf"\b(?:f32|bf16)\[[\d,]*\b(?:{qc},{w}|{w},{qc})\]")
+        for w in range(qc, t + 1, qc)}   # each chunk's key prefix
     instr = re.compile(r"^\s*(?:ROOT )?(%[\w.\-]+) = (\S+) ([a-z][\w\-]*)\(")
-    seen, outside = 0, []
+    seen, outside, fused = dict.fromkeys(tiles, 0), [], False
     for line in text.splitlines():
+        if not line.startswith(" "):   # a computation's header or end
+            fused = line.startswith("%fused_computation")
         m = instr.match(line)
-        if not m or not tile.search(m.group(2)) or m.group(3) in (
-                "parameter", "get-tuple-element"):
+        if not m or m.group(3) in ("parameter", "get-tuple-element") or (
+                fused and m.group(3) == "broadcast"
+                and "broadcast(%constant" in line):
             continue
-        seen += 1
-        if scopes.get(m.group(1)) != "repro.mla":
+        hit = [w for w, tile in tiles.items() if tile.search(m.group(2))]
+        for w in hit:
+            seen[w] += 1
+        if hit and scopes.get(m.group(1)) != "repro.mla":
             outside.append(line.strip()[:200])
-    assert seen > 0
+    assert all(seen.values()), seen
     assert not outside, outside
